@@ -95,7 +95,7 @@ def all_kernels() -> List[Row]:
     c = jnp.asarray(np.array([6.0, -1, -1, -1, -1, -1, -1]))
     for rep in ("f64", "digits", "ds"):
         usx = _timed(lambda rep=rep: ops.ozaki_stencil7(u, c, out_rep=rep,
-                                                        bz=8, mode="pallas"))
+                                                        bx=4, mode="pallas"))
         plan_s = ozaki2.make_plan(8, margin_bits=4)
         npts = 32 ** 3
         out_bytes = {"f64": 8, "ds": 8, "digits": plan_s.r}[rep] * npts
@@ -130,11 +130,11 @@ def all_kernels() -> List[Row]:
     # stencil: default plan, both routes are cheap on CPU.
     stencil_out = {}
     for mode in ("xla", "pallas"):
-        us = _timed(lambda mode=mode: ops.ozaki_stencil7(u, c, bz=8, mode=mode))
+        us = _timed(lambda mode=mode: ops.ozaki_stencil7(u, c, bx=4, mode=mode))
         route, cls = _provenance(
-            lambda mode=mode: ops.ozaki_stencil7(u, c, bz=8, mode=mode))
+            lambda mode=mode: ops.ozaki_stencil7(u, c, bx=4, mode=mode))
         stencil_out[mode] = (f"kernel_stencil/route_{mode}/us", us,
-                             ops.ozaki_stencil7(u, c, bz=8, mode=mode),
+                             ops.ozaki_stencil7(u, c, bx=4, mode=mode),
                              route, cls)
     diff = float(jnp.max(jnp.abs(stencil_out["pallas"][2]
                                  - stencil_out["xla"][2])))

@@ -65,6 +65,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core import backend, splitting
 from repro.core.numerics import fast_two_sum, two_prod, two_sum  # noqa: F401
 from repro.obs import telemetry as obs
 
@@ -264,13 +265,24 @@ def compensated_norm(x: jax.Array, axis: Optional[int] = None) -> jax.Array:
     else:
         ax = _normalize_axis(axis, x.ndim)
     rec = obs.op_start("reduce", (x.shape[ax],), "xla", None, x, label="nrm2")
-    it, mb, eb, _ = _ieee_layout(x.dtype)
     finite = jnp.isfinite(x)
     has_nan = jnp.any(jnp.isnan(x), axis=ax)
     has_inf = jnp.any(jnp.isinf(x), axis=ax)
     # Non-finite entries are masked out of the scaled accumulation so the
     # normal path never produces inf - inf = NaN; the flags override below.
     xf = jnp.where(finite, x, 0.0)
+    if jnp.dtype(x.dtype).itemsize == 8 and backend.float64_is_f32_pair():
+        nrm = _norm_f32_pair(xf, ax)
+    else:
+        nrm = _norm_bits(xf, ax)
+    nrm = jnp.where(has_inf, jnp.asarray(jnp.inf, nrm.dtype), nrm)
+    return obs.op_end(rec, jnp.where(has_nan, jnp.asarray(jnp.nan, nrm.dtype),
+                                     nrm))
+
+
+def _norm_bits(xf: jax.Array, ax: int) -> jax.Array:
+    """IEEE path of ``compensated_norm``: scaling from bit fields."""
+    it, mb, eb, _ = _ieee_layout(xf.dtype)
     m, e = _decompose(xf)
     # floor(log2 |x_i|) = e + (exponent of m's leading bit); m is normal or
     # zero here, where frexp is reliable.
@@ -283,23 +295,33 @@ def compensated_norm(x: jax.Array, axis: Optional[int] = None) -> jax.Array:
     # squares can neither overflow nor flush.  (Elements so far below absmax
     # that the clip in _pow2 engages contribute < u**4 relatively — below
     # even the compensated bound.)
-    xs = m * _pow2(e - es, x.dtype)
+    xs = m * _pow2(e - es, xf.dtype)
     r = jnp.sqrt(_dot_impl(xs, xs, ax, None))          # in [1, ~2*sqrt(n)]
     es = jnp.squeeze(es, ax)
     # Reconstruct r * 2**es.  Two exact power-of-two multiplies cover the
     # normal range (split so neither factor over/underflows); ...
     half = es // 2
-    big = (r * _pow2(half, x.dtype)) * _pow2(es - half, x.dtype)
+    big = (r * _pow2(half, xf.dtype)) * _pow2(es - half, xf.dtype)
     # ... and a result in the denormal range (or the first normal binade) is
     # t = value * 2**(eb+mb-1) < 2**(mb+1), whose integer rounding IS the
     # result's bit pattern — FTZ'd arithmetic cannot produce these values.
-    t = r * _pow2(es + (eb + mb - 1), x.dtype)
+    t = r * _pow2(es + (eb + mb - 1), xf.dtype)
     tiny = t < 2.0 ** (mb + 1)
     k = jnp.round(jnp.where(tiny, t, 0.0)).astype(it)
-    nrm = jnp.where(tiny, jax.lax.bitcast_convert_type(k, x.dtype), big)
-    nrm = jnp.where(has_inf, jnp.asarray(jnp.inf, nrm.dtype), nrm)
-    return obs.op_end(rec, jnp.where(has_nan, jnp.asarray(jnp.nan, nrm.dtype),
-                                     nrm))
+    return jnp.where(tiny, jax.lax.bitcast_convert_type(k, xf.dtype), big)
+
+
+def _norm_f32_pair(xf: jax.Array, ax: int) -> jax.Array:
+    """``compensated_norm`` where float64 is a float32 pair (XLA:TPU): no
+    64-bit bit fields to read, and no denormals to keep.  The scale comes
+    from log2 of the largest magnitude; ``splitting.ldexp`` applies it
+    exactly, so squares neither overflow nor flush."""
+    a = jnp.abs(xf)
+    amax = jnp.max(a, axis=ax, keepdims=True)
+    es = jnp.floor(jnp.log2(jnp.where(amax > 0, amax, 1.0))).astype(jnp.int32)
+    xs = splitting.ldexp(a, -es)
+    r = jnp.sqrt(_dot_impl(xs, xs, ax, None))
+    return splitting.ldexp(r, jnp.squeeze(es, ax))
 
 
 # ---------------------------------------------------------------------------

@@ -72,7 +72,7 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import ozaki2
+from repro.core import backend, ozaki2
 from repro.obs import telemetry as obs
 
 MODES = ("auto", "xla", "pallas")
@@ -202,7 +202,7 @@ TUNE_TABLE: Dict[Tuple[str, str], Dict[str, Any]] = {
     ("gemm", "*"): {"bm": 128, "bn": 128, "bk": 256},
     ("gemv", "*"): {"bm": 128, "bk": 256},
     ("spmv_bell", "*"): {"br": 128},
-    ("stencil7", "*"): {"bz": 8},
+    ("stencil7", "*"): {"bx": 1},
     ("attention", "*"): {"bq": 128, "bkv": 128},
     ("reduce", "*"): {"block": 512},
     # Measured on CPU (f64 compensated_dot sweep): short vectors are
@@ -387,7 +387,7 @@ def choose_route(plan: Optional[ozaki2.Plan], kind: str = "gemm",
                                  f"got {route!r}")
             return route
     table = AUTO_ROUTE[kind]
-    return table.get(jax.default_backend(), table["default"])
+    return table.get(backend.name(), table["default"])
 
 
 def pallas_interpret(kind: str = "gemm") -> bool:
@@ -399,7 +399,7 @@ def pallas_interpret(kind: str = "gemm") -> bool:
     ``interpret=`` to pick a path — they pass ``mode=`` and land here.
     """
     _validate_kind(kind)
-    return jax.default_backend() != "tpu"
+    return backend.name() != "tpu"
 
 
 def _working_float():
@@ -502,14 +502,14 @@ def spmv(a_val: jax.Array, a_col: jax.Array, x: jax.Array,
 
 
 def stencil7(u: jax.Array, c: jax.Array, plan: Optional[ozaki2.Plan] = None,
-             out_rep: str = "f64", bz: Optional[int] = None,
+             out_rep: str = "f64", bx: Optional[int] = None,
              mode: Optional[str] = None) -> jax.Array:
     """Emulated 7-point stencil v = S[c] u through the dispatch layer.
 
     u: (X, Y, Z) grid, c: (7,) coefficients ordered
     [centre, -x, +x, -y, +y, -z, +z]; boundary points see a zero halo.  The
     route follows ``choose_route(plan, "stencil7", mode)``: the fused Pallas
-    kernel (z-axis blocked, padded and unpadded internally) vs the
+    kernel (x-axis blocked, padded and unpadded internally) vs the
     bit-identical jnp reference ``ozaki_stencil.stencil7_ref``.
     """
     from repro.kernels import ozaki_stencil as _stencil
@@ -519,9 +519,9 @@ def stencil7(u: jax.Array, c: jax.Array, plan: Optional[ozaki2.Plan] = None,
     route = choose_route(plan, "stencil7", mode, shape=u.shape)
     rec = obs.op_start("stencil7", u.shape, route, plan, u, c)
     if route == "pallas":
-        if bz is None:
-            bz = int(get_tuning("stencil7", u.shape).get("bz", 8))
-        out = _stencil.stencil7(u, c, plan, out_rep=out_rep, bz=bz,
+        if bx is None:
+            bx = int(get_tuning("stencil7", u.shape).get("bx", 1))
+        out = _stencil.stencil7(u, c, plan, out_rep=out_rep, bx=bx,
                                 interpret=pallas_interpret("stencil7"))
     else:
         out = _stencil.stencil7_ref(u, c, plan, out_rep=out_rep)

@@ -21,12 +21,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.core import fp8_quant
+from repro.core import backend, fp8_quant
 from repro.core import moduli as moduli_lib
 from repro.core import splitting
 
@@ -109,11 +110,6 @@ def decompose(x: jax.Array, plan: Plan, scale_axis: int,
 # Phase 2: modular matmuls
 # ---------------------------------------------------------------------------
 
-def _balanced_mod_i32(v: jax.Array, m: int) -> jax.Array:
-    u = jnp.remainder(v, m)
-    return jnp.where(u > (m - 1) // 2, u - m, u)
-
-
 def _dot_int8(a8: jax.Array, b8: jax.Array) -> jax.Array:
     """int8 x int8 -> int32 contraction over the last/first axes (MXU int8 path)."""
     return jax.lax.dot_general(
@@ -125,12 +121,12 @@ def _chunked_modular_dot_int8(ares: jax.Array, bres: jax.Array, m: int) -> jax.A
     """(Ã mod m)(B̃ mod m) mod m with int32-safe chunking over the contraction."""
     k = ares.shape[-1]
     if k <= _INT8_K_CHUNK:
-        return _balanced_mod_i32(_dot_int8(ares, bres), m)
+        return splitting.balanced_mod(_dot_int8(ares, bres), m)
     acc = None
     for s in range(0, k, _INT8_K_CHUNK):
         e = min(s + _INT8_K_CHUNK, k)
-        part = _balanced_mod_i32(_dot_int8(ares[..., s:e], bres[s:e]), m)
-        acc = part if acc is None else _balanced_mod_i32(acc + part, m)
+        part = splitting.balanced_mod(_dot_int8(ares[..., s:e], bres[s:e]), m)
+        acc = part if acc is None else splitting.balanced_mod(acc + part, m)
     return acc
 
 
@@ -166,7 +162,7 @@ def _chunked_modular_dot_fp8(ares: jax.Array, bres: jax.Array, m: int) -> jax.Ar
         L = plane(a_lo, b_lo, s, e).astype(jnp.int32)
         Mid = plane(a_hi + a_lo, b_hi + b_lo, s, e).astype(jnp.int32)
         part = fp8_quant.fp8_karatsuba_combine(H, Mid, L, m)
-        acc = part if acc is None else _balanced_mod_i32(acc + part, m)
+        acc = part if acc is None else splitting.balanced_mod(acc + part, m)
     return acc
 
 
@@ -182,6 +178,110 @@ def modular_matmul(ares: jax.Array, bres: jax.Array, plan: Plan) -> jax.Array:
 # Phase 3: Garner reconstruction
 # ---------------------------------------------------------------------------
 
+def garner_digits(accs: Sequence[jax.Array], plan: Plan) -> List[jax.Array]:
+    """Balanced mixed-radix digits v_j (int32) from per-modulus residues.
+
+    The integer half of the reconstruction, shared by the XLA reference and
+    the fused kernels (which run it on their accumulators before the store).
+    """
+    gc = plan.garner
+    ms = plan.moduli
+    r = plan.r
+    carry = [jnp.zeros_like(accs[0]) for _ in range(r)]
+    digits: List[jax.Array] = []
+    for j in range(r):
+        t = splitting.balanced_mod(
+            (splitting.balanced_mod(accs[j], ms[j]) - carry[j])
+            * np.int32(gc.inv_pref[j]), ms[j])
+        digits.append(t)
+        for l in range(j + 1, r):
+            carry[l] = splitting.balanced_mod(
+                carry[l] + t * np.int32(gc.pref_mod[j, l]), ms[l])
+    return digits
+
+
+def _split_prefix(ph: np.ndarray, bits: int):
+    """Host-side split of a prefix-product constant into (hi, lo) in ph's
+    dtype: hi keeps all but the low ``bits`` significand bits, lo = ph - hi
+    exactly.  Bit masking, not Veltkamp's (2**bits + 1) * ph, which overflows
+    float32 for the largest prefixes of a 16-modulus plan (~2**116)."""
+    it = np.dtype(f"uint{8 * ph.dtype.itemsize}")
+    mask = np.asarray(~((1 << bits) - 1) & ((1 << (8 * ph.dtype.itemsize)) - 1), it)
+    hi = (np.asarray(ph).view(it) & mask).view(ph.dtype)
+    return hi, (ph - hi).astype(ph.dtype)
+
+
+def digits_to_f64(digits: Sequence[jax.Array], plan: Plan,
+                  out_dtype=jnp.float64) -> jax.Array:
+    """Compensated double-double Horner over the digits (the float half).
+
+    term_j = t_j * P_j with P_j = pref_f64 + pref_f64_lo exact, accumulated
+    with two_sum and a compensation stream, so the result is the correctly
+    rounded float of the exact integer.  The two_prod of each term needs no
+    split of t_j (|t_j| <= 128 fits any half-mantissa exactly), and the split
+    of the constant P_j is done once on the host.
+
+    Where float64 is a float32 pair (XLA:TPU), the double-double algebra does
+    not hold and its emulation costs tens of seconds of compile per program:
+    there the digits run the double-single Horner, whose float32 EFTs are
+    exact, and the pair it returns is the backend's float64.
+    """
+    if jnp.dtype(out_dtype) == jnp.float64 and backend.float64_is_f32_pair():
+        hi, lo = digits_to_ds(digits, plan)
+        return hi.astype(out_dtype) + lo.astype(out_dtype)
+    gc = plan.garner
+    np_dtype = np.dtype(jnp.dtype(out_dtype).name)
+    bits = 27 if np_dtype == np.float64 else 12
+    out = jnp.zeros(digits[0].shape, out_dtype)
+    comp = jnp.zeros(digits[0].shape, out_dtype)
+    for j, t in enumerate(digits):
+        tf = t.astype(out_dtype)
+        ph = np.asarray(gc.pref_f64[j], np_dtype)
+        ph_h, ph_l = _split_prefix(ph, bits)
+        p = tf * jnp.asarray(ph, out_dtype)
+        e = (tf * jnp.asarray(ph_h, out_dtype) - p) + tf * jnp.asarray(ph_l, out_dtype)
+        e = e + tf * jnp.asarray(gc.pref_f64_lo[j], out_dtype)
+        # two_sum(out, p)
+        s = out + p
+        v = s - out
+        comp = comp + ((out - (s - v)) + (p - v)) + e
+        out = s
+    return out + comp
+
+
+def digits_to_ds(digits: Sequence[jax.Array], plan: Plan
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """Double-single (f32, f32) reconstruction of the digits.
+
+    P_j (to 48 bits) is held as four float32 constants, split on the host, of
+    at most 16, 8, 16 and 8 significant bits, so each product with a digit
+    (|t_j| <= 128) is exact: the two larger ones enter the (hi, lo) pair
+    through two_sum, the two smaller ones are added to lo.  No product
+    rounds, so a fused multiply-add (which XLA:CPU forms where it likes)
+    cannot change a bit.  The result holds ~45-48 significant bits (vs 24 for
+    a naive f32 Horner).
+    """
+    gc = plan.garner
+    hi = jnp.zeros(digits[0].shape, jnp.float32)
+    lo = jnp.zeros(digits[0].shape, jnp.float32)
+    for j, t in enumerate(digits):
+        tf = t.astype(jnp.float32)
+        ph = np.float32(gc.pref_f64[j])
+        c0, c1 = _split_prefix(ph, 8)
+        c2, c3 = _split_prefix(np.float32(gc.pref_f64[j] - np.float64(ph)), 8)
+        for c in (c0, c1):
+            # two_sum(hi, t_j * c)
+            p = tf * c
+            s = hi + p
+            v = s - hi
+            lo = lo + ((hi - (s - v)) + (p - v))
+            hi = s
+        lo = lo + (tf * c2 + tf * c3)
+    s = hi + lo
+    lo = lo - (s - hi)
+    return s, lo
+
+
 def garner_reconstruct(cres: jax.Array, plan: Plan,
                        out_dtype=jnp.float64) -> jax.Array:
     """Balanced-digit Garner: recover the (signed) integer value as a float.
@@ -196,28 +296,14 @@ def garner_reconstruct(cres: jax.Array, plan: Plan,
     value is the *correctly rounded* float of the exact integer: products whose
     unscaled value is representable in the output mantissa are recovered EXACTLY.
     """
-    from repro.core import numerics
-
-    gc = plan.garner
-    r = plan.r
-    ms = plan.moduli
-    acc = [jnp.zeros(cres.shape[1:], jnp.int32) for _ in range(r)]
-    out = jnp.zeros(cres.shape[1:], out_dtype)
-    comp = jnp.zeros(cres.shape[1:], out_dtype)
-    for j in range(r):
-        t = _balanced_mod_i32(
-            (cres[j].astype(jnp.int32) - acc[j]) * int(gc.inv_pref[j]), ms[j])
-        tf = t.astype(out_dtype)
-        # term = t * P_j in double-double: P_j = pref_f64 + pref_f64_lo (exact).
-        p_term, e_term = numerics.two_prod(
-            tf, jnp.asarray(gc.pref_f64[j], out_dtype))
-        e_term = e_term + tf * jnp.asarray(gc.pref_f64_lo[j], out_dtype)
-        s, e_sum = numerics.two_sum(out, p_term)
-        comp = comp + (e_sum + e_term)
-        out = s
-        for l in range(j + 1, r):
-            acc[l] = _balanced_mod_i32(acc[l] + t * int(gc.pref_mod[j, l]), ms[l])
-    return out + comp
+    # Optimization barriers (identities on values) around the Garner step keep
+    # XLA from fusing it with its producers and with the float epilogue: on
+    # XLA:TPU's float64 emulation that fusion costs minutes of compile, and
+    # on a (r, M, N) stack gigabytes of temporaries.
+    accs = jax.lax.optimization_barrier(
+        [cres[j].astype(jnp.int32) for j in range(plan.r)])
+    digits = jax.lax.optimization_barrier(garner_digits(accs, plan))
+    return digits_to_f64(digits, plan, out_dtype=out_dtype)
 
 
 # ---------------------------------------------------------------------------

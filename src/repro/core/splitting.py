@@ -10,17 +10,68 @@ Paper mapping (Matsuoka 2026 §2.3 Phase 1, Appendix C):
     traffic to native FP64, which is what keeps the TME bandwidth multiplier β = 1.
   * ``residues_from_hilo`` computes balanced residues mod m using int32 arithmetic only
     ((hi mod m) * (2^26 mod m) + lo) mod m — bit-exact vs the int64 oracle (tested).
+  * ``ldexp`` / ``pow2`` are the exact power-of-two scalings of Phase 1 and of the
+    unscale, in ops XLA:TPU lowers (it has no 64-bit bitcast, which ``jnp.ldexp`` needs).
 """
 
 from __future__ import annotations
 
-from typing import Sequence, Tuple
+from typing import List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.moduli import SPLIT_BITS, SPLIT_RADIX
+
+
+def _trunc_div(e: jax.Array, d: int) -> jax.Array:
+    """Integer e / d rounded toward zero (so e and e - d*q share a sign)."""
+    return jnp.where(e < 0, -((-e) // d), e // d)
+
+
+def pow2(e: jax.Array, dtype=jnp.float64) -> jax.Array:
+    """Exact ``2**e`` for an int32 array e with ``2**e`` normal in ``dtype``.
+
+    Built from float32 bit fields (a 32-bit bitcast, which every backend
+    lowers) and exact power-of-two products: for float64,
+    ``2**e = (2**a)**16 * 2**b`` with ``a = trunc(e/16)``, so every factor
+    and partial product is a power of two between 1 and ``2**e``.  XLA:TPU
+    lowers none of the 64-bit bitcasts that ``jnp.ldexp``/``jnp.frexp`` use.
+    """
+    e = e.astype(jnp.int32)
+
+    def f32_pow2(k):          # k in [-126, 127]
+        return jax.lax.bitcast_convert_type((k + 127) << 23, jnp.float32)
+
+    if jnp.dtype(dtype).itemsize <= 4:
+        return f32_pow2(jnp.clip(e, -126, 127)).astype(dtype)
+    a = _trunc_div(e, 16)
+    p = f32_pow2(a).astype(dtype)
+    for _ in range(4):
+        p = p * p
+    return p * f32_pow2(e - 16 * a).astype(dtype)
+
+
+def ldexp(x: jax.Array, e: jax.Array) -> jax.Array:
+    """``x * 2**e`` (int e, broadcast against x) as three exact power-of-two
+    multiplies — the ``jnp.ldexp`` contract on the values this repo scales,
+    in ops that XLA:TPU lowers.
+
+    The exponent splits into three same-signed thirds, so every partial
+    product lies between x and the result: no multiply rounds unless the
+    result itself leaves the normal range (where CPU arithmetic flushes to
+    zero or overflows exactly as ``jnp.ldexp`` does).  A third of the widest
+    shift a float64 product needs (|e| <= 2252) stays a normal float64, and
+    a third of the widest a float32-range float64 needs stays a normal
+    float32, which is what a TPU's float64 holds.
+    """
+    e = jnp.asarray(e, jnp.int32)
+    e1 = _trunc_div(e, 3)
+    e2 = _trunc_div(e - e1, 2)
+    for part in (e1, e2, e - e1 - e2):
+        x = x * pow2(part, x.dtype)
+    return x
 
 
 def scale_to_int(x: jax.Array, payload_bits: int, axis: int) -> Tuple[jax.Array, jax.Array]:
@@ -37,8 +88,8 @@ def scale_to_int(x: jax.Array, payload_bits: int, axis: int) -> Tuple[jax.Array,
     # exponent e with 2**e <= absmax < 2**(e+1); for absmax == 0 use e = 0.
     e = jnp.floor(jnp.log2(jnp.where(absmax > 0, absmax, 1.0)))
     shift = (payload_bits - 1) - e.astype(jnp.int32)
-    # ldexp (NOT exp2 — exp2 is inexact on some backends): exact pow2 scaling.
-    scaled = jnp.ldexp(x, jnp.broadcast_to(shift, x.shape))
+    # Exact pow2 scaling (NOT exp2 — exp2 is inexact on some backends).
+    scaled = ldexp(x, shift)
     # Guard against log2 boundary: ensure scaled max strictly < 2**payload_bits.
     too_big = jnp.max(jnp.abs(scaled), axis=ax, keepdims=True) >= 2.0 ** payload_bits
     shift = shift - too_big.astype(jnp.int32)
@@ -63,10 +114,25 @@ def merge_hi_lo(hi: jax.Array, lo: jax.Array, dtype=jnp.float64) -> jax.Array:
     return hi.astype(dtype) * float(SPLIT_RADIX) + lo.astype(dtype)
 
 
-def _balanced_mod(v: jax.Array, m: int) -> jax.Array:
-    """Balanced representative of v mod m in int32: range [-(m//2), (m-1)//2]."""
-    u = jnp.remainder(v, m)          # canonical [0, m)
-    return jnp.where(u > (m - 1) // 2, u - m, u)
+def balanced_mod(v: jax.Array, m: int) -> jax.Array:
+    """Balanced representative of v mod m: range [-(m//2), (m-1)//2].
+
+    The one implementation of the residue reduction: the XLA reference and
+    the fused kernels both call it.  Constants take v's integer dtype, so
+    under x64 no Python int becomes an int64 scalar inside a Mosaic kernel.
+    """
+    m_ = np.asarray(m, v.dtype)
+    u = jnp.remainder(v, m_)         # canonical [0, m)
+    return jnp.where(u > np.asarray((m - 1) // 2, v.dtype), u - m_, u)
+
+
+def residues_int32(hi: jax.Array, lo: jax.Array, moduli: Sequence[int]) -> List[jax.Array]:
+    """Balanced residues of x = hi*2^26 + lo per modulus; int32-only arithmetic."""
+    outs = []
+    for m in moduli:
+        v = balanced_mod(hi, m) * np.int32(SPLIT_RADIX % m) + balanced_mod(lo, m)
+        outs.append(balanced_mod(v, m))
+    return outs
 
 
 def residues_from_hilo(hi: jax.Array, lo: jax.Array, moduli: Sequence[int]) -> jax.Array:
@@ -75,12 +141,8 @@ def residues_from_hilo(hi: jax.Array, lo: jax.Array, moduli: Sequence[int]) -> j
     Pure int32 arithmetic (TPU-friendly).  Output dtype int8: every balanced residue of
     every modulus <= 256 fits [-128, 127].
     """
-    outs = []
-    for m in moduli:
-        radix_mod = SPLIT_RADIX % m
-        v = _balanced_mod(hi, m) * radix_mod + _balanced_mod(lo, m)
-        outs.append(_balanced_mod(v, m).astype(jnp.int8))
-    return jnp.stack(outs, axis=0)
+    return jnp.stack([r.astype(jnp.int8) for r in residues_int32(hi, lo, moduli)],
+                     axis=0)
 
 
 def residues_direct(xi: jax.Array, moduli: Sequence[int]) -> jax.Array:
@@ -100,8 +162,7 @@ def residues_direct(xi: jax.Array, moduli: Sequence[int]) -> jax.Array:
 
 def apply_unscale(c: jax.Array, shift_rows: jax.Array, shift_cols: jax.Array) -> jax.Array:
     """C = D^{-1} C̃ E^{-1}: undo the exact power-of-two row/col scaling on the output."""
-    total = -(shift_rows[:, None] + shift_cols[None, :])
-    return jnp.ldexp(c, jnp.broadcast_to(total, c.shape))
+    return ldexp(c, -(shift_rows[:, None] + shift_cols[None, :]))
 
 
 def np_split_hi_lo(xi: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:

@@ -8,7 +8,9 @@ reconstruction runs on the int32 accumulators before the store.
 Output representations (the one place the TPU adaptation pays a real cost, since
 Mosaic has no float64 type):
   f64    — full in-kernel double-double Garner.  Bit-equivalent to the XLA reference;
-           valid in interpret mode (this container) and on backends with f64.
+           valid in interpret mode only (the interpreter evaluates float64).  Under
+           Mosaic an "f64" request writes ``digits`` instead (``kernel_rep``) and XLA
+           finishes the same reconstruction (``finish``), so the result is unchanged.
   digits — TPU-production mode: the kernel stores the r balanced mixed-radix digits
            as int8 (r bytes/output vs 8 for f64) and a cheap bandwidth-bound XLA
            epilogue finishes the double-double Horner.  β_out = r/8.
@@ -21,112 +23,40 @@ pl.pallas_call (interpret or Mosaic) and in the XLA reference path.
 
 from __future__ import annotations
 
-from typing import List, Sequence, Tuple
+from typing import List, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import ozaki2
-from repro.core.moduli import SPLIT_RADIX
+from repro.core.ozaki2 import digits_to_ds, digits_to_f64, garner_digits  # noqa: F401
+from repro.core.splitting import balanced_mod, residues_int32  # noqa: F401
 
 OUT_REPS = ("f64", "digits", "ds")
 
-
-def balanced_mod(v: jax.Array, m: int) -> jax.Array:
-    u = jnp.remainder(v, m)
-    return jnp.where(u > (m - 1) // 2, u - m, u)
-
-
-def residues_int32(hi: jax.Array, lo: jax.Array, moduli: Sequence[int]) -> List[jax.Array]:
-    """Balanced residues of x = hi*2^26 + lo per modulus; int32-only arithmetic."""
-    outs = []
-    for m in moduli:
-        v = balanced_mod(hi, m) * (SPLIT_RADIX % m) + balanced_mod(lo, m)
-        outs.append(balanced_mod(v, m))
-    return outs
+# Literal block index 0 for index maps.  Under x64 a Python int traces as an
+# int64 scalar, and Mosaic lowers no int64: every integer constant that enters
+# a kernel or an index map is an explicit int32.
+ZERO = np.int32(0)
 
 
-def garner_digits(accs: Sequence[jax.Array], plan: ozaki2.Plan) -> List[jax.Array]:
-    """Balanced mixed-radix digits v_j (int32 arrays) from per-modulus accumulators."""
-    gc = plan.garner
-    ms = plan.moduli
-    r = plan.r
-    carry = [jnp.zeros_like(accs[0]) for _ in range(r)]
-    digits: List[jax.Array] = []
-    for j in range(r):
-        t = balanced_mod((balanced_mod(accs[j], ms[j]) - carry[j])
-                         * int(gc.inv_pref[j]), ms[j])
-        digits.append(t)
-        for l in range(j + 1, r):
-            carry[l] = balanced_mod(carry[l] + t * int(gc.pref_mod[j, l]), ms[l])
-    return digits
+def kernel_rep(out_rep: str, interpret: bool) -> str:
+    """What the kernel body writes for a requested ``out_rep``: Mosaic has no
+    float64 type, so a compiled kernel writes the digits of an "f64" request
+    and ``finish`` runs the same reconstruction in XLA."""
+    if out_rep not in OUT_REPS:
+        raise ValueError(f"out_rep must be one of {OUT_REPS}, got {out_rep!r}")
+    return "digits" if out_rep == "f64" and not interpret else out_rep
 
 
-def digits_to_f64(digits: Sequence[jax.Array], plan: ozaki2.Plan,
-                  out_dtype=jnp.float64) -> jax.Array:
-    """Compensated double-double Horner over the digits (the reconstruction epilogue)."""
-    gc = plan.garner
-    out = jnp.zeros(digits[0].shape, out_dtype)
-    comp = jnp.zeros(digits[0].shape, out_dtype)
-    split_bits = 27 if out_dtype == jnp.float64 else 12
-    split_c = (2.0 ** split_bits + 1.0)
-    for j, t in enumerate(digits):
-        tf = t.astype(out_dtype)
-        ph = jnp.asarray(gc.pref_f64[j], out_dtype)
-        p = tf * ph
-        # two_prod(tf, ph) inline (Veltkamp)
-        c1 = split_c * tf
-        tf_h = c1 - (c1 - tf)
-        tf_l = tf - tf_h
-        c2 = split_c * ph
-        ph_h = c2 - (c2 - ph)
-        ph_l = ph - ph_h
-        e = ((tf_h * ph_h - p) + tf_h * ph_l + tf_l * ph_h) + tf_l * ph_l
-        e = e + tf * jnp.asarray(gc.pref_f64_lo[j], out_dtype)
-        # two_sum(out, p)
-        s = out + p
-        v = s - out
-        comp = comp + ((out - (s - v)) + (p - v)) + e
-        out = s
-    return out + comp
-
-
-def digits_to_ds(digits: Sequence[jax.Array], plan: ozaki2.Plan
-                 ) -> Tuple[jax.Array, jax.Array]:
-    """Double-single (f32, f32) reconstruction — the β_out = 1 TPU fast path.
-
-    Full double-single arithmetic: each prefix product is carried as an exact
-    (hi, lo) f32 pair and each digit term uses a Veltkamp two_prod, so the result
-    holds ~45-48 significant bits (vs 24 for a naive f32 Horner).
-    """
-    gc = plan.garner
-    split_c = jnp.float32(2.0 ** 12 + 1.0)
-    hi = jnp.zeros(digits[0].shape, jnp.float32)
-    lo = jnp.zeros(digits[0].shape, jnp.float32)
-    for j, t in enumerate(digits):
-        tf = t.astype(jnp.float32)
-        ph_np = np.float32(gc.pref_f64[j])
-        ph = jnp.asarray(ph_np)
-        pl_ = jnp.asarray(np.float32(gc.pref_f64[j] - np.float64(ph_np)))
-        # two_prod(tf, ph) in f32
-        p = tf * ph
-        c1 = split_c * tf
-        tf_h = c1 - (c1 - tf)
-        tf_l = tf - tf_h
-        c2 = split_c * ph
-        ph_h = c2 - (c2 - ph)
-        ph_l = ph - ph_h
-        e = ((tf_h * ph_h - p) + tf_h * ph_l + tf_l * ph_h) + tf_l * ph_l
-        e = e + tf * pl_
-        # two_sum(hi, p)
-        s = hi + p
-        v = s - hi
-        lo = lo + ((hi - (s - v)) + (p - v)) + e
-        hi = s
-    s = hi + lo
-    lo = lo - (s - hi)
-    return s, lo
+def finish(raw: jax.Array, plan: ozaki2.Plan, rep: str, dtype) -> jax.Array:
+    """Epilogue in XLA: a kernel's raw ``rep`` output -> working float."""
+    if rep == "f64":
+        return raw
+    if rep == "ds":
+        return raw[0].astype(dtype) + raw[1].astype(dtype)
+    return digits_to_f64(unstack_digits(raw), plan, out_dtype=dtype)
 
 
 def stack_digits_int8(digits: Sequence[jax.Array]) -> jax.Array:

@@ -17,7 +17,7 @@ Two tiers of wrapper live here:
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -40,21 +40,6 @@ def _working_f64():
     return jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
 
 
-def _finish(raw: jax.Array, plan: ozaki2.Plan, out_rep: str,
-            shape: Tuple[int, int]) -> jax.Array:
-    """Epilogue: raw kernel output -> scaled-integer product as working float."""
-    M, N = shape
-    if out_rep == "f64":
-        return raw[:M, :N]
-    if out_rep == "ds":
-        return (raw[0].astype(_working_f64())
-                + raw[1].astype(_working_f64()))[:M, :N]
-    if out_rep == "digits":
-        digits = common.unstack_digits(raw)
-        return common.digits_to_f64(digits, plan, out_dtype=_working_f64())[:M, :N]
-    raise ValueError(out_rep)
-
-
 def ozaki_gemm(a: jax.Array, b: jax.Array, plan: Optional[ozaki2.Plan] = None,
                out_rep: str = "f64", bm: int = 128, bn: int = 128, bk: int = 256,
                interpret: Optional[bool] = None) -> jax.Array:
@@ -75,9 +60,10 @@ def ozaki_gemm(a: jax.Array, b: jax.Array, plan: Optional[ozaki2.Plan] = None,
     a_hi, a_lo = _pad2(a_hi, bm, bk), _pad2(a_lo, bm, bk)
     b_hi, b_lo = _pad2(b_hi, bk, bn), _pad2(b_lo, bk, bn)
 
-    raw = _gemm.gemm_hilo(a_hi, a_lo, b_hi, b_lo, plan, out_rep=out_rep,
+    rep = common.kernel_rep(out_rep, interpret)
+    raw = _gemm.gemm_hilo(a_hi, a_lo, b_hi, b_lo, plan, out_rep=rep,
                           bm=bm, bn=bn, bk=bk, interpret=interpret)
-    c = _finish(raw, plan, out_rep, (M, N))
+    c = common.finish(raw, plan, rep, f64)[..., :M, :N]
     return splitting.apply_unscale(c, sa, sb)
 
 
@@ -101,15 +87,16 @@ def ozaki_gemv(a: jax.Array, x: jax.Array, plan: Optional[ozaki2.Plan] = None,
     a_hi, a_lo = _pad2(a_hi, bm, bk), _pad2(a_lo, bm, bk)
     x_hi, x_lo = _pad2(x_hi, bk, B), _pad2(x_lo, bk, B)
 
-    raw = _gemv.gemv_hilo(a_hi, a_lo, x_hi, x_lo, plan, out_rep=out_rep,
+    rep = common.kernel_rep(out_rep, interpret)
+    raw = _gemv.gemv_hilo(a_hi, a_lo, x_hi, x_lo, plan, out_rep=rep,
                           bm=bm, bk=bk, interpret=interpret)
-    y = _finish(raw, plan, out_rep, (M, B))
+    y = common.finish(raw, plan, rep, f64)[..., :M, :B]
     return splitting.apply_unscale(y, sa, sx)
 
 
 def ozaki_stencil7(u: jax.Array, c: jax.Array,
                    plan: Optional[ozaki2.Plan] = None, out_rep: str = "f64",
-                   bz: int = 8, mode: Optional[str] = None) -> jax.Array:
+                   bx: int = 1, mode: Optional[str] = None) -> jax.Array:
     """7-point 3-D stencil (paper Alg. 2) at FP64 accuracy, dispatch-routed.
 
     u: (X, Y, Z) grid, c: (7,) coefficients ordered
@@ -117,7 +104,7 @@ def ozaki_stencil7(u: jax.Array, c: jax.Array,
     ``mode`` (or the ambient ``mode_scope`` / ``REPRO_DISPATCH``) selects the
     fused Pallas kernel or the bit-identical jnp reference.
     """
-    return dispatch.stencil7(u, c, plan=plan, out_rep=out_rep, bz=bz, mode=mode)
+    return dispatch.stencil7(u, c, plan=plan, out_rep=out_rep, bx=bx, mode=mode)
 
 
 def ozaki_attention(q: jax.Array, k: jax.Array, v: jax.Array,

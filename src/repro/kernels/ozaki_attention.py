@@ -27,9 +27,9 @@ tests/test_attention.py): ``attention_ref`` composes ``ozaki2.emulated_matmul``
 per kv-block with the *same* block size, padding, scaling axes, and shared
 ``_masked_scores``/``_online_update`` helpers, so both routes perform the same
 float operations in the same order on the same exact integer products.  The
-in-kernel f64 epilogue is valid in interpret mode (this container) and on
-backends with f64 vector support; a digits/ds output variant for compiled
-Mosaic is the accelerator-lane follow-on, as for the GEMM kernel.
+in-kernel f64 epilogue runs in interpret mode only: compiled for a TPU, the
+pallas route runs the scan in XLA with each block product on the fused GEMM
+kernel (see ``attention_fused``).
 """
 
 from __future__ import annotations
@@ -42,7 +42,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import ozaki2, splitting
+from repro.core import dispatch, ozaki2, splitting
 from repro.kernels import common
 
 # Finite stand-in for -inf (matches repro.models.attention.NEG_INF): keeps the
@@ -85,6 +85,43 @@ def _online_update(s: jax.Array, m: jax.Array, l: jax.Array):
 # XLA reference: the same scan composed from the seam GEMMs
 # ---------------------------------------------------------------------------
 
+def _block_scan(q, k, v, mask, plan_qk, plan_pv, softcap, bkv, out_dtype,
+                product):
+    """The online-softmax scan over kv-blocks of ``bkv`` rows, with each
+    block's QKᵀ and PV computed by ``product(a, b, plan)``."""
+    S0, D = q.shape
+    T = k.shape[0]
+    # Query rows padded to a sublane multiple.  Rows are independent (q and p
+    # scale per row), so padding changes no real row; it keeps a decode step
+    # (S = 1) from carrying its softmax state as one-element float64 arrays,
+    # which XLA:TPU computes at float32 precision.
+    S = -(-S0 // 8) * 8
+    q = jnp.pad(q.astype(out_dtype), ((0, S - S0), (0, 0)))
+    tp = -(-T // bkv) * bkv
+    kp = jnp.pad(k.astype(out_dtype), ((0, tp - T), (0, 0)))
+    vp = jnp.pad(v.astype(out_dtype), ((0, tp - T), (0, 0)))
+    mp = jnp.pad(mask.astype(jnp.int8), ((0, S - S0), (0, tp - T)))
+    kb = kp.reshape(tp // bkv, bkv, D)
+    vb = vp.reshape(tp // bkv, bkv, D)
+    mb = jnp.moveaxis(mp.reshape(S, tp // bkv, bkv), 1, 0)
+    inv_sqrt_d = 1.0 / math.sqrt(D)
+
+    def step(carry, blk):
+        m, l, acc = carry
+        k_blk, v_blk, mask_blk = blk
+        s_prod = product(q, k_blk.T, plan_qk)
+        s = _masked_scores(s_prod, mask_blk != 0, softcap, inv_sqrt_d)
+        p, corr, m, l = _online_update(s, m, l)
+        pv = product(p, v_blk, plan_pv)
+        acc = acc * corr[:, None] + pv
+        return (m, l, acc), None
+
+    init = (jnp.full((S,), NEG_INF, out_dtype), jnp.zeros((S,), out_dtype),
+            jnp.zeros((S, D), out_dtype))
+    (m, l, acc), _ = jax.lax.scan(step, init, (kb, vb, mb))
+    return (acc / l[:, None])[:S0]
+
+
 @functools.partial(jax.jit, static_argnames=("plan_qk", "plan_pv", "softcap",
                                              "bkv", "out_dtype"))
 def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array,
@@ -99,35 +136,12 @@ def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array,
     integer products and reconstruction the fused kernel performs in VMEM,
     at the same scaling granularity (q/k per row over D; p per row and v per
     column over the block).  Bit-identical to the fused kernel for any
-    (bq, bkv) blocking, the same way ``stencil7_ref`` is for z-blocking.
+    (bq, bkv) blocking, the same way ``stencil7_ref`` is for x-blocking.
     """
-    S, D = q.shape
-    T = k.shape[0]
-    q = q.astype(out_dtype)
-    tp = -(-T // bkv) * bkv
-    kp = jnp.pad(k.astype(out_dtype), ((0, tp - T), (0, 0)))
-    vp = jnp.pad(v.astype(out_dtype), ((0, tp - T), (0, 0)))
-    mp = jnp.pad(mask.astype(jnp.int8), ((0, 0), (0, tp - T)))
-    kb = kp.reshape(tp // bkv, bkv, D)
-    vb = vp.reshape(tp // bkv, bkv, D)
-    mb = jnp.moveaxis(mp.reshape(S, tp // bkv, bkv), 1, 0)
-    inv_sqrt_d = 1.0 / math.sqrt(D)
-
-    def step(carry, blk):
-        m, l, acc = carry
-        k_blk, v_blk, mask_blk = blk
-        s_prod = ozaki2.emulated_matmul(q, k_blk.T, plan_qk,
-                                        out_dtype=out_dtype)
-        s = _masked_scores(s_prod, mask_blk != 0, softcap, inv_sqrt_d)
-        p, corr, m, l = _online_update(s, m, l)
-        pv = ozaki2.emulated_matmul(p, v_blk, plan_pv, out_dtype=out_dtype)
-        acc = acc * corr[:, None] + pv
-        return (m, l, acc), None
-
-    init = (jnp.full((S,), NEG_INF, out_dtype), jnp.zeros((S,), out_dtype),
-            jnp.zeros((S, D), out_dtype))
-    (m, l, acc), _ = jax.lax.scan(step, init, (kb, vb, mb))
-    return acc / l[:, None]
+    def product(a, b, plan):
+        return ozaki2.emulated_matmul(a, b, plan, out_dtype=out_dtype)
+    return _block_scan(q, k, v, mask, plan_qk, plan_pv, softcap, bkv,
+                       out_dtype, product)
 
 
 # ---------------------------------------------------------------------------
@@ -210,7 +224,17 @@ def attention_fused(q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array,
     and D to block multiples is exact (padded rows/cols scale with shift 0 and
     contribute zero residues; padded key columns are masked), so the unpadded
     region is bit-identical to ``attention_ref`` at the same ``bkv``.
+
+    Mosaic has no float64, so a compiled kernel cannot hold the softmax
+    state.  With ``interpret=False`` the same scan runs in XLA instead, each
+    block product on the fused GEMM kernel (``dispatch.matmul`` on the pallas
+    route), and the result is still the reference's.
     """
+    if not interpret:
+        def product(a, b, plan):
+            return dispatch.matmul(a, b, plan=plan, mode="pallas")
+        return _block_scan(q, k, v, mask, plan_qk, plan_pv, softcap, bkv,
+                           out_dtype, product)
     S, D = q.shape
     T = k.shape[0]
     inv_sqrt_d = 1.0 / math.sqrt(D)

@@ -20,6 +20,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -31,7 +32,7 @@ def _gemm_kernel(a_hi_ref, a_lo_ref, b_hi_ref, b_lo_ref, out_ref, acc_ref, *,
                  plan: ozaki2.Plan, out_rep: str, k_steps: int):
     kidx = pl.program_id(2)
 
-    @pl.when(kidx == 0)
+    @pl.when(kidx == np.int32(0))
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -45,7 +46,7 @@ def _gemm_kernel(a_hi_ref, a_lo_ref, b_hi_ref, b_lo_ref, out_ref, acc_ref, *,
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
         acc_ref[i] = common.balanced_mod(acc_ref[i] + part, m)
 
-    @pl.when(kidx == k_steps - 1)
+    @pl.when(kidx == np.int32(k_steps - 1))
     def _epilogue():
         digits = common.garner_digits([acc_ref[i] for i in range(plan.r)], plan)
         if out_rep == "f64":
@@ -87,10 +88,10 @@ def gemm_hilo(a_hi: jax.Array, a_lo: jax.Array, b_hi: jax.Array, b_lo: jax.Array
         out_spec = pl.BlockSpec((bm, bn), lambda i, j, k: (i, j))
     elif out_rep == "ds":
         out_shape = jax.ShapeDtypeStruct((2, M, N), jnp.float32)
-        out_spec = pl.BlockSpec((2, bm, bn), lambda i, j, k: (0, i, j))
+        out_spec = pl.BlockSpec((2, bm, bn), lambda i, j, k: (common.ZERO, i, j))
     elif out_rep == "digits":
         out_shape = jax.ShapeDtypeStruct((plan.r, M, N), jnp.int8)
-        out_spec = pl.BlockSpec((plan.r, bm, bn), lambda i, j, k: (0, i, j))
+        out_spec = pl.BlockSpec((plan.r, bm, bn), lambda i, j, k: (common.ZERO, i, j))
     else:
         raise ValueError(f"out_rep must be one of {common.OUT_REPS}")
 

@@ -19,6 +19,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -30,7 +31,7 @@ def _gemv_kernel(a_hi_ref, a_lo_ref, x_hi_ref, x_lo_ref, out_ref, acc_ref, *,
                  plan: ozaki2.Plan, out_rep: str, k_steps: int):
     kidx = pl.program_id(1)
 
-    @pl.when(kidx == 0)
+    @pl.when(kidx == np.int32(0))
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -43,7 +44,7 @@ def _gemv_kernel(a_hi_ref, a_lo_ref, x_hi_ref, x_lo_ref, out_ref, acc_ref, *,
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
         acc_ref[i] = common.balanced_mod(acc_ref[i] + part, m)
 
-    @pl.when(kidx == k_steps - 1)
+    @pl.when(kidx == np.int32(k_steps - 1))
     def _epilogue():
         digits = common.garner_digits([acc_ref[i] for i in range(plan.r)], plan)
         if out_rep == "f64":
@@ -70,18 +71,18 @@ def gemv_hilo(a_hi: jax.Array, a_lo: jax.Array, x_hi: jax.Array, x_lo: jax.Array
     in_specs = [
         pl.BlockSpec((bm, bk), lambda i, k: (i, k)),
         pl.BlockSpec((bm, bk), lambda i, k: (i, k)),
-        pl.BlockSpec((bk, B), lambda i, k: (k, 0)),
-        pl.BlockSpec((bk, B), lambda i, k: (k, 0)),
+        pl.BlockSpec((bk, B), lambda i, k: (k, common.ZERO)),
+        pl.BlockSpec((bk, B), lambda i, k: (k, common.ZERO)),
     ]
     if out_rep == "f64":
         out_shape = jax.ShapeDtypeStruct((M, B), jnp.float64)
-        out_spec = pl.BlockSpec((bm, B), lambda i, k: (i, 0))
+        out_spec = pl.BlockSpec((bm, B), lambda i, k: (i, common.ZERO))
     elif out_rep == "ds":
         out_shape = jax.ShapeDtypeStruct((2, M, B), jnp.float32)
-        out_spec = pl.BlockSpec((2, bm, B), lambda i, k: (0, i, 0))
+        out_spec = pl.BlockSpec((2, bm, B), lambda i, k: (common.ZERO, i, common.ZERO))
     elif out_rep == "digits":
         out_shape = jax.ShapeDtypeStruct((plan.r, M, B), jnp.int8)
-        out_spec = pl.BlockSpec((plan.r, bm, B), lambda i, k: (0, i, 0))
+        out_spec = pl.BlockSpec((plan.r, bm, B), lambda i, k: (common.ZERO, i, common.ZERO))
     else:
         raise ValueError(f"out_rep must be one of {common.OUT_REPS}")
 

@@ -2,18 +2,17 @@
 
 y = A·x with A in Blocked-ELL layout: ``a_val (M, bw)`` padded nonzero values and
 ``a_col (M, bw)`` gather indices.  Each program handles a block of ``br`` rows:
-stream the value block, gather x, residue-decompose both in VMEM, contract the
-bw-length products per modulus, Garner, store.
+stream the value block and the gathered x block, residue-decompose both in VMEM,
+contract the bw-length products per modulus, Garner, store.
 
-TPU adaptation notes (DESIGN.md §3):
-  * the dense vector x stays fully VMEM-resident as an (hi, lo) int32 pair
-    (8 B/element; for N = 1M that is 8 MiB — well within v5e VMEM), which is the
-    shared-memory-tile assumption of Algorithm 3;
-  * the gather x[a_col] is expressed as a vector gather; on Mosaic this lowers to
-    dynamic-gather (supported for minor-dim gathers) — the one-hot-matmul fallback
-    documented in DESIGN.md is not needed in interpret mode;
-  * β inherits the ELL padding ratio ρ_pad exactly as Appendix D derives — the
-    kernel adds nothing on top (residues never touch HBM).
+TPU adaptation notes:
+  * the gather x[a_col] runs in XLA ahead of the kernel, as (hi, lo) int32 pairs:
+    Mosaic lowers no general vector gather.  That costs 8 B per stored slot
+    on top of Algorithm 3's VMEM-resident x;
+  * blocks are laid out (bw, br), rows on the 128 lanes, so a block is
+    lane-dense for any band width;
+  * β otherwise inherits the ELL padding ratio ρ_pad exactly as Appendix D
+    derives (residues never touch HBM).
 """
 
 from __future__ import annotations
@@ -23,27 +22,26 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import ozaki2, splitting
 from repro.kernels import common
 from repro.kernels.ozaki_stencil import _global_scale_to_int
 
 
-def _spmv_kernel(av_hi_ref, av_lo_ref, col_ref, x_hi_ref, x_lo_ref, out_ref, *,
+def _spmv_kernel(av_hi_ref, av_lo_ref, xg_hi_ref, xg_lo_ref, out_ref, *,
                  plan: ozaki2.Plan, out_rep: str):
-    cols = col_ref[...]                      # (br, bw) int32
-    xg_hi = x_hi_ref[...][cols]              # VMEM gather
-    xg_lo = x_lo_ref[...][cols]
-
+    # (bw, br) blocks: the row's slots on sublanes, rows on the lanes.
     a_res = common.residues_int32(av_hi_ref[...], av_lo_ref[...], plan.moduli)
-    x_res = common.residues_int32(xg_hi, xg_lo, plan.moduli)
+    x_res = common.residues_int32(xg_hi_ref[...], xg_lo_ref[...], plan.moduli)
 
     accs = []
     for i, m in enumerate(plan.moduli):
-        prod = a_res[i] * x_res[i]           # (br, bw) int32, |.| <= 128*128
-        accs.append(common.balanced_mod(jnp.sum(prod, axis=-1), m))
+        prod = a_res[i] * x_res[i]           # (bw, br) int32, |.| <= 128*128
+        accs.append(common.balanced_mod(
+            jnp.sum(prod, axis=0, keepdims=True, dtype=jnp.int32), m))
 
-    digits = common.garner_digits(accs, plan)
+    digits = common.garner_digits(accs, plan)   # (1, br) each
     if out_rep == "f64":
         out_ref[...] = common.digits_to_f64(digits, plan)
     elif out_rep == "ds":
@@ -76,7 +74,8 @@ def _spmv_ref_digits(a_val: jax.Array, a_col: jax.Array, x: jax.Array,
 
     a_res = common.residues_int32(av_hi, av_lo, plan.moduli)
     x_res = common.residues_int32(x_hi[cols], x_lo[cols], plan.moduli)
-    accs = [common.balanced_mod(jnp.sum(a_res[i] * x_res[i], axis=-1), m)
+    accs = [common.balanced_mod(
+                jnp.sum(a_res[i] * x_res[i], axis=-1, dtype=jnp.int32), m)
             for i, m in enumerate(plan.moduli)]
     return common.garner_digits(accs, plan), sa, sx
 
@@ -93,7 +92,7 @@ def _spmv_ref_epilogue(digits, sa: jax.Array, sx: jax.Array,
         y = hi.astype(f64) + lo.astype(f64)
     else:
         raise ValueError(f"out_rep must be one of {common.OUT_REPS}")
-    return jnp.ldexp(y, jnp.broadcast_to(-(sa + sx), y.shape))
+    return splitting.ldexp(y, -(sa + sx))
 
 
 def spmv_bell_ref(a_val: jax.Array, a_col: jax.Array, x: jax.Array,
@@ -122,55 +121,41 @@ def spmv_bell(a_val: jax.Array, a_col: jax.Array, x: jax.Array,
               plan: ozaki2.Plan, out_rep: str = "f64", br: int = 128,
               interpret: bool = True) -> jax.Array:
     M, bw = a_val.shape
-    N = x.shape[0]
     f64 = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
     br = min(br, M)
     pm = (-M) % br
 
     av_hi, av_lo, col, x_hi, x_lo, sa, sx = _decompose_operands(
         a_val, a_col, x, plan)
-    if pm:
-        av_hi = jnp.pad(av_hi, ((0, pm), (0, 0)))
-        av_lo = jnp.pad(av_lo, ((0, pm), (0, 0)))
-        col = jnp.pad(col, ((0, pm), (0, 0)))
-        sa = jnp.pad(sa, (0, pm))
+    # The gather runs in XLA: Mosaic has no general vector gather.  Rows go
+    # to the lanes, so a (bw, br) block is lane-dense for any band width.
+    ops = [av_hi, av_lo, x_hi[col], x_lo[col]]
+    ops = [jnp.pad(o, ((0, pm), (0, 0))).T for o in ops]
     Mp = M + pm
-    grid = (Mp // br,)
+    zero = common.ZERO
+    blk = pl.BlockSpec((bw, br), lambda i: (zero, i))
 
-    in_specs = [
-        pl.BlockSpec((br, bw), lambda i: (i, 0)),
-        pl.BlockSpec((br, bw), lambda i: (i, 0)),
-        pl.BlockSpec((br, bw), lambda i: (i, 0)),
-        pl.BlockSpec((N,), lambda i: (0,)),     # x fully VMEM-resident
-        pl.BlockSpec((N,), lambda i: (0,)),
-    ]
-    if out_rep == "f64":
-        out_shape = jax.ShapeDtypeStruct((Mp,), jnp.float64)
-        out_spec = pl.BlockSpec((br,), lambda i: (i,))
-    elif out_rep == "ds":
-        out_shape = jax.ShapeDtypeStruct((2, Mp), jnp.float32)
-        out_spec = pl.BlockSpec((2, br), lambda i: (0, i))
-    elif out_rep == "digits":
-        out_shape = jax.ShapeDtypeStruct((plan.r, Mp), jnp.int8)
-        out_spec = pl.BlockSpec((plan.r, br), lambda i: (0, i))
+    rep = common.kernel_rep(out_rep, interpret)
+    if rep == "f64":
+        out_shape = jax.ShapeDtypeStruct((1, Mp), jnp.float64)
+        out_spec = pl.BlockSpec((1, br), lambda i: (zero, i))
+    elif rep == "ds":
+        out_shape = jax.ShapeDtypeStruct((2, 1, Mp), jnp.float32)
+        out_spec = pl.BlockSpec((2, 1, br), lambda i: (zero, zero, i))
     else:
-        raise ValueError(f"out_rep must be one of {common.OUT_REPS}")
+        out_shape = jax.ShapeDtypeStruct((plan.r, 1, Mp), jnp.int8)
+        out_spec = pl.BlockSpec((plan.r, 1, br), lambda i: (zero, zero, i))
 
-    kernel = functools.partial(_spmv_kernel, plan=plan, out_rep=out_rep)
+    kernel = functools.partial(_spmv_kernel, plan=plan, out_rep=rep)
     raw = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
+        grid=(Mp // br,),
+        in_specs=[blk] * 4,
         out_specs=out_spec,
         out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(av_hi, av_lo, col, x_hi, x_lo)
+    )(*ops)
 
-    if out_rep == "f64":
-        y = raw[:M]
-    elif out_rep == "ds":
-        y = (raw[0].astype(f64) + raw[1].astype(f64))[:M]
-    else:
-        y = common.digits_to_f64(common.unstack_digits(raw), plan,
-                                 out_dtype=f64)[:M]
-    return jnp.ldexp(y, jnp.broadcast_to(-(sa[:M] + sx), y.shape))
+    y = common.finish(raw, plan, rep, f64)[0, :M]
+    return splitting.ldexp(y, -(sa + sx))

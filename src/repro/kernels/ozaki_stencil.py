@@ -22,17 +22,24 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import ozaki2, splitting
 from repro.kernels import common
+
+# Scoped VMEM for one x-slab: the r residue accumulators and Garner carries
+# of a 256 x 256 plane take ~20 MiB, past Mosaic's 16 MiB default (a v5e core
+# has 128 MiB of VMEM).
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 
 def _global_scale_to_int(x: jax.Array, payload_bits: int):
     absmax = jnp.max(jnp.abs(x))
     e = jnp.floor(jnp.log2(jnp.where(absmax > 0, absmax, 1.0)))
     shift = (payload_bits - 1) - e.astype(jnp.int32)
-    scaled = jnp.ldexp(x, jnp.broadcast_to(shift, x.shape))
+    scaled = splitting.ldexp(x, shift)
     too_big = jnp.max(jnp.abs(scaled)) >= 2.0 ** payload_bits
     shift = shift - too_big.astype(jnp.int32)
     scaled = jnp.where(too_big, scaled * 0.5, scaled)
@@ -47,41 +54,46 @@ def _roll_mask(arr: jax.Array, ax: int, d: int) -> jax.Array:
     return rolled.at[tuple(idx)].set(0)
 
 
+def _shift_in_tile(arr: jax.Array, ax: int, d: int) -> jax.Array:
+    """``_roll_mask`` inside a kernel: a lane/sublane rotate (``pltpu.roll``,
+    whose shift must be non-negative) with the wrapped row masked to zero."""
+    n = arr.shape[ax]
+    rolled = pltpu.roll(arr, np.int32(d % n), ax)
+    edge = np.int32(0 if d == 1 else n - 1)
+    pos = jax.lax.broadcasted_iota(jnp.int32, arr.shape, ax)
+    return jnp.where(pos == edge, jnp.zeros_like(arr), rolled)
+
+
 def _stencil_kernel(c_res_ref, u_hi_p, u_lo_p, u_hi_c, u_lo_c, u_hi_n, u_lo_n,
-                    out_ref, *, plan: ozaki2.Plan, out_rep: str, z_steps: int):
-    zidx = pl.program_id(0)
-    X, Y, bz = u_hi_c.shape
+                    out_ref, *, plan: ozaki2.Plan, out_rep: str, x_steps: int):
+    xidx = pl.program_id(0)
+    bx = u_hi_c.shape[0]
+    # Halo planes beyond the global x boundary are the zero halo.
+    first = xidx == np.int32(0)
+    last = xidx == np.int32(x_steps - 1)
 
-    def neighborhood(cur, prev, nxt):
-        """Stack the 7-point neighbourhood: [centre, -x, +x, -y, +y, -z, +z]."""
-        zm = jnp.concatenate([prev[:, :, -1:], cur[:, :, :-1]], axis=2)
-        zm = jnp.where(zidx == 0,
-                       zm.at[:, :, 0].set(0), zm)  # global -z boundary
-        zp = jnp.concatenate([cur[:, :, 1:], nxt[:, :, :1]], axis=2)
-        zp = jnp.where(zidx == z_steps - 1,
-                       zp.at[:, :, -1].set(0), zp)  # global +z boundary
-        return jnp.stack([
-            cur,
-            _roll_mask(cur, 0, 1), _roll_mask(cur, 0, -1),
-            _roll_mask(cur, 1, 1), _roll_mask(cur, 1, -1),
-            zm, zp,
-        ], axis=0)  # (7, X, Y, bz)
-
-    nb_hi = neighborhood(u_hi_c[...], u_hi_p[...], u_hi_n[...])
-    nb_lo = neighborhood(u_lo_c[...], u_lo_p[...], u_lo_n[...])
-
-    # im2col: (7, X*Y*bz) residue planes contracted against (1, 7) coefficients.
-    nb_hi2 = nb_hi.reshape(7, -1)
-    nb_lo2 = nb_lo.reshape(7, -1)
-    u_res = common.residues_int32(nb_hi2, nb_lo2, plan.moduli)
-
+    # The residue of a neighbour is the neighbour of the residue: decompose
+    # each point once per modulus, then shift residue planes.
     accs = []
     for i, m in enumerate(plan.moduli):
-        ci = c_res_ref[i].reshape(1, 7)  # constant-memory analogue
-        part = jax.lax.dot_general(
-            ci.astype(jnp.int8), u_res[i].astype(jnp.int8),
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
-        accs.append(common.balanced_mod(part.reshape(X, Y, bz), m))
+        (cur,) = common.residues_int32(u_hi_c[...], u_lo_c[...], (m,))
+        (prev,) = common.residues_int32(u_hi_p[...], u_lo_p[...], (m,))
+        (nxt,) = common.residues_int32(u_hi_n[...], u_lo_n[...], (m,))
+        prev = jnp.where(first, jnp.zeros_like(prev), prev)
+        nxt = jnp.where(last, jnp.zeros_like(nxt), nxt)
+        if bx == 1:
+            xm, xp = prev, nxt
+        else:
+            xm = jnp.concatenate([prev, cur[:-1]], axis=0)
+            xp = jnp.concatenate([cur[1:], nxt], axis=0)
+        # [centre, -x, +x, -y, +y, -z, +z]; |sum| <= 7 * 128 * 128, exact.
+        nbs = (cur, xm, xp,
+               _shift_in_tile(cur, 1, 1), _shift_in_tile(cur, 1, -1),
+               _shift_in_tile(cur, 2, 1), _shift_in_tile(cur, 2, -1))
+        acc = nbs[0] * c_res_ref[i, 0]
+        for t in range(1, 7):
+            acc = acc + nbs[t] * c_res_ref[i, t]
+        accs.append(common.balanced_mod(acc, m))
 
     digits = common.garner_digits(accs, plan)
     if out_rep == "f64":
@@ -94,70 +106,65 @@ def _stencil_kernel(c_res_ref, u_hi_p, u_lo_p, u_hi_c, u_lo_c, u_hi_n, u_lo_n,
         out_ref[...] = common.stack_digits_int8(digits)
 
 
-@functools.partial(jax.jit, static_argnames=("plan", "out_rep", "bz", "interpret"))
+@functools.partial(jax.jit, static_argnames=("plan", "out_rep", "bx", "interpret"))
 def stencil7(u: jax.Array, c: jax.Array, plan: ozaki2.Plan,
-             out_rep: str = "f64", bz: int = 8, interpret: bool = True) -> jax.Array:
+             out_rep: str = "f64", bx: int = 1, interpret: bool = True) -> jax.Array:
     X, Y, Z = u.shape
     f64 = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
-    bz = min(bz, Z)
-    pz = (-Z) % bz
+    bx = min(bx, X)
+    px = (-X) % bx
     ui, su = _global_scale_to_int(u.astype(f64), plan.payload_bits)
     ci, sc = _global_scale_to_int(c.astype(f64), plan.payload_bits)
     u_hi, u_lo = splitting.split_hi_lo(ui)
-    if pz:
-        u_hi = jnp.pad(u_hi, ((0, 0), (0, 0), (0, pz)))
-        u_lo = jnp.pad(u_lo, ((0, 0), (0, 0), (0, pz)))
+    if px:
+        # Zero planes past X are the zero halo the last real plane sees.
+        u_hi = jnp.pad(u_hi, ((0, px), (0, 0), (0, 0)))
+        u_lo = jnp.pad(u_lo, ((0, px), (0, 0), (0, 0)))
     c_hi, c_lo = splitting.split_hi_lo(ci)
-    c_res = jnp.stack(common.residues_int32(c_hi, c_lo, plan.moduli)).astype(jnp.int8)
+    c_res = jnp.stack(common.residues_int32(c_hi, c_lo, plan.moduli))
 
-    Zp = Z + pz
-    z_steps = Zp // bz
-    grid = (z_steps,)
+    Xp = X + px
+    x_steps = Xp // bx
+    zero = common.ZERO
+    # x-blocked slabs of whole (Y, Z) planes (z on the lanes); the x halo is
+    # one plane on each side, clamped at the ends and masked in the kernel.
+    cur = pl.BlockSpec((bx, Y, Z), lambda k: (k, zero, zero))
+    prev = pl.BlockSpec((1, Y, Z),
+                        lambda k: (jnp.maximum(k * bx - 1, 0), zero, zero))
+    nxt = pl.BlockSpec((1, Y, Z),
+                       lambda k: (jnp.minimum((k + 1) * bx, Xp - 1), zero, zero))
+    in_specs = [pl.BlockSpec((plan.r, 7), lambda k: (zero, zero),
+                             memory_space=pltpu.SMEM),
+                prev, prev, cur, cur, nxt, nxt]
 
-    def spec(offset):
-        # clamped halo slabs: offset -1 (prev), 0 (cur), +1 (next)
-        if offset == -1:
-            return pl.BlockSpec((X, Y, bz),
-                                lambda k: (0, 0, jnp.maximum(k - 1, 0)))
-        if offset == 1:
-            return pl.BlockSpec((X, Y, bz),
-                                lambda k: (0, 0, jnp.minimum(k + 1, z_steps - 1)))
-        return pl.BlockSpec((X, Y, bz), lambda k: (0, 0, k))
-
-    in_specs = [pl.BlockSpec((plan.r, 7), lambda k: (0, 0)),
-                spec(-1), spec(-1), spec(0), spec(0), spec(1), spec(1)]
-
-    if out_rep == "f64":
-        out_shape = jax.ShapeDtypeStruct((X, Y, Zp), jnp.float64)
-        out_spec = pl.BlockSpec((X, Y, bz), lambda k: (0, 0, k))
-    elif out_rep == "ds":
-        out_shape = jax.ShapeDtypeStruct((2, X, Y, Zp), jnp.float32)
-        out_spec = pl.BlockSpec((2, X, Y, bz), lambda k: (0, 0, 0, k))
-    elif out_rep == "digits":
-        out_shape = jax.ShapeDtypeStruct((plan.r, X, Y, Zp), jnp.int8)
-        out_spec = pl.BlockSpec((plan.r, X, Y, bz), lambda k: (0, 0, 0, k))
+    rep = common.kernel_rep(out_rep, interpret)
+    if rep == "f64":
+        out_shape = jax.ShapeDtypeStruct((Xp, Y, Z), jnp.float64)
+        out_spec = cur
+    elif rep == "ds":
+        out_shape = jax.ShapeDtypeStruct((2, Xp, Y, Z), jnp.float32)
+        out_spec = pl.BlockSpec((2, bx, Y, Z), lambda k: (zero, k, zero, zero))
     else:
-        raise ValueError(f"out_rep must be one of {common.OUT_REPS}")
+        out_shape = jax.ShapeDtypeStruct((plan.r, Xp, Y, Z), jnp.int8)
+        out_spec = pl.BlockSpec((plan.r, bx, Y, Z),
+                                lambda k: (zero, k, zero, zero))
 
-    kernel = functools.partial(_stencil_kernel, plan=plan, out_rep=out_rep,
-                               z_steps=z_steps)
+    kernel = functools.partial(_stencil_kernel, plan=plan, out_rep=rep,
+                               x_steps=x_steps)
     raw = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(x_steps,),
         in_specs=in_specs,
         out_specs=out_spec,
         out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(c_res, u_hi, u_lo, u_hi, u_lo, u_hi, u_lo)
 
-    if out_rep == "f64":
-        v = raw[:, :, :Z]
-    elif out_rep == "ds":
-        v = (raw[0].astype(f64) + raw[1].astype(f64))[:, :, :Z]
-    else:
-        v = common.digits_to_f64(common.unstack_digits(raw), plan,
-                                 out_dtype=f64)[:, :, :Z]
-    return jnp.ldexp(v, jnp.broadcast_to(-(su + sc), v.shape))
+    v = common.finish(raw, plan, rep, f64)[..., :X, :, :]
+    return splitting.ldexp(v, -(su + sc))
 
 
 @functools.partial(jax.jit, static_argnames=("plan", "out_rep"))
@@ -165,11 +172,11 @@ def stencil7_ref(u: jax.Array, c: jax.Array, plan: ozaki2.Plan,
                  out_rep: str = "f64") -> jax.Array:
     """Unfused jnp reference of the fused stencil kernel, bit-identical.
 
-    Same Phase-1 global scaling, hi/lo split, zero-halo neighbourhood
-    (``_roll_mask`` is shared with the kernel), residues, per-modulus 7-term
-    contraction, Garner digits, and reconstruction epilogue as ``stencil7`` —
-    every integer step is exact and point-local, so the result matches the
-    Pallas path bit-for-bit regardless of z-blocking.  This is the ``xla``
+    Same Phase-1 global scaling, hi/lo split, residues, zero-halo
+    neighbourhood, per-modulus 7-term contraction, Garner digits, and
+    reconstruction epilogue as ``stencil7`` — every integer step is exact
+    and point-local, so the result matches the Pallas path bit-for-bit
+    regardless of x-blocking.  This is the ``xla``
     route of ``repro.core.dispatch.stencil7``.
     """
     f64 = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
@@ -178,29 +185,24 @@ def stencil7_ref(u: jax.Array, c: jax.Array, plan: ozaki2.Plan,
     u_hi, u_lo = splitting.split_hi_lo(ui)
     c_hi, c_lo = splitting.split_hi_lo(ci)
     c_res = common.residues_int32(c_hi, c_lo, plan.moduli)
-
-    def neighborhood(arr):
-        # Global-array version of the kernel's halo'd stack: the z neighbours
-        # come from jnp.roll with the same boundary masking the kernel applies
-        # to its first/last slab.
-        return jnp.stack([
-            arr,
-            _roll_mask(arr, 0, 1), _roll_mask(arr, 0, -1),
-            _roll_mask(arr, 1, 1), _roll_mask(arr, 1, -1),
-            _roll_mask(arr, 2, 1), _roll_mask(arr, 2, -1),
-        ], axis=0)  # (7, X, Y, Z)
-
-    nb_hi = neighborhood(u_hi).reshape(7, -1)
-    nb_lo = neighborhood(u_lo).reshape(7, -1)
-    u_res = common.residues_int32(nb_hi, nb_lo, plan.moduli)
+    u_res = common.residues_int32(u_hi, u_lo, plan.moduli)
 
     accs = []
     for i, m in enumerate(plan.moduli):
-        # (1, 7) x (7, npts) int32 contraction: |sum| <= 7 * 128 * 128, exact.
-        part = jnp.tensordot(c_res[i].reshape(1, 7), u_res[i], axes=(1, 0))
-        accs.append(common.balanced_mod(part.reshape(u.shape), m))
+        # The kernel's order: residues once per point, then the zero-halo
+        # neighbours of the residue grid.  |sum| <= 7 * 128 * 128, exact.
+        res = u_res[i]
+        nbs = (res, _roll_mask(res, 0, 1), _roll_mask(res, 0, -1),
+               _roll_mask(res, 1, 1), _roll_mask(res, 1, -1),
+               _roll_mask(res, 2, 1), _roll_mask(res, 2, -1))
+        acc = nbs[0] * c_res[i][0]
+        for t in range(1, 7):
+            acc = acc + nbs[t] * c_res[i][t]
+        accs.append(common.balanced_mod(acc, m))
 
-    digits = common.garner_digits(accs, plan)
+    # Barriers around the Garner step, as in ``ozaki2.garner_reconstruct``.
+    accs = jax.lax.optimization_barrier(accs)
+    digits = jax.lax.optimization_barrier(common.garner_digits(accs, plan))
     if out_rep in ("f64", "digits"):
         v = common.digits_to_f64(digits, plan, out_dtype=f64)
     elif out_rep == "ds":
@@ -208,4 +210,4 @@ def stencil7_ref(u: jax.Array, c: jax.Array, plan: ozaki2.Plan,
         v = hi.astype(f64) + lo.astype(f64)
     else:
         raise ValueError(f"out_rep must be one of {common.OUT_REPS}")
-    return jnp.ldexp(v, jnp.broadcast_to(-(su + sc), v.shape))
+    return splitting.ldexp(v, -(su + sc))

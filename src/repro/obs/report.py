@@ -102,7 +102,7 @@ def _builtin_sweep() -> None:
     for mode in ("xla", "pallas"):
         dispatch.matmul(a, b, mode=mode)
         dispatch.matmul(a, v, mode=mode)
-        dispatch.stencil7(u, c, bz=4, mode=mode)
+        dispatch.stencil7(u, c, bx=4, mode=mode)
         dispatch.spmv(val, col, x, plan=plan_r7, br=8, mode=mode)
         dispatch.attention(q, kq, vq, mask=causal, mode=mode)
     compensated.compensated_dot(jnp.asarray(rng.standard_normal(4096)),

@@ -49,21 +49,40 @@ def dft_stacked(x: jax.Array, inverse: bool = False,
     Dense single-GEMM below ``dft.DENSE_MAX`` (and for prime n); Bailey
     four-step with recursive factor transforms above it.
     """
-    n, batch = x.shape
+    x = jnp.asarray(x)
+    yr, yi = dft_stacked_parts(jnp.real(x).astype(dft.working_float()),
+                               jnp.imag(x).astype(dft.working_float()),
+                               inverse=inverse, mode=mode)
+    return jax.lax.complex(yr, yi)
+
+
+def dft_stacked_parts(xr: jax.Array, xi: jax.Array, inverse: bool = False,
+                      mode: Optional[str] = None) -> Tuple[jax.Array, jax.Array]:
+    """``dft_stacked`` on the real and imaginary parts, each (n, batch) real.
+
+    The recursion carries the two parts as real arrays: XLA:TPU emulates
+    complex128 on float64 pairs and refuses some of its reshapes, so the
+    transforms keep complex numbers at their API boundary only.
+    """
+    n, batch = xr.shape
     if n <= 1:
-        return x.astype(dft.working_complex())
+        return xr, xi
     factors = choose_factors(n) if n > dft.DENSE_MAX else None
     if factors is None:
-        return dft.dft_dense(x, inverse=inverse, mode=mode)
+        return dft.dft_dense_parts(xr, xi, inverse=inverse, mode=mode)
     n1, n2 = factors
 
     # Step 1+2: column DFTs of the (n1, n2) view, batched as one GEMM.
-    a = x.reshape(n1, n2 * batch)
-    b = dft_stacked(a, inverse=inverse, mode=mode)
+    br, bi = dft_stacked_parts(xr.reshape(n1, n2 * batch),
+                               xi.reshape(n1, n2 * batch),
+                               inverse=inverse, mode=mode)
     # Step 3: twiddle scaling (elementwise complex, working precision).
-    b = b.reshape(n1, n2, batch) * dft.twiddle(n, n1, n2, inverse)[:, :, None]
+    wr, wi = (w[:, :, None] for w in dft.twiddle(n, n1, n2, inverse))
+    br, bi = br.reshape(n1, n2, batch), bi.reshape(n1, n2, batch)
+    br, bi = br * wr - bi * wi, br * wi + bi * wr
     # Step 4: transpose, then row DFTs as the second GEMM pass.
-    c = jnp.moveaxis(b, 1, 0).reshape(n2, n1 * batch)
-    d = dft_stacked(c, inverse=inverse, mode=mode)
+    dr, di = dft_stacked_parts(jnp.moveaxis(br, 1, 0).reshape(n2, n1 * batch),
+                               jnp.moveaxis(bi, 1, 0).reshape(n2, n1 * batch),
+                               inverse=inverse, mode=mode)
     # Step 5: the output is read transposed: X[k2·n1 + k1] = D[k2, k1].
-    return d.reshape(n, batch)
+    return dr.reshape(n, batch), di.reshape(n, batch)

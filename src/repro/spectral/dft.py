@@ -24,7 +24,7 @@ emulated GEMM then reproduces the correctly-rounded FP64 contraction.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -74,7 +74,10 @@ CACHE_MAX = 4 * DENSE_MAX
 def _build_realified(n: int, inverse: bool, dtype_name: str) -> jax.Array:
     f = dft_matrix(n, inverse)
     blk = np.block([[f.real, -f.imag], [f.imag, f.real]])
-    return jnp.asarray(blk, dtype=jnp.dtype(dtype_name))
+    # A concrete array even when first built inside a jit trace: the cache
+    # outlives the trace.
+    with jax.ensure_compile_time_eval():
+        return jnp.asarray(blk, dtype=jnp.dtype(dtype_name))
 
 
 @functools.lru_cache(maxsize=None)
@@ -101,20 +104,24 @@ TWIDDLE_CACHE_MAX = 1 << 16
 
 
 def _build_twiddle(n: int, n1: int, n2: int, inverse: bool,
-                   dtype_name: str) -> jax.Array:
+                   dtype_name: str) -> Tuple[jax.Array, jax.Array]:
     w = _roots_of_unity(np.arange(n1), np.arange(n2), n, inverse)
-    return jnp.asarray(w, dtype=jnp.dtype(dtype_name))
+    dt = jnp.dtype(dtype_name)
+    with jax.ensure_compile_time_eval():   # as in _build_realified
+        return jnp.asarray(w.real, dtype=dt), jnp.asarray(w.imag, dtype=dt)
 
 
 @functools.lru_cache(maxsize=None)
 def _twiddle(n: int, n1: int, n2: int, inverse: bool,
-             dtype_name: str) -> jax.Array:
+             dtype_name: str) -> Tuple[jax.Array, jax.Array]:
     """(n1, n2) four-step twiddle W[k1, j2] = omega_n^(±k1·j2), device-cached."""
     return _build_twiddle(n, n1, n2, inverse, dtype_name)
 
 
-def twiddle(n: int, n1: int, n2: int, inverse: bool = False) -> jax.Array:
-    dtype_name = jnp.dtype(working_complex()).name
+def twiddle(n: int, n1: int, n2: int,
+            inverse: bool = False) -> Tuple[jax.Array, jax.Array]:
+    """Real and imaginary parts of the (n1, n2) four-step twiddle table."""
+    dtype_name = jnp.dtype(working_float()).name
     if n > TWIDDLE_CACHE_MAX:
         return _build_twiddle(int(n), int(n1), int(n2), bool(inverse),
                               dtype_name)
@@ -127,17 +134,16 @@ def cache_clear() -> None:
     _twiddle.cache_clear()
 
 
-def dft_dense(x: jax.Array, inverse: bool = False,
-              mode: Optional[str] = None) -> jax.Array:
-    """Unnormalised DFT along axis 0 of a stacked (n, batch) complex operand.
+def dft_dense_parts(xr: jax.Array, xi: jax.Array, inverse: bool = False,
+                    mode: Optional[str] = None) -> Tuple[jax.Array, jax.Array]:
+    """Unnormalised DFT along axis 0 of a stacked (n, batch) operand given as
+    its real and imaginary parts.
 
     One realified GEMM through the dispatch layer: stack real over imag parts
     into a (2n, batch) real operand, multiply by the cached (2n, 2n) block
-    operator, and re-interleave the halves as the complex result.
+    operator, and split the halves into the result's parts.
     """
-    n = x.shape[0]
-    wf = working_float()
+    n = xr.shape[0]
     op = realified_dft(n, inverse)
-    xb = jnp.concatenate([jnp.real(x), jnp.imag(x)], axis=0).astype(wf)
-    out = dispatch.matmul(op, xb, mode=mode)
-    return jax.lax.complex(out[:n], out[n:]).astype(working_complex())
+    out = dispatch.matmul(op, jnp.concatenate([xr, xi], axis=0), mode=mode)
+    return out[:n], out[n:]

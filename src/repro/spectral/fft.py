@@ -5,6 +5,9 @@ All transforms compose the stacked axis-0 DFT of ``bailey.dft_stacked``:
   * ``fft2`` / ``fftn``   — multi-dimensional transforms by axis composition,
   * ``rfft`` / ``irfft``  — real-input / Hermitian-output transforms.
 
+``fft_parts`` / ``ifft_parts`` take and return the real and imaginary parts
+instead of complex arrays, for backends without complex128 (XLA:TPU).
+
 Normalisation follows numpy/jax: ``fft`` is unnormalised, ``ifft`` carries the
 1/n factor, ``irfft(rfft(x), n) == x``.  ``mode`` forwards to the dispatch
 layer (None inherits ``REPRO_DISPATCH`` / ``dispatch.mode_scope``), so a single
@@ -18,17 +21,34 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.spectral import bailey, dft
 
 
+def _apply_parts(xr: jax.Array, xi: jax.Array, axis: int, inverse: bool,
+                 mode: Optional[str], norm: int = 1
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """DFT along ``axis`` (over ``norm``) of the real and imaginary parts:
+    move the axis to the front, flatten the rest as batch."""
+    wf = dft.working_float()
+    parts = []
+    for part in (xr, xi):
+        part = jnp.moveaxis(jnp.asarray(part).astype(wf), axis, 0)
+        parts.append(part.reshape(part.shape[0], -1))
+    yr, yi = bailey.dft_stacked_parts(*parts, inverse=inverse, mode=mode)
+    shp = parts[0].shape[:1] + tuple(np.delete(np.shape(xr), axis))
+    yr, yi = (jnp.moveaxis(y.reshape(shp), 0, axis) for y in (yr, yi))
+    if norm != 1:
+        yr, yi = yr / norm, yi / norm
+    return yr, yi
+
+
 def _apply_along_axis(x: jax.Array, axis: int, inverse: bool,
-                      mode: Optional[str]) -> jax.Array:
-    """DFT along ``axis``: move it to the front, flatten the rest as batch."""
-    x = jnp.moveaxis(jnp.asarray(x), axis, 0).astype(dft.working_complex())
-    shp = x.shape
-    out = bailey.dft_stacked(x.reshape(shp[0], -1), inverse=inverse, mode=mode)
-    return jnp.moveaxis(out.reshape(shp), 0, axis)
+                      mode: Optional[str], norm: int = 1) -> jax.Array:
+    x = jnp.asarray(x)
+    return jax.lax.complex(*_apply_parts(jnp.real(x), jnp.imag(x), axis,
+                                         inverse, mode, norm))
 
 
 def fft(x: jax.Array, axis: int = -1, mode: Optional[str] = None) -> jax.Array:
@@ -40,7 +60,26 @@ def ifft(x: jax.Array, axis: int = -1, mode: Optional[str] = None) -> jax.Array:
     """Inverse DFT along ``axis`` with the 1/n normalisation."""
     x = jnp.asarray(x)
     n = x.shape[axis]
-    return _apply_along_axis(x, axis, inverse=True, mode=mode) / n
+    return _apply_along_axis(x, axis, inverse=True, mode=mode, norm=n)
+
+
+def fft_parts(xr: jax.Array, xi: jax.Array, axis: int = -1,
+              mode: Optional[str] = None) -> Tuple[jax.Array, jax.Array]:
+    """``fft`` on a complex operand given as its real and imaginary parts,
+    returning the parts of the transform.
+
+    For backends without complex128: XLA:TPU compiles no complex128 op at
+    all (even taking the real part aborts the compiler), so on a TPU the
+    transforms are called this way.  ``fft`` wraps this same computation.
+    """
+    return _apply_parts(xr, xi, axis, inverse=False, mode=mode)
+
+
+def ifft_parts(xr: jax.Array, xi: jax.Array, axis: int = -1,
+               mode: Optional[str] = None) -> Tuple[jax.Array, jax.Array]:
+    """``ifft`` on real and imaginary parts (see ``fft_parts``)."""
+    return _apply_parts(xr, xi, axis, inverse=True, mode=mode,
+                        norm=np.shape(xr)[axis])
 
 
 def _resolve_axes(ndim: int, axes: Optional[Sequence[int]]) -> Tuple[int, ...]:

@@ -6,8 +6,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import backend, numerics
 from repro.core import compensated as C
-from repro.core import numerics
 
 RNG = np.random.default_rng(5)
 
@@ -65,6 +65,22 @@ def test_compensated_norm_overflow_underflow_safe():
     np.testing.assert_allclose(float(C.compensated_norm(tiny)),
                                np.sqrt(5.0) * 1e-300, rtol=1e-12)
     assert float(C.compensated_norm(jnp.zeros(8))) == 0.0
+
+
+@pytest.mark.parametrize("scale", [1.0, 1e200, 1e-200])
+def test_compensated_norm_float32_pair_path(monkeypatch, scale):
+    """The path taken where float64 is a float32 pair (XLA:TPU): no 64-bit
+    bit fields, the scale from log2 of the largest magnitude.  On the CPU it
+    must keep the IEEE path's contract: no overflow or flush at extreme
+    scales, per-axis results, zeros and non-finite flags."""
+    monkeypatch.setattr(backend, "float64_is_f32_pair", lambda: True)
+    x = RNG.standard_normal((3, 1000))
+    want = np.sqrt([math.fsum(v * v for v in row) for row in x]) * scale
+    got = np.asarray(C.compensated_norm(jnp.asarray(x * scale), axis=1))
+    np.testing.assert_allclose(got, want, rtol=4 * 2.0 ** -53)
+    assert float(C.compensated_norm(jnp.zeros(8))) == 0.0
+    assert float(C.compensated_norm(jnp.asarray([1.0, np.inf]))) == np.inf
+    assert math.isnan(float(C.compensated_norm(jnp.asarray([np.nan, 1.0]))))
 
 
 def test_neumaier_vs_fsum_property():

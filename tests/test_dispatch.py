@@ -272,7 +272,7 @@ def test_mode_scope_flips_stencil_route(monkeypatch):
         calls.append("xla")
         return real_ref(*a, **kw)
 
-    def pallas_spy(u, c, plan, out_rep="f64", bz=8, interpret=True):
+    def pallas_spy(u, c, plan, out_rep="f64", bx=1, interpret=True):
         calls.append("pallas")
         assert interpret == dispatch.pallas_interpret("stencil7")
         return real_ref(u, c, plan, out_rep=out_rep)
@@ -313,7 +313,7 @@ def test_stencil_routes_bit_identical():
     c = jnp.asarray(RNG.standard_normal(7))
     for rep in ("f64", "digits", "ds"):
         v_xla = np.asarray(dispatch.stencil7(u, c, out_rep=rep, mode="xla"))
-        v_pal = np.asarray(dispatch.stencil7(u, c, out_rep=rep, bz=4,
+        v_pal = np.asarray(dispatch.stencil7(u, c, out_rep=rep, bx=4,
                                              mode="pallas"))
         np.testing.assert_array_equal(v_xla, v_pal)
 

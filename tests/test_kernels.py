@@ -100,16 +100,16 @@ def test_gemv_matches_gemm_kernel():
 # 7-point stencil (Algorithm 2)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("shape,bz", [
+@pytest.mark.parametrize("shape,bx", [
     ((12, 10, 20), 4),
     ((8, 8, 8), 8),      # single slab
-    ((6, 7, 13), 4),     # ragged z: padding path
+    ((6, 7, 13), 4),     # ragged x: padding path
 ])
 @pytest.mark.parametrize("out_rep", ["f64", "digits"])
-def test_stencil_accuracy_sweep(shape, bz, out_rep):
+def test_stencil_accuracy_sweep(shape, bx, out_rep):
     u = jnp.asarray(RNG.standard_normal(shape))
     c = jnp.asarray(np.array([6.0, -1.0, -1.0, -1.0, -1.0, -1.0, -1.0]))
-    v = ops.ozaki_stencil7(u, c, out_rep=out_rep, bz=bz)
+    v = ops.ozaki_stencil7(u, c, out_rep=out_rep, bx=bx)
     want = np.asarray(ref.stencil7_f64(u, c))
     scale = 7 * np.max(np.abs(np.asarray(u))) * np.max(np.abs(np.asarray(c)))
     assert np.max(np.abs(np.asarray(v) - want)) <= 8 * U64 * scale
@@ -120,7 +120,7 @@ def test_stencil_boundary_zero_halo():
     """Points on the global boundary must see a zero halo, not wraparound."""
     u = jnp.asarray(np.ones((4, 4, 8)))
     c = jnp.asarray(np.array([0.0, 0.0, 0.0, 0.0, 0.0, 1.0, 0.0]))  # pure -z shift
-    v = np.asarray(ops.ozaki_stencil7(u, c, bz=4))
+    v = np.asarray(ops.ozaki_stencil7(u, c, bx=4))
     assert np.all(v[:, :, 0] == 0.0)   # first plane has no -z neighbour
     assert np.all(v[:, :, 1:] == 1.0)
 
@@ -128,7 +128,7 @@ def test_stencil_boundary_zero_halo():
 def test_stencil_anisotropic_coeffs():
     u = jnp.asarray(RNG.standard_normal((8, 8, 8)))
     c = jnp.asarray(RNG.standard_normal(7))
-    v = np.asarray(ops.ozaki_stencil7(u, c, bz=4))
+    v = np.asarray(ops.ozaki_stencil7(u, c, bx=4))
     want = np.asarray(ref.stencil7_f64(u, c))
     scale = float(7 * jnp.max(jnp.abs(u)) * jnp.max(jnp.abs(c)))
     assert np.max(np.abs(v - want)) <= 8 * U64 * scale

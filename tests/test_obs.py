@@ -139,7 +139,7 @@ def test_all_dispatch_kinds_record(tmp_path):
     with obs.telemetry_scope("counters"):
         dispatch.matmul(a, b, mode="xla")
         dispatch.matmul(a, v, mode="xla")
-        dispatch.stencil7(u, c, bz=4, mode="xla")
+        dispatch.stencil7(u, c, bx=4, mode="xla")
         dispatch.spmv(val, col, x, plan=plan_r7, br=8, mode="xla")
         dispatch.attention(q, kq, vq, mode="xla")
         compensated.compensated_dot(x, x)
@@ -208,7 +208,7 @@ def test_jit_bit_identical_and_silent(op):
     elif op == "stencil7":
         u = jnp.asarray(rng.standard_normal((8, 8, 8)))
         c = jnp.asarray(np.array([6.0, -1, -1, -1, -1, -1, -1]))
-        fn = jax.jit(lambda u, c: dispatch.stencil7(u, c, bz=4, mode="xla"))
+        fn = jax.jit(lambda u, c: dispatch.stencil7(u, c, bx=4, mode="xla"))
         args = (u, c)
     elif op == "attention":
         q = jnp.asarray(rng.standard_normal((16, 8)))

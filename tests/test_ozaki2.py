@@ -3,6 +3,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import backend
 from repro.core import moduli as M
 from repro.core import ozaki2
 
@@ -90,6 +91,21 @@ def test_garner_against_python_bigint():
     ])
     got = np.asarray(ozaki2.garner_reconstruct(jnp.asarray(cres), plan))
     np.testing.assert_array_equal(got, vals.astype(np.float64))
+
+
+@pytest.mark.parametrize("k", [64, 4096])   # r = 15 and r = 16 moduli
+def test_float32_pair_epilogue(monkeypatch, k):
+    """Where float64 is a float32 pair (XLA:TPU) the digits run the
+    double-single Horner: finite for the largest prefix products of a
+    16-modulus plan (~2**116, where a Veltkamp split overflows float32) and
+    accurate to the ~45 bits that double-single carries."""
+    monkeypatch.setattr(backend, "float64_is_f32_pair", lambda: True)
+    a = RNG.standard_normal((12, k))
+    b = RNG.standard_normal((k, 6))
+    c = np.asarray(ozaki2.emulated_matmul(jnp.asarray(a), jnp.asarray(b),
+                                          ozaki2.make_plan(k)))
+    assert np.all(np.isfinite(c))
+    assert _relerr(c, a, b) <= 2.0 ** -44
 
 
 def test_modular_matmul_congruence():

@@ -15,10 +15,7 @@ def _fake_mesh(data=16, model=16, pod=None):
         sizes, names = (pod, data, model), ("pod", "data", "model")
     else:
         sizes, names = (data, model), ("data", "model")
-    try:
-        return AbstractMesh(sizes, names)            # jax >= 0.5 signature
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))  # 0.4.x: shape_tuple
+    return AbstractMesh(sizes, names)
 
 
 def _specs_for(arch, layout="tp", mesh=None):

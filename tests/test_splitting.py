@@ -1,5 +1,6 @@
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import splitting as S
 from repro.core.moduli import DEFAULT_MODULI, SPLIT_RADIX
@@ -62,6 +63,33 @@ def test_scale_to_int_zero_rows():
     xi, shift = S.scale_to_int(x, 53, axis=-1)
     assert np.all(np.asarray(xi) == 0)
     assert np.all(np.isfinite(np.asarray(shift)))
+
+
+@pytest.mark.parametrize("dtype", [np.float64, np.float32])
+def test_ldexp_same_bits_as_jnp_ldexp(dtype):
+    """The exact scaling that XLA:TPU lowers gives jnp.ldexp's bits on the
+    CPU for zero and normal x and any shift: results that stay normal, that
+    fall below the normal range, and that overflow."""
+    fi = np.finfo(dtype)
+    emax = fi.maxexp - 1
+    sig = RNG.choice([-1.0, 1.0], 20000) * RNG.uniform(1.0, 2.0, 20000)
+    x = np.ldexp(sig, RNG.integers(fi.minexp, emax - 1, 20000)).astype(dtype)
+    x[:100] = 0.0
+    assert np.all((np.abs(x) >= fi.tiny) | (x == 0)) and np.all(np.isfinite(x))
+    e = RNG.integers(-3 * emax, 3 * emax, 20000).astype(np.int32)
+    got = np.asarray(S.ldexp(jnp.asarray(x), jnp.asarray(e)))
+    want = np.asarray(jnp.ldexp(jnp.asarray(x), jnp.asarray(e)))
+    assert got.dtype == dtype
+    np.testing.assert_array_equal(got.view(f"u{got.itemsize}"),
+                                  want.view(f"u{want.itemsize}"))
+
+
+@pytest.mark.parametrize("dtype", [np.float64, np.float32])
+def test_pow2_exact_over_normal_range(dtype):
+    fi = np.finfo(dtype)
+    e = np.arange(fi.minexp, fi.maxexp, dtype=np.int32)
+    np.testing.assert_array_equal(np.asarray(S.pow2(jnp.asarray(e), dtype)),
+                                  np.ldexp(np.ones(e.shape, dtype), e))
 
 
 def test_apply_unscale_exact_pow2():

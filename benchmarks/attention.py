@@ -1,13 +1,14 @@
-"""Fused emulated attention benchmarks — the seam's fifth kind, both routes.
+"""Emulated attention benchmarks — the seam's fifth kind, both routes.
 
 Rows (name,us_per_call,derived,route,shape_class):
   kernel_attention/route_<mode>/us        — prefill (S = T) wall-clock per
                                             route; derived on both rows of the
                                             pair = max |pallas - xla| over the
                                             outputs, expected exactly 0 (the
-                                            FlashAttention-style fused kernel
-                                            and the seam-GEMM reference are
-                                            bit-identical by construction).
+                                            online-softmax scan over the Pallas
+                                            GEMM kernels and over
+                                            emulated_matmul are bit-identical
+                                            by construction).
   kernel_attention/decode_route_<mode>/us — same contract at the serving
                                             decode shape (S = 1 against a T
                                             deep cache).

@@ -3,8 +3,20 @@
 Every branch that differs between a TPU and the other backends keys on
 ``name()``: the dispatch layer's auto routes and Pallas flavour (Mosaic or
 the interpreter), and the float64 arithmetic that has to change on a TPU.
-XLA:TPU emulates float64 as a pair of float32 values: about 49 significand
-bits, float32's exponent range, and no 64-bit bitcasts.
+
+XLA:TPU emulates float64 as a pair of float32 values.  Measured on a TPU v5e
+(phase 0 of ``chip_smoke.py``):
+
+  * storage keeps 53 significand bits: integers up to 2^53 - 1 and 1 + 2^-52
+    round-trip exactly;
+  * the exponent range is float32's, 2^-149 to 2^127: 2^-1022 comes back 0
+    and 2^1000 comes back inf;
+  * one arithmetic op errs by up to 4.58e-14 relative (sqrt; div 3.88e-14,
+    add 5.47e-15), so the device's unit roundoff is about 2^-44.3, not 2^-53;
+  * ``exp`` over a one-element float64 array errs at float32 level (1.7e-8);
+    over (128,), (1, 128), (2, 128) or (8, 128) it errs by 4e-15 to 9e-15;
+  * there are no 64-bit bitcasts, so ``jnp.ldexp`` and bit-field tricks on
+    float64 do not compile (``splitting.ldexp`` replaces the former).
 """
 
 from __future__ import annotations

@@ -203,7 +203,7 @@ TUNE_TABLE: Dict[Tuple[str, str], Dict[str, Any]] = {
     ("gemv", "*"): {"bm": 128, "bk": 256},
     ("spmv_bell", "*"): {"br": 128},
     ("stencil7", "*"): {"bx": 1},
-    ("attention", "*"): {"bq": 128, "bkv": 128},
+    ("attention", "*"): {"bkv": 128},
     ("reduce", "*"): {"block": 512},
     # Measured on CPU (f64 compensated_dot sweep): short vectors are
     # dispatch-bound and flat across blocks; >=64k-element reductions favor
@@ -534,7 +534,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               plan_pv: Optional[ozaki2.Plan] = None,
               payload_bits: int = 53, substrate: str = "int8",
               mode: Optional[str] = None) -> jax.Array:
-    """Fused emulated attention out = softmax(mask(QKᵀ/√D + softcap)) V.
+    """Emulated attention out = softmax(mask(QKᵀ/√D + softcap)) V.
 
     q: (..., S, D) queries, k/v: (..., T, D) keys/values; leading dims (batch,
     heads, ...) are flattened and mapped.  ``mask`` is None (attend to all),
@@ -542,11 +542,12 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     ``softcap`` > 0 applies the tanh logit cap between scaling and masking
     (the models' score order).  Returns working-float (..., S, D).
 
-    Routing follows ``choose_route(plan_qk, "attention", mode)``: the pallas
-    route is the FlashAttention-style fused kernel whose QKᵀ and PV products
-    ride the Ozaki-II residue pipeline inside one online-softmax scan; the
-    xla route is the bit-identical ``attention_ref`` composed from the seam
-    GEMMs at the same kv-blocking.  ``plan_qk`` covers the length-D score
+    Both routes run one FlashAttention-style online-softmax scan over kv
+    blocks (``ozaki_attention``).  Routing follows ``choose_route(plan_qk,
+    "attention", mode)``: the pallas route computes each block's QKᵀ and PV
+    on the Pallas GEMM/GEMV kernels (``_pallas_matmul``); the xla route,
+    bit-identical, on ``ozaki2.emulated_matmul``.  Each block's scores and
+    probabilities go through HBM on both.  ``plan_qk`` covers the length-D score
     contraction, ``plan_pv`` the length-bkv probability-value contraction;
     both resolve from the plan cache when omitted.  Telemetry records the
     op with a ``prefill`` (S > 1) or ``decode`` (S = 1) label so the two
@@ -561,8 +562,6 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     for d in lead:
         B *= int(d)
     tune = get_tuning("attention", (B, S, D, T))
-    bq = min(_round_up(int(tune.get("bq", 128)), SUBLANE),
-             _round_up(S, SUBLANE))
     bkv = min(_round_up(int(tune.get("bkv", 128)), SUBLANE),
               _round_up(T, SUBLANE))
     if plan_qk is None:
@@ -585,10 +584,9 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if route == "pallas":
         def one(args):
             qi, ki, vi, mi = args
-            return _attn.attention_fused(
-                qi, ki, vi, mi, plan_qk, plan_pv, softcap=softcap, bq=bq,
-                bkv=bkv, interpret=pallas_interpret("attention"),
-                out_dtype=wf)
+            return _attn.attention_pallas_gemms(
+                qi, ki, vi, mi, plan_qk, plan_pv, _pallas_matmul,
+                softcap=softcap, bkv=bkv, out_dtype=wf)
     else:
         def one(args):
             qi, ki, vi, mi = args

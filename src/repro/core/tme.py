@@ -183,7 +183,7 @@ def op_costs(kind: str, dims: Tuple[int, ...]) -> Tuple[float, float, float]:
     if kind == "attention":
         # (B, S, D, T) — B independent rows of S queries against T keys at
         # head dim D; a bare 3-tuple (S, D, T) means B = 1.  W counts the
-        # QK^T + PV products (2·2·S·T·D each row); Q is the fused-path f64
+        # QK^T + PV products (2·2·S·T·D each row); Q is the native op's f64
         # traffic: q + out (S·D each) and k + v (T·D each); n_out counts the
         # Garner reconstructions (S·T scores + S·D outputs per row).
         if len(dims) == 3:
@@ -237,15 +237,14 @@ def attention_emulated_time(dims: Tuple[int, ...], r: int = 10,
                             alpha: Optional[float] = None,
                             substrate: str = "int8", route: str = "xla",
                             spec: Optional[ChipSpec] = None) -> float:
-    """TME-predicted seconds for the fused attention kind, per route.
+    """TME-predicted seconds for the attention kind, per route.
 
-    The pallas route is the FlashAttention-style scan: scores and
-    probabilities never leave registers/VMEM, so it is priced like the other
-    fused kernels (β = 1 over the q/k/v/out traffic, γ per reconstruction).
-    The xla reference composes seam GEMMs per kv block and *materialises*
-    the S and P matrices (2·8·B·S·T bytes); that extra traffic is charged
-    on top of the residue-plane β = r multiplier (added as q_scores/r so the
-    β factor restores it to one full f64 pass each way).
+    Both routes run the online-softmax scan over per-kv-block GEMMs and
+    *materialise* each block's S and P (2·8·B·S·T bytes in all).  The pallas
+    route's GEMMs are the fused kernels, so residues stay in VMEM (β = 1)
+    and S/P are charged one f64 pass each.  The xla route's GEMMs write r
+    residue planes (β = r); S/P are added as q_scores/r so the β factor
+    restores them to one f64 pass each.
     """
     if spec is None:
         spec = default_chip()
@@ -256,11 +255,11 @@ def attention_emulated_time(dims: Tuple[int, ...], r: int = 10,
     if alpha is None:
         alpha = float(r) if substrate == "int8" else 3.0 * r
     gamma = garner_gamma(spec, r)
+    q_scores = 2.0 * 8.0 * B * S * T
     if route == "pallas":
         params = EmulationParams(alpha=float(alpha), beta=1.0, gamma=gamma,
                                  substrate=substrate)
-        return emulated_time(W, Q, n_out, spec, params)
-    q_scores = 2.0 * 8.0 * B * S * T
+        return emulated_time(W, Q + q_scores, n_out, spec, params)
     params = EmulationParams(alpha=float(alpha), beta=float(r), gamma=gamma,
                              substrate=substrate)
     return emulated_time(W, Q + q_scores / float(r), n_out, spec, params)

@@ -7,13 +7,11 @@ reconstruction runs on the int32 accumulators before the store.
 
 Output representations (the one place the TPU adaptation pays a real cost, since
 Mosaic has no float64 type):
-  f64    — full in-kernel double-double Garner.  Bit-equivalent to the XLA reference;
-           valid in interpret mode only (the interpreter evaluates float64).  Under
-           Mosaic an "f64" request writes ``digits`` instead (``kernel_rep``) and XLA
-           finishes the same reconstruction (``finish``), so the result is unchanged.
-  digits — TPU-production mode: the kernel stores the r balanced mixed-radix digits
-           as int8 (r bytes/output vs 8 for f64) and a cheap bandwidth-bound XLA
-           epilogue finishes the double-double Horner.  β_out = r/8.
+  f64    — the working-float result, bit-equivalent to the XLA reference: the
+           kernel writes ``digits`` (``kernel_rep``) and XLA finishes (``finish``).
+  digits — the kernel stores the r balanced mixed-radix digits as int8 (r
+           bytes/output vs 8 for f64) and a cheap bandwidth-bound XLA epilogue
+           finishes the double-double Horner.  β_out = r/8.
   ds     — two-float32 double-single output (8 B/output, β_out = 1) with ~49-bit
            accuracy: full-bandwidth mode for consumers that tolerate 2^-45 error.
 
@@ -41,19 +39,17 @@ OUT_REPS = ("f64", "digits", "ds")
 ZERO = np.int32(0)
 
 
-def kernel_rep(out_rep: str, interpret: bool) -> str:
-    """What the kernel body writes for a requested ``out_rep``: Mosaic has no
-    float64 type, so a compiled kernel writes the digits of an "f64" request
-    and ``finish`` runs the same reconstruction in XLA."""
+def kernel_rep(out_rep: str) -> str:
+    """What a kernel writes for a requested ``out_rep``, "digits" or "ds":
+    Mosaic has no float64 type, so kernels write the digits of an "f64"
+    request and ``finish`` reconstructs them in XLA."""
     if out_rep not in OUT_REPS:
         raise ValueError(f"out_rep must be one of {OUT_REPS}, got {out_rep!r}")
-    return "digits" if out_rep == "f64" and not interpret else out_rep
+    return "ds" if out_rep == "ds" else "digits"
 
 
 def finish(raw: jax.Array, plan: ozaki2.Plan, rep: str, dtype) -> jax.Array:
     """Epilogue in XLA: a kernel's raw ``rep`` output -> working float."""
-    if rep == "f64":
-        return raw
     if rep == "ds":
         return raw[0].astype(dtype) + raw[1].astype(dtype)
     return digits_to_f64(unstack_digits(raw), plan, out_dtype=dtype)

@@ -60,7 +60,7 @@ def ozaki_gemm(a: jax.Array, b: jax.Array, plan: Optional[ozaki2.Plan] = None,
     a_hi, a_lo = _pad2(a_hi, bm, bk), _pad2(a_lo, bm, bk)
     b_hi, b_lo = _pad2(b_hi, bk, bn), _pad2(b_lo, bk, bn)
 
-    rep = common.kernel_rep(out_rep, interpret)
+    rep = common.kernel_rep(out_rep)
     raw = _gemm.gemm_hilo(a_hi, a_lo, b_hi, b_lo, plan, out_rep=rep,
                           bm=bm, bn=bn, bk=bk, interpret=interpret)
     c = common.finish(raw, plan, rep, f64)[..., :M, :N]
@@ -87,7 +87,7 @@ def ozaki_gemv(a: jax.Array, x: jax.Array, plan: Optional[ozaki2.Plan] = None,
     a_hi, a_lo = _pad2(a_hi, bm, bk), _pad2(a_lo, bm, bk)
     x_hi, x_lo = _pad2(x_hi, bk, B), _pad2(x_lo, bk, B)
 
-    rep = common.kernel_rep(out_rep, interpret)
+    rep = common.kernel_rep(out_rep)
     raw = _gemv.gemv_hilo(a_hi, a_lo, x_hi, x_lo, plan, out_rep=rep,
                           bm=bm, bk=bk, interpret=interpret)
     y = common.finish(raw, plan, rep, f64)[..., :M, :B]

@@ -1,35 +1,22 @@
-"""Fused Ozaki-II attention Pallas kernel (FlashAttention scan over slice GEMMs).
+"""Ozaki-II attention: a FlashAttention-style scan over emulated block GEMMs.
 
-Attention is the layer-3 dwarf the serving stack actually spends its time in,
-and the shape the paper's register-fusion argument (§5.1) is sharpest about:
-the (S, T) score and probability matrices are pure intermediates, so a fused
-online-softmax scan that keeps them resident in VMEM turns the whole op
-memory-bound in q/k/v/out alone (β = 1), while the unfused composition of seam
-GEMMs must round-trip r residue planes *and* the materialised S/P matrices
-through HBM.
+Both routes run one online-softmax scan over kv blocks of ``bkv`` rows, with
+the running (max, normaliser, accumulator) state per query row in the working
+float.  Each block's QKᵀ and PV are emulated products; the routes differ only
+in what computes them:
 
-TPU mapping of the fused scan:
-  * q and k arrive pre-scaled per row over the head dimension, v per
-    (kv-block, column) over the block — exactly the scaling granularity the
-    per-block reference GEMMs use, which is what makes the two routes
-    bit-identical;
-  * each grid step loads one (bq, D) q-tile against one (bkv, D) k/v-tile,
-    computes QKᵀ through the int8 residue pipeline (residues in VMEM, one
-    int8×int8→int32 MXU contraction per modulus, balanced-digit Garner),
-    applies scale/softcap/mask, folds the block into the running
-    (m, l, acc) online-softmax state, and feeds the block's probabilities
-    straight back through a second residue pipeline for PV;
-  * the only stores are the final acc / l — no S/P matrix ever exists at
-    full size.
+  * ``attention_ref`` (xla route) — ``ozaki2.emulated_matmul``;
+  * ``attention_pallas_gemms`` (pallas route) — the product the dispatch
+    layer passes in, its Pallas GEMM/GEMV kernels (Mosaic on a TPU).
+
+Each block's (S, bkv) scores and probabilities go through HBM on both routes.
+A single fused kernel that keeps them in VMEM would need its softmax state in
+float64, which Mosaic does not have.
 
 Bit-identity contract (the dispatch seam's invariant, verified by
-tests/test_attention.py): ``attention_ref`` composes ``ozaki2.emulated_matmul``
-per kv-block with the *same* block size, padding, scaling axes, and shared
-``_masked_scores``/``_online_update`` helpers, so both routes perform the same
-float operations in the same order on the same exact integer products.  The
-in-kernel f64 epilogue runs in interpret mode only: compiled for a TPU, the
-pallas route runs the scan in XLA with each block product on the fused GEMM
-kernel (see ``attention_fused``).
+tests/test_attention.py): the block products are bit-identical across routes,
+and everything around them is the same code (``_block_scan``), so both routes
+perform the same float operations in the same order.
 """
 
 from __future__ import annotations
@@ -39,11 +26,8 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import dispatch, ozaki2, splitting
-from repro.kernels import common
+from repro.core import ozaki2
 
 # Finite stand-in for -inf (matches repro.models.attention.NEG_INF): keeps the
 # online-softmax state NaN-free for fully-masked rows on both routes.
@@ -82,7 +66,7 @@ def _online_update(s: jax.Array, m: jax.Array, l: jax.Array):
 
 
 # ---------------------------------------------------------------------------
-# XLA reference: the same scan composed from the seam GEMMs
+# The scan, and the two routes over it
 # ---------------------------------------------------------------------------
 
 def _block_scan(q, k, v, mask, plan_qk, plan_pv, softcap, bkv, out_dtype,
@@ -94,7 +78,7 @@ def _block_scan(q, k, v, mask, plan_qk, plan_pv, softcap, bkv, out_dtype,
     # Query rows padded to a sublane multiple.  Rows are independent (q and p
     # scale per row), so padding changes no real row; it keeps a decode step
     # (S = 1) from carrying its softmax state as one-element float64 arrays,
-    # which XLA:TPU computes at float32 precision.
+    # whose exp XLA:TPU computes at float32 precision (see repro.core.backend).
     S = -(-S0 // 8) * 8
     q = jnp.pad(q.astype(out_dtype), ((0, S - S0), (0, 0)))
     tp = -(-T // bkv) * bkv
@@ -128,15 +112,11 @@ def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array,
                   plan_qk: ozaki2.Plan, plan_pv: ozaki2.Plan,
                   softcap: float = 0.0, bkv: int = 128,
                   out_dtype=jnp.float64) -> jax.Array:
-    """Bit-identical reference for ``attention_fused`` built from seam GEMMs.
+    """The xla route: the scan with its block products on
+    ``ozaki2.emulated_matmul``.
 
     q: (S, D), k/v: (T, D), mask: (S, T) (int8 or bool; nonzero = attend).
-    Scans kv-blocks of ``bkv`` rows in kernel order, computing each block's
-    QKᵀ and PV products with ``ozaki2.emulated_matmul`` — the same exact
-    integer products and reconstruction the fused kernel performs in VMEM,
-    at the same scaling granularity (q/k per row over D; p per row and v per
-    column over the block).  Bit-identical to the fused kernel for any
-    (bq, bkv) blocking, the same way ``stencil7_ref`` is for x-blocking.
+    q/k scale per row over D; p per row and v per column over the block.
     """
     def product(a, b, plan):
         return ozaki2.emulated_matmul(a, b, plan, out_dtype=out_dtype)
@@ -144,153 +124,18 @@ def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array,
                        out_dtype, product)
 
 
-# ---------------------------------------------------------------------------
-# Fused Pallas kernel
-# ---------------------------------------------------------------------------
+@functools.partial(jax.jit, static_argnames=("plan_qk", "plan_pv", "product",
+                                             "softcap", "bkv", "out_dtype"))
+def attention_pallas_gemms(q: jax.Array, k: jax.Array, v: jax.Array,
+                           mask: jax.Array, plan_qk: ozaki2.Plan,
+                           plan_pv: ozaki2.Plan, product,
+                           softcap: float = 0.0, bkv: int = 128,
+                           out_dtype=jnp.float64) -> jax.Array:
+    """The pallas route: the scan with its block products on ``product(a, b,
+    plan)``, the dispatch layer's Pallas GEMM/GEMV kernels.
 
-def _attn_kernel(q_hi_ref, q_lo_ref, sq_ref, k_hi_ref, k_lo_ref, sk_ref,
-                 v_hi_ref, v_lo_ref, sv_ref, mask_ref, out_ref,
-                 m_ref, l_ref, acc_ref, *, plan_qk: ozaki2.Plan,
-                 plan_pv: ozaki2.Plan, softcap: float, inv_sqrt_d: float,
-                 kv_steps: int, out_dtype):
-    jidx = pl.program_id(1)
-
-    @pl.when(jidx == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    # QK^T slice product: residues of the freshly-loaded (hi, lo) tiles stay
-    # in VMEM/VREGs; one int8 MXU contraction per modulus; Garner before use.
-    q_res = common.residues_int32(q_hi_ref[...], q_lo_ref[...], plan_qk.moduli)
-    k_res = common.residues_int32(k_hi_ref[...], k_lo_ref[...], plan_qk.moduli)
-    accs = []
-    for i, mod in enumerate(plan_qk.moduli):
-        part = jax.lax.dot_general(
-            q_res[i].astype(jnp.int8), k_res[i].astype(jnp.int8),
-            (((1,), (1,)), ((), ())), preferred_element_type=jnp.int32)
-        accs.append(common.balanced_mod(part, mod))
-    digits = common.garner_digits(accs, plan_qk)
-    s_int = common.digits_to_f64(digits, plan_qk, out_dtype=out_dtype)
-    s_prod = splitting.apply_unscale(s_int, sq_ref[...][:, 0], sk_ref[...][:, 0])
-
-    s = _masked_scores(s_prod, mask_ref[...] != 0, softcap, inv_sqrt_d)
-    p, corr, m_new, l_new = _online_update(s, m_ref[...][:, 0], l_ref[...][:, 0])
-    m_ref[...] = m_new[:, None]
-    l_ref[...] = l_new[:, None]
-
-    # PV slice product: the block's probabilities decompose in-kernel (Phase-1
-    # scaling per row over bkv — the reference GEMM's granularity) and ride a
-    # second residue pipeline against the pre-scaled v tile.
-    pi, sp = splitting.scale_to_int(p, plan_pv.payload_bits, axis=-1)
-    p_hi, p_lo = splitting.split_hi_lo(pi)
-    p_res = common.residues_int32(p_hi, p_lo, plan_pv.moduli)
-    v_res = common.residues_int32(v_hi_ref[...], v_lo_ref[...], plan_pv.moduli)
-    accs = []
-    for i, mod in enumerate(plan_pv.moduli):
-        part = jax.lax.dot_general(
-            p_res[i].astype(jnp.int8), v_res[i].astype(jnp.int8),
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
-        accs.append(common.balanced_mod(part, mod))
-    digits = common.garner_digits(accs, plan_pv)
-    pv_int = common.digits_to_f64(digits, plan_pv, out_dtype=out_dtype)
-    pv = splitting.apply_unscale(pv_int, sp, sv_ref[...][0])
-
-    acc_ref[...] = acc_ref[...] * corr[:, None] + pv
-
-    @pl.when(jidx == kv_steps - 1)
-    def _epilogue():
-        out_ref[...] = acc_ref[...] / l_ref[...]
-
-
-def _pad_rows(x: jax.Array, mult: int) -> jax.Array:
-    pad = (-x.shape[0]) % mult
-    return jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1)) if pad else x
-
-
-@functools.partial(jax.jit, static_argnames=("plan_qk", "plan_pv", "softcap",
-                                             "bq", "bkv", "interpret",
-                                             "out_dtype"))
-def attention_fused(q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array,
-                    plan_qk: ozaki2.Plan, plan_pv: ozaki2.Plan,
-                    softcap: float = 0.0, bq: int = 128, bkv: int = 128,
-                    interpret: bool = True, out_dtype=jnp.float64) -> jax.Array:
-    """Fused emulated attention: out = softmax(mask(QKᵀ/√D)) V in one scan.
-
-    q: (S, D), k/v: (T, D), mask: (S, T) (nonzero = attend).  Grid is
-    (S/bq, T/bkv) with the kv axis innermost; the (m, l, acc) online-softmax
-    state lives in VMEM scratch across the kv sweep.  Zero-padding of S, T,
-    and D to block multiples is exact (padded rows/cols scale with shift 0 and
-    contribute zero residues; padded key columns are masked), so the unpadded
-    region is bit-identical to ``attention_ref`` at the same ``bkv``.
-
-    Mosaic has no float64, so a compiled kernel cannot hold the softmax
-    state.  With ``interpret=False`` the same scan runs in XLA instead, each
-    block product on the fused GEMM kernel (``dispatch.matmul`` on the pallas
-    route), and the result is still the reference's.
+    Same arguments as ``attention_ref``, and bit-identical to it: the kernels
+    give ``emulated_matmul``'s bits.
     """
-    if not interpret:
-        def product(a, b, plan):
-            return dispatch.matmul(a, b, plan=plan, mode="pallas")
-        return _block_scan(q, k, v, mask, plan_qk, plan_pv, softcap, bkv,
-                           out_dtype, product)
-    S, D = q.shape
-    T = k.shape[0]
-    inv_sqrt_d = 1.0 / math.sqrt(D)
-    dp = -(-D // 128) * 128
-    tp = -(-T // bkv) * bkv
-    sp_ = -(-S // bq) * bq
-    kv_steps = tp // bkv
-
-    # Phase-1 scaling at the reference GEMMs' granularity, *before* padding
-    # (rows/blocks are whole either way, so the shifts are identical).
-    qi, sq = splitting.scale_to_int(q.astype(out_dtype),
-                                    plan_qk.payload_bits, axis=-1)
-    ki, sk = splitting.scale_to_int(k.astype(out_dtype),
-                                    plan_qk.payload_bits, axis=-1)
-    vp = _pad_rows(v.astype(out_dtype), bkv).reshape(kv_steps, bkv, D)
-    vi, sv = splitting.scale_to_int(vp, plan_pv.payload_bits, axis=1)
-
-    def hilo(xi, rows, cols):
-        hi, lo = splitting.split_hi_lo(xi)
-        padder = lambda a: jnp.pad(a, ((0, rows - a.shape[0]),
-                                       (0, cols - a.shape[1])))
-        return padder(hi), padder(lo)
-
-    q_hi, q_lo = hilo(qi, sp_, dp)
-    k_hi, k_lo = hilo(ki, tp, dp)
-    v_hi, v_lo = hilo(vi.reshape(tp, D), tp, dp)
-    sq_p = jnp.pad(sq, (0, sp_ - S)).reshape(sp_, 1)
-    sk_p = jnp.pad(sk, (0, tp - T)).reshape(tp, 1)
-    sv_p = jnp.pad(sv, ((0, 0), (0, dp - D)))
-    mask_p = jnp.pad(mask.astype(jnp.int8), ((0, sp_ - S), (0, tp - T)))
-
-    grid = (sp_ // bq, kv_steps)
-    in_specs = [
-        pl.BlockSpec((bq, dp), lambda i, j: (i, 0)),      # q_hi
-        pl.BlockSpec((bq, dp), lambda i, j: (i, 0)),      # q_lo
-        pl.BlockSpec((bq, 1), lambda i, j: (i, 0)),       # sq
-        pl.BlockSpec((bkv, dp), lambda i, j: (j, 0)),     # k_hi
-        pl.BlockSpec((bkv, dp), lambda i, j: (j, 0)),     # k_lo
-        pl.BlockSpec((bkv, 1), lambda i, j: (j, 0)),      # sk
-        pl.BlockSpec((bkv, dp), lambda i, j: (j, 0)),     # v_hi
-        pl.BlockSpec((bkv, dp), lambda i, j: (j, 0)),     # v_lo
-        pl.BlockSpec((1, dp), lambda i, j: (j, 0)),       # sv
-        pl.BlockSpec((bq, bkv), lambda i, j: (i, j)),     # mask
-    ]
-    kernel = functools.partial(_attn_kernel, plan_qk=plan_qk, plan_pv=plan_pv,
-                               softcap=softcap, inv_sqrt_d=inv_sqrt_d,
-                               kv_steps=kv_steps, out_dtype=out_dtype)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((bq, dp), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((sp_, dp), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bq, 1), out_dtype),
-                        pltpu.VMEM((bq, 1), out_dtype),
-                        pltpu.VMEM((bq, dp), out_dtype)],
-        interpret=interpret,
-    )(q_hi, q_lo, sq_p, k_hi, k_lo, sk_p, v_hi, v_lo, sv_p, mask_p)
-    return out[:S, :D]
+    return _block_scan(q, k, v, mask, plan_qk, plan_pv, softcap, bkv,
+                       out_dtype, product)

@@ -49,9 +49,7 @@ def _gemm_kernel(a_hi_ref, a_lo_ref, b_hi_ref, b_lo_ref, out_ref, acc_ref, *,
     @pl.when(kidx == np.int32(k_steps - 1))
     def _epilogue():
         digits = common.garner_digits([acc_ref[i] for i in range(plan.r)], plan)
-        if out_rep == "f64":
-            out_ref[...] = common.digits_to_f64(digits, plan)
-        elif out_rep == "ds":
+        if out_rep == "ds":
             hi, lo = common.digits_to_ds(digits, plan)
             out_ref[0] = hi
             out_ref[1] = lo
@@ -62,13 +60,14 @@ def _gemm_kernel(a_hi_ref, a_lo_ref, b_hi_ref, b_lo_ref, out_ref, acc_ref, *,
 @functools.partial(jax.jit, static_argnames=("plan", "out_rep", "bm", "bn", "bk",
                                              "interpret"))
 def gemm_hilo(a_hi: jax.Array, a_lo: jax.Array, b_hi: jax.Array, b_lo: jax.Array,
-              plan: ozaki2.Plan, out_rep: str = "f64",
+              plan: ozaki2.Plan, out_rep: str = "digits",
               bm: int = 128, bn: int = 128, bk: int = 256,
               interpret: bool = True) -> jax.Array:
     """Raw kernel entry on pre-scaled (hi, lo) operands.  Shapes must tile evenly.
 
-    Returns: f64 (M,N) | ds f32 (2,M,N) | digits int8 (r,M,N) — the *integer-scaled*
-    product; callers apply the exact power-of-two unscale.
+    Returns: ds f32 (2,M,N) | digits int8 (r,M,N) — the *integer-scaled*
+    product; callers finish it (``common.finish``) and apply the exact
+    power-of-two unscale.
     """
     M, K = a_hi.shape
     K2, N = b_hi.shape
@@ -83,17 +82,14 @@ def gemm_hilo(a_hi: jax.Array, a_lo: jax.Array, b_hi: jax.Array, b_lo: jax.Array
         pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
         pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
     ]
-    if out_rep == "f64":
-        out_shape = jax.ShapeDtypeStruct((M, N), jnp.float64)
-        out_spec = pl.BlockSpec((bm, bn), lambda i, j, k: (i, j))
-    elif out_rep == "ds":
+    if out_rep == "ds":
         out_shape = jax.ShapeDtypeStruct((2, M, N), jnp.float32)
         out_spec = pl.BlockSpec((2, bm, bn), lambda i, j, k: (common.ZERO, i, j))
     elif out_rep == "digits":
         out_shape = jax.ShapeDtypeStruct((plan.r, M, N), jnp.int8)
         out_spec = pl.BlockSpec((plan.r, bm, bn), lambda i, j, k: (common.ZERO, i, j))
     else:
-        raise ValueError(f"out_rep must be one of {common.OUT_REPS}")
+        raise ValueError(f"out_rep must be 'digits' or 'ds', got {out_rep!r}")
 
     kernel = functools.partial(_gemm_kernel, plan=plan, out_rep=out_rep,
                                k_steps=k_steps)

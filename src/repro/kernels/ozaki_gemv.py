@@ -47,9 +47,7 @@ def _gemv_kernel(a_hi_ref, a_lo_ref, x_hi_ref, x_lo_ref, out_ref, acc_ref, *,
     @pl.when(kidx == np.int32(k_steps - 1))
     def _epilogue():
         digits = common.garner_digits([acc_ref[i] for i in range(plan.r)], plan)
-        if out_rep == "f64":
-            out_ref[...] = common.digits_to_f64(digits, plan)
-        elif out_rep == "ds":
+        if out_rep == "ds":
             hi, lo = common.digits_to_ds(digits, plan)
             out_ref[0] = hi
             out_ref[1] = lo
@@ -60,7 +58,7 @@ def _gemv_kernel(a_hi_ref, a_lo_ref, x_hi_ref, x_lo_ref, out_ref, acc_ref, *,
 @functools.partial(jax.jit, static_argnames=("plan", "out_rep", "bm", "bk",
                                              "interpret"))
 def gemv_hilo(a_hi: jax.Array, a_lo: jax.Array, x_hi: jax.Array, x_lo: jax.Array,
-              plan: ozaki2.Plan, out_rep: str = "f64", bm: int = 128,
+              plan: ozaki2.Plan, out_rep: str = "digits", bm: int = 128,
               bk: int = 256, interpret: bool = True) -> jax.Array:
     M, N = a_hi.shape
     _, B = x_hi.shape
@@ -74,17 +72,14 @@ def gemv_hilo(a_hi: jax.Array, a_lo: jax.Array, x_hi: jax.Array, x_lo: jax.Array
         pl.BlockSpec((bk, B), lambda i, k: (k, common.ZERO)),
         pl.BlockSpec((bk, B), lambda i, k: (k, common.ZERO)),
     ]
-    if out_rep == "f64":
-        out_shape = jax.ShapeDtypeStruct((M, B), jnp.float64)
-        out_spec = pl.BlockSpec((bm, B), lambda i, k: (i, common.ZERO))
-    elif out_rep == "ds":
+    if out_rep == "ds":
         out_shape = jax.ShapeDtypeStruct((2, M, B), jnp.float32)
         out_spec = pl.BlockSpec((2, bm, B), lambda i, k: (common.ZERO, i, common.ZERO))
     elif out_rep == "digits":
         out_shape = jax.ShapeDtypeStruct((plan.r, M, B), jnp.int8)
         out_spec = pl.BlockSpec((plan.r, bm, B), lambda i, k: (common.ZERO, i, common.ZERO))
     else:
-        raise ValueError(f"out_rep must be one of {common.OUT_REPS}")
+        raise ValueError(f"out_rep must be 'digits' or 'ds', got {out_rep!r}")
 
     kernel = functools.partial(_gemv_kernel, plan=plan, out_rep=out_rep,
                                k_steps=k_steps)

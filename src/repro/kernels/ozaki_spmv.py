@@ -42,9 +42,7 @@ def _spmv_kernel(av_hi_ref, av_lo_ref, xg_hi_ref, xg_lo_ref, out_ref, *,
             jnp.sum(prod, axis=0, keepdims=True, dtype=jnp.int32), m))
 
     digits = common.garner_digits(accs, plan)   # (1, br) each
-    if out_rep == "f64":
-        out_ref[...] = common.digits_to_f64(digits, plan)
-    elif out_rep == "ds":
+    if out_rep == "ds":
         hi, lo = common.digits_to_ds(digits, plan)
         out_ref[0] = hi
         out_ref[1] = lo
@@ -135,11 +133,8 @@ def spmv_bell(a_val: jax.Array, a_col: jax.Array, x: jax.Array,
     zero = common.ZERO
     blk = pl.BlockSpec((bw, br), lambda i: (zero, i))
 
-    rep = common.kernel_rep(out_rep, interpret)
-    if rep == "f64":
-        out_shape = jax.ShapeDtypeStruct((1, Mp), jnp.float64)
-        out_spec = pl.BlockSpec((1, br), lambda i: (zero, i))
-    elif rep == "ds":
+    rep = common.kernel_rep(out_rep)
+    if rep == "ds":
         out_shape = jax.ShapeDtypeStruct((2, 1, Mp), jnp.float32)
         out_spec = pl.BlockSpec((2, 1, br), lambda i: (zero, zero, i))
     else:

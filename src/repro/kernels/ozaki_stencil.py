@@ -12,8 +12,8 @@ tile (re-reads hit the same HBM pages the neighbouring programs stream anyway; t
 paper's §5.3 traffic model already counts them as cached).  Global-boundary planes
 are masked to the zero halo inside the kernel.
 
-HBM traffic per output: 8 B in (hi+lo int32) + 8 B out (f64 mode) — exactly the
-native-FP64 footprint, β = 1 (out_rep="digits" pays r/8 instead, see common.py).
+HBM traffic per output: 8 B in (hi+lo int32) + r B of int8 digits out (8 B for
+out_rep="ds"), see common.py.
 """
 
 from __future__ import annotations
@@ -96,9 +96,7 @@ def _stencil_kernel(c_res_ref, u_hi_p, u_lo_p, u_hi_c, u_lo_c, u_hi_n, u_lo_n,
         accs.append(common.balanced_mod(acc, m))
 
     digits = common.garner_digits(accs, plan)
-    if out_rep == "f64":
-        out_ref[...] = common.digits_to_f64(digits, plan)
-    elif out_rep == "ds":
+    if out_rep == "ds":
         hi, lo = common.digits_to_ds(digits, plan)
         out_ref[0] = hi
         out_ref[1] = lo
@@ -137,11 +135,8 @@ def stencil7(u: jax.Array, c: jax.Array, plan: ozaki2.Plan,
                              memory_space=pltpu.SMEM),
                 prev, prev, cur, cur, nxt, nxt]
 
-    rep = common.kernel_rep(out_rep, interpret)
-    if rep == "f64":
-        out_shape = jax.ShapeDtypeStruct((Xp, Y, Z), jnp.float64)
-        out_spec = cur
-    elif rep == "ds":
+    rep = common.kernel_rep(out_rep)
+    if rep == "ds":
         out_shape = jax.ShapeDtypeStruct((2, Xp, Y, Z), jnp.float32)
         out_spec = pl.BlockSpec((2, bx, Y, Z), lambda k: (zero, k, zero, zero))
     else:

@@ -5,8 +5,8 @@ All transforms compose the stacked axis-0 DFT of ``bailey.dft_stacked``:
   * ``fft2`` / ``fftn``   — multi-dimensional transforms by axis composition,
   * ``rfft`` / ``irfft``  — real-input / Hermitian-output transforms.
 
-``fft_parts`` / ``ifft_parts`` take and return the real and imaginary parts
-instead of complex arrays, for backends without complex128 (XLA:TPU).
+``fft_parts`` takes and returns the real and imaginary parts instead of
+complex arrays, for backends without complex128 (XLA:TPU).
 
 Normalisation follows numpy/jax: ``fft`` is unnormalised, ``ifft`` carries the
 1/n factor, ``irfft(rfft(x), n) == x``.  ``mode`` forwards to the dispatch
@@ -73,13 +73,6 @@ def fft_parts(xr: jax.Array, xi: jax.Array, axis: int = -1,
     transforms are called this way.  ``fft`` wraps this same computation.
     """
     return _apply_parts(xr, xi, axis, inverse=False, mode=mode)
-
-
-def ifft_parts(xr: jax.Array, xi: jax.Array, axis: int = -1,
-               mode: Optional[str] = None) -> Tuple[jax.Array, jax.Array]:
-    """``ifft`` on real and imaginary parts (see ``fft_parts``)."""
-    return _apply_parts(xr, xi, axis, inverse=True, mode=mode,
-                        norm=np.shape(xr)[axis])
 
 
 def _resolve_axes(ndim: int, axes: Optional[Sequence[int]]) -> Tuple[int, ...]:

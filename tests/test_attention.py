@@ -1,8 +1,8 @@
-"""Fused emulated attention on the dispatch seam.
+"""Emulated attention on the dispatch seam.
 
 The contract of ``docs/dispatch-seam.md``, verified for the fifth kind:
-cross-route bit-identity (the FlashAttention-style Pallas scan vs the
-reference composed from seam GEMMs), FP64-oracle parity, and mode-flipping
+cross-route bit-identity (the online-softmax scan over the Pallas GEMM
+kernels vs over ``emulated_matmul``), FP64-oracle parity, and mode-flipping
 end-to-end from the models/ and serve/ layers down to ``dispatch.attention``.
 """
 
@@ -89,8 +89,8 @@ def test_attention_batched_leading_dims_both_routes():
 
 @pytest.mark.parametrize("softcap", [0.0, 30.0])
 def test_attention_matches_fp64_oracle(softcap):
-    """The seam-GEMM reference (and therefore, by bit-identity, the fused
-    kernel) matches a plain jnp-free FP64 softmax-attention oracle to well
+    """The xla route (and therefore, by bit-identity, the pallas route)
+    matches a plain jnp-free FP64 softmax-attention oracle to well
     under 1e-12 — the QK^T and PV products are exact, only the softmax
     transcendentals differ in evaluation order."""
     q, k, v = _qkv(16, 16, 8)
@@ -113,8 +113,8 @@ def test_attention_no_mask_means_attend_all():
 
 def _spy_attention_routes(monkeypatch):
     """Replace both attention routes with recorders, delegating to the real
-    reference so callers still get correct outputs (the fused interpreter at
-    model shapes would dominate the fast lane otherwise)."""
+    reference so callers still get correct outputs (the interpreted GEMM
+    kernels at model shapes would dominate the fast lane otherwise)."""
     from repro.kernels import ozaki_attention
 
     calls = []
@@ -124,15 +124,15 @@ def _spy_attention_routes(monkeypatch):
         calls.append("xla")
         return real_ref(*a, **kw)
 
-    def pallas_spy(q, k, v, mask, plan_qk, plan_pv, softcap=0.0, bq=128,
-                   bkv=128, interpret=True, out_dtype=jnp.float64):
+    def pallas_spy(q, k, v, mask, plan_qk, plan_pv, product, softcap=0.0,
+                   bkv=128, out_dtype=jnp.float64):
         calls.append("pallas")
-        assert interpret == dispatch.pallas_interpret("attention")
+        assert product is dispatch._pallas_matmul
         return real_ref(q, k, v, mask, plan_qk, plan_pv, softcap=softcap,
                         bkv=bkv, out_dtype=out_dtype)
 
     monkeypatch.setattr(ozaki_attention, "attention_ref", ref_spy)
-    monkeypatch.setattr(ozaki_attention, "attention_fused", pallas_spy)
+    monkeypatch.setattr(ozaki_attention, "attention_pallas_gemms", pallas_spy)
     return calls
 
 
@@ -174,7 +174,7 @@ def test_model_attention_rides_the_seam(monkeypatch):
 
 
 def test_serve_decode_attention_rides_the_seam(monkeypatch):
-    """The engine's dispatch_mode pin reaches the fused attention kind inside
+    """The engine's dispatch_mode pin reaches the attention kind inside
     the jitted decode step (the spy fires at trace time)."""
     from repro.configs import registry
     from repro.models.transformer import Model

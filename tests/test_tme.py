@@ -218,9 +218,9 @@ def test_predict_op_time_route_beta_ordering():
 
 
 def test_attention_emulated_time_routes_and_orders():
-    """The fused kind's prediction: the xla route pays the materialised S/P
-    matrices (β = r reference GEMMs), the pallas route streams them through
-    the online-softmax scan (β = 1) — so xla ≥ pallas, and predict_op_time
+    """Both routes pay the materialised S/P matrices once; the xla route's
+    GEMMs also write r residue planes (β = r against the pallas GEMM
+    kernels' β = 1) — so xla ≥ pallas ≥ the S/P-free op, and predict_op_time
     delegates to attention_emulated_time for kind="attention"."""
     dims = (1, 64, 32, 64)
     t_xla = tme.attention_emulated_time(dims, r=15, route="xla",
@@ -228,6 +228,11 @@ def test_attention_emulated_time_routes_and_orders():
     t_pal = tme.attention_emulated_time(dims, r=15, route="pallas",
                                         spec=tme.TPU_V5E)
     assert 0.0 < t_pal < t_xla
+    W, Q, n_out = tme.op_costs("attention", dims)
+    params = tme.EmulationParams(alpha=15.0, beta=1.0,
+                                 gamma=tme.garner_gamma(tme.TPU_V5E, 15),
+                                 substrate="int8")
+    assert t_pal > tme.emulated_time(W, Q, n_out, tme.TPU_V5E, params)
     assert tme.predict_op_time("attention", dims, r=15, route="xla",
                                spec=tme.TPU_V5E) == pytest.approx(t_xla)
     assert tme.predict_op_time("attention", dims, r=15, route="pallas",

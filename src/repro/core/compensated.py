@@ -67,7 +67,7 @@ import jax.numpy as jnp
 
 from repro.core import backend, splitting
 from repro.core.numerics import fast_two_sum, two_prod, two_sum  # noqa: F401
-from repro.obs import telemetry as obs
+from repro.obs import spans, telemetry as obs
 
 __all__ = ["two_sum", "two_prod", "fast_two_sum", "neumaier_sum",
            "compensated_dot", "compensated_norm", "neumaier_sum_scan",
@@ -126,13 +126,14 @@ def _carry_scan(s_b: jax.Array, c_b: jax.Array) -> jax.Array:
 def _blocked_sum2(p: jax.Array, e: jax.Array, block: int) -> jax.Array:
     """Compensated sum of p (+ pre-existing error stream e) along the last
     axis: block tree → per-block partials → carry scan."""
-    p = _pad_to_blocks(p, block)
-    e = _pad_to_blocks(e, block)
-    nb = p.shape[-1] // block
-    shape = p.shape[:-1] + (nb, block)
-    s_b, c_b = _block_tree(p.reshape(shape), e.reshape(shape))
-    # scan wants the block axis leading; batch dims ride along.
-    return _carry_scan(jnp.moveaxis(s_b, -1, 0), jnp.moveaxis(c_b, -1, 0))
+    with spans.scope("reduce.dot2"):
+        p = _pad_to_blocks(p, block)
+        e = _pad_to_blocks(e, block)
+        nb = p.shape[-1] // block
+        shape = p.shape[:-1] + (nb, block)
+        s_b, c_b = _block_tree(p.reshape(shape), e.reshape(shape))
+        # scan wants the block axis leading; batch dims ride along.
+        return _carry_scan(jnp.moveaxis(s_b, -1, 0), jnp.moveaxis(c_b, -1, 0))
 
 
 def _normalize_axis(axis: int, ndim: int) -> int:

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import compensated, dispatch, ozaki2
-from repro.obs import telemetry as obs
+from repro.obs import spans, telemetry as obs
 
 
 @dataclasses.dataclass
@@ -59,31 +59,39 @@ def cg_solve(matvec: Callable[[jax.Array], jax.Array], b: jax.Array,
     bnorm = norm(b)
     bnorm_plain = jnp.sqrt(jnp.dot(b, b)) if record_plain else None
 
-    history: List[float] = [float(jnp.sqrt(rs) / bnorm)]
+    history: List[float] = [_read(jnp.sqrt(rs) / bnorm)]
     history_plain: List[float] = []
     # Residual-trace telemetry: one event per recorded residual (iteration 0
     # included), so convergence trajectories are observable alongside the
     # per-op seam events the matvec itself records.
     obs.record_event("solver.cg", dims=b.shape, iter=0, rel_residual=history[0])
     if record_plain:
-        history_plain.append(float(jnp.sqrt(jnp.dot(r, r)) / bnorm_plain))
+        history_plain.append(_read(jnp.sqrt(jnp.dot(r, r)) / bnorm_plain))
     it = 0
     for it in range(1, maxiter + 1):
-        ap = matvec(p)
-        alpha = rs / dot(p, ap)
-        x = x + alpha * p
-        r = r - alpha * ap
-        rs_new = dot(r, r)
-        history.append(float(jnp.sqrt(rs_new) / bnorm))
-        obs.record_event("solver.cg", dims=b.shape, iter=it,
-                         rel_residual=history[-1])
-        if record_plain:
-            history_plain.append(float(jnp.sqrt(jnp.dot(r, r)) / bnorm_plain))
-        if history[-1] < tol:
-            return CGResult(x, it, history[-1], True, history, history_plain)
-        p = r + (rs_new / rs) * p
-        rs = rs_new
+        with spans.span("cg.iter"):
+            ap = matvec(p)
+            alpha = rs / dot(p, ap)
+            x = x + alpha * p
+            r = r - alpha * ap
+            rs_new = dot(r, r)
+            history.append(_read(jnp.sqrt(rs_new) / bnorm))
+            obs.record_event("solver.cg", dims=b.shape, iter=it,
+                             rel_residual=history[-1])
+            if record_plain:
+                history_plain.append(_read(jnp.sqrt(jnp.dot(r, r)) / bnorm_plain))
+            if history[-1] < tol:
+                return CGResult(x, it, history[-1], True, history, history_plain)
+            p = r + (rs_new / rs) * p
+            rs = rs_new
     return CGResult(x, it, history[-1], False, history, history_plain)
+
+
+def _read(v: jax.Array) -> float:
+    """A device scalar read on the host: the loop waits here for the device,
+    so each read is a ``sync`` span."""
+    with spans.span("sync"):
+        return float(v)
 
 
 def cg_solve_bell(a_val: jax.Array, a_col: jax.Array, b: jax.Array,

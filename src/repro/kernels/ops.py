@@ -26,6 +26,7 @@ from repro.core import dispatch, ozaki2, splitting
 from repro.kernels import common
 from repro.kernels import ozaki_gemm as _gemm
 from repro.kernels import ozaki_gemv as _gemv
+from repro.obs import spans
 
 
 def _pad2(x: jax.Array, bm: int, bn: int) -> jax.Array:
@@ -53,18 +54,26 @@ def ozaki_gemm(a: jax.Array, b: jax.Array, plan: Optional[ozaki2.Plan] = None,
     bm, bn, bk = min(bm, M), min(bn, N), min(bk, K)
     f64 = _working_f64()
 
-    ai, sa = splitting.scale_to_int(a.astype(f64), plan.payload_bits, axis=-1)
-    bi, sb = splitting.scale_to_int(b.astype(f64), plan.payload_bits, axis=0)
-    a_hi, a_lo = splitting.split_hi_lo(ai)
-    b_hi, b_lo = splitting.split_hi_lo(bi)
-    a_hi, a_lo = _pad2(a_hi, bm, bk), _pad2(a_lo, bm, bk)
-    b_hi, b_lo = _pad2(b_hi, bk, bn), _pad2(b_lo, bk, bn)
+    # Scopes around the statements in their trace order (``repro.obs.spans``).
+    with spans.scope("ozaki.split_a"):
+        ai, sa = splitting.scale_to_int(a.astype(f64), plan.payload_bits, axis=-1)
+    with spans.scope("ozaki.split_b"):
+        bi, sb = splitting.scale_to_int(b.astype(f64), plan.payload_bits, axis=0)
+    with spans.scope("ozaki.split_a"):
+        a_hi, a_lo = splitting.split_hi_lo(ai)
+    with spans.scope("ozaki.split_b"):
+        b_hi, b_lo = splitting.split_hi_lo(bi)
+    with spans.scope("ozaki.split_a"):
+        a_hi, a_lo = _pad2(a_hi, bm, bk), _pad2(a_lo, bm, bk)
+    with spans.scope("ozaki.split_b"):
+        b_hi, b_lo = _pad2(b_hi, bk, bn), _pad2(b_lo, bk, bn)
 
     rep = common.kernel_rep(out_rep)
     raw = _gemm.gemm_hilo(a_hi, a_lo, b_hi, b_lo, plan, out_rep=rep,
                           bm=bm, bn=bn, bk=bk, interpret=interpret)
-    c = common.finish(raw, plan, rep, f64)[..., :M, :N]
-    return splitting.apply_unscale(c, sa, sb)
+    with spans.scope("ozaki.finish"):
+        c = common.finish(raw, plan, rep, f64)[..., :M, :N]
+        return splitting.apply_unscale(c, sa, sb)
 
 
 def ozaki_gemv(a: jax.Array, x: jax.Array, plan: Optional[ozaki2.Plan] = None,
@@ -80,18 +89,26 @@ def ozaki_gemv(a: jax.Array, x: jax.Array, plan: Optional[ozaki2.Plan] = None,
     bm, bk = min(bm, M), min(bk, N)
     f64 = _working_f64()
 
-    ai, sa = splitting.scale_to_int(a.astype(f64), plan.payload_bits, axis=-1)
-    xi, sx = splitting.scale_to_int(x.astype(f64), plan.payload_bits, axis=0)
-    a_hi, a_lo = splitting.split_hi_lo(ai)
-    x_hi, x_lo = splitting.split_hi_lo(xi)
-    a_hi, a_lo = _pad2(a_hi, bm, bk), _pad2(a_lo, bm, bk)
-    x_hi, x_lo = _pad2(x_hi, bk, B), _pad2(x_lo, bk, B)
+    # Scopes around the statements in their trace order (``repro.obs.spans``).
+    with spans.scope("ozaki.split_a"):
+        ai, sa = splitting.scale_to_int(a.astype(f64), plan.payload_bits, axis=-1)
+    with spans.scope("ozaki.split_b"):
+        xi, sx = splitting.scale_to_int(x.astype(f64), plan.payload_bits, axis=0)
+    with spans.scope("ozaki.split_a"):
+        a_hi, a_lo = splitting.split_hi_lo(ai)
+    with spans.scope("ozaki.split_b"):
+        x_hi, x_lo = splitting.split_hi_lo(xi)
+    with spans.scope("ozaki.split_a"):
+        a_hi, a_lo = _pad2(a_hi, bm, bk), _pad2(a_lo, bm, bk)
+    with spans.scope("ozaki.split_b"):
+        x_hi, x_lo = _pad2(x_hi, bk, B), _pad2(x_lo, bk, B)
 
     rep = common.kernel_rep(out_rep)
     raw = _gemv.gemv_hilo(a_hi, a_lo, x_hi, x_lo, plan, out_rep=rep,
                           bm=bm, bk=bk, interpret=interpret)
-    y = common.finish(raw, plan, rep, f64)[..., :M, :B]
-    return splitting.apply_unscale(y, sa, sx)
+    with spans.scope("ozaki.finish"):
+        y = common.finish(raw, plan, rep, f64)[..., :M, :B]
+        return splitting.apply_unscale(y, sa, sx)
 
 
 def ozaki_stencil7(u: jax.Array, c: jax.Array,

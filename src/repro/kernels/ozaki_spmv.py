@@ -27,6 +27,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import ozaki2, splitting
 from repro.kernels import common
 from repro.kernels.ozaki_stencil import _global_scale_to_int
+from repro.obs import spans
 
 
 def _spmv_kernel(av_hi_ref, av_lo_ref, xg_hi_ref, xg_lo_ref, out_ref, *,
@@ -56,10 +57,16 @@ def _decompose_operands(a_val: jax.Array, a_col: jax.Array, x: jax.Array,
     scaling, hi/lo split, column cast.  One implementation keeps the two
     paths' bit-identity structural rather than a testing promise."""
     f64 = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
-    av, sa = splitting.scale_to_int(a_val.astype(f64), plan.payload_bits, axis=-1)
-    xi, sx = _global_scale_to_int(x.astype(f64), plan.payload_bits)
-    av_hi, av_lo = splitting.split_hi_lo(av)
-    x_hi, x_lo = splitting.split_hi_lo(xi)
+    # Scopes around the statements in their trace order (``repro.obs.spans``).
+    with spans.scope("ozaki.split_a"):
+        av, sa = splitting.scale_to_int(a_val.astype(f64), plan.payload_bits,
+                                        axis=-1)
+    with spans.scope("ozaki.split_b"):
+        xi, sx = _global_scale_to_int(x.astype(f64), plan.payload_bits)
+    with spans.scope("ozaki.split_a"):
+        av_hi, av_lo = splitting.split_hi_lo(av)
+    with spans.scope("ozaki.split_b"):
+        x_hi, x_lo = splitting.split_hi_lo(xi)
     return av_hi, av_lo, a_col.astype(jnp.int32), x_hi, x_lo, sa, sx
 
 
@@ -127,8 +134,13 @@ def spmv_bell(a_val: jax.Array, a_col: jax.Array, x: jax.Array,
         a_val, a_col, x, plan)
     # The gather runs in XLA: Mosaic has no general vector gather.  Rows go
     # to the lanes, so a (bw, br) block is lane-dense for any band width.
-    ops = [av_hi, av_lo, x_hi[col], x_lo[col]]
-    ops = [jnp.pad(o, ((0, pm), (0, 0))).T for o in ops]
+    # Scopes around the statements in their trace order (``repro.obs.spans``).
+    with spans.scope("spmv.gather"):
+        xg_hi, xg_lo = x_hi[col], x_lo[col]
+    with spans.scope("ozaki.split_a"):
+        av_hi, av_lo = [jnp.pad(o, ((0, pm), (0, 0))).T for o in (av_hi, av_lo)]
+    with spans.scope("spmv.gather"):
+        xg_hi, xg_lo = [jnp.pad(o, ((0, pm), (0, 0))).T for o in (xg_hi, xg_lo)]
     Mp = M + pm
     zero = common.ZERO
     blk = pl.BlockSpec((bw, br), lambda i: (zero, i))
@@ -150,7 +162,8 @@ def spmv_bell(a_val: jax.Array, a_col: jax.Array, x: jax.Array,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(*ops)
+    )(av_hi, av_lo, xg_hi, xg_lo)
 
-    y = common.finish(raw, plan, rep, f64)[0, :M]
-    return splitting.ldexp(y, -(sa + sx))
+    with spans.scope("ozaki.finish"):
+        y = common.finish(raw, plan, rep, f64)[0, :M]
+        return splitting.ldexp(y, -(sa + sx))

@@ -28,6 +28,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import ozaki2, splitting
 from repro.kernels import common
+from repro.obs import spans
 
 # Scoped VMEM for one x-slab: the r residue accumulators and Garner carries
 # of a 256 x 256 plane take ~20 MiB, past Mosaic's 16 MiB default (a v5e core
@@ -111,15 +112,20 @@ def stencil7(u: jax.Array, c: jax.Array, plan: ozaki2.Plan,
     f64 = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
     bx = min(bx, X)
     px = (-X) % bx
-    ui, su = _global_scale_to_int(u.astype(f64), plan.payload_bits)
-    ci, sc = _global_scale_to_int(c.astype(f64), plan.payload_bits)
-    u_hi, u_lo = splitting.split_hi_lo(ui)
-    if px:
-        # Zero planes past X are the zero halo the last real plane sees.
-        u_hi = jnp.pad(u_hi, ((0, px), (0, 0), (0, 0)))
-        u_lo = jnp.pad(u_lo, ((0, px), (0, 0), (0, 0)))
-    c_hi, c_lo = splitting.split_hi_lo(ci)
-    c_res = jnp.stack(common.residues_int32(c_hi, c_lo, plan.moduli))
+    # Scopes around the statements in their trace order (``repro.obs.spans``).
+    with spans.scope("ozaki.split_b"):
+        ui, su = _global_scale_to_int(u.astype(f64), plan.payload_bits)
+    with spans.scope("ozaki.split_a"):
+        ci, sc = _global_scale_to_int(c.astype(f64), plan.payload_bits)
+    with spans.scope("ozaki.split_b"):
+        u_hi, u_lo = splitting.split_hi_lo(ui)
+        if px:
+            # Zero planes past X are the zero halo the last real plane sees.
+            u_hi = jnp.pad(u_hi, ((0, px), (0, 0), (0, 0)))
+            u_lo = jnp.pad(u_lo, ((0, px), (0, 0), (0, 0)))
+    with spans.scope("ozaki.split_a"):
+        c_hi, c_lo = splitting.split_hi_lo(ci)
+        c_res = jnp.stack(common.residues_int32(c_hi, c_lo, plan.moduli))
 
     Xp = X + px
     x_steps = Xp // bx
@@ -158,8 +164,9 @@ def stencil7(u: jax.Array, c: jax.Array, plan: ozaki2.Plan,
         interpret=interpret,
     )(c_res, u_hi, u_lo, u_hi, u_lo, u_hi, u_lo)
 
-    v = common.finish(raw, plan, rep, f64)[..., :X, :, :]
-    return splitting.ldexp(v, -(su + sc))
+    with spans.scope("ozaki.finish"):
+        v = common.finish(raw, plan, rep, f64)[..., :X, :, :]
+        return splitting.ldexp(v, -(su + sc))
 
 
 @functools.partial(jax.jit, static_argnames=("plan", "out_rep"))

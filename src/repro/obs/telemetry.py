@@ -132,11 +132,6 @@ def enabled() -> bool:
     return True
 
 
-def tracing() -> bool:
-    """Whether the per-event trace ring is filling (mode == "trace")."""
-    return get_mode() == "trace"
-
-
 # ---------------------------------------------------------------------------
 # Recording
 # ---------------------------------------------------------------------------

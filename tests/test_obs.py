@@ -1,9 +1,12 @@
-"""Telemetry subsystem tests: mode resolution, recording tiers, tracer safety
-(the instrumented entry points must still jit, bit-identically), cache
+"""Observability tests.  Telemetry: mode resolution, recording tiers, tracer
+safety (the instrumented entry points must still jit, bit-identically), cache
 counters, solver residual traces, serving events, and the report/probe
-surfaces."""
+surfaces.  Spans: the device scopes in each kind's HLO metadata, and the CG
+loop's host spans in a profiler trace."""
 
+import glob
 import json
+import re
 
 import jax
 import jax.numpy as jnp
@@ -12,7 +15,8 @@ import pytest
 
 from repro.core import compensated, dispatch, ozaki2
 from repro.hpc import cg, jacobi
-from repro.obs import report, telemetry as obs
+from repro.kernels import ops, ozaki_spmv, ozaki_stencil
+from repro.obs import report, spans, telemetry as obs
 
 
 @pytest.fixture(autouse=True)
@@ -42,14 +46,12 @@ def _gemm_operands(n=32):
 def test_mode_default_off():
     assert obs.get_mode() == "off"
     assert not obs.enabled()
-    assert not obs.tracing()
 
 
 def test_mode_from_env(monkeypatch):
     monkeypatch.setenv(obs.ENV_VAR, "counters")
     assert obs.get_mode() == "counters"
     assert obs.enabled()
-    assert not obs.tracing()
 
 
 def test_mode_env_invalid_raises(monkeypatch):
@@ -70,7 +72,7 @@ def test_scope_nests_and_restores():
     with obs.telemetry_scope("counters"):
         assert obs.get_mode() == "counters"
         with obs.telemetry_scope("trace"):
-            assert obs.tracing()
+            assert obs.get_mode() == "trace"
         with obs.telemetry_scope(None):      # None inherits
             assert obs.get_mode() == "counters"
         assert obs.get_mode() == "counters"
@@ -361,3 +363,79 @@ def test_snapshot_json_roundtrip_and_report_main(tmp_path, capsys):
     assert report.main([path, "--json"]) == 0
     rows = json.loads(capsys.readouterr().out)
     assert rows[0]["kind"] == "gemm"
+
+
+# --- spans: device scopes and host spans --------------------------------------
+
+def _scope_lowering(kind):
+    """A small lowering of one kind's jitted program (interpret-mode Pallas,
+    24-bit plans so the residue graphs stay small)."""
+    f64 = jnp.float64
+    if kind == "gemm":
+        plan = ozaki2.make_plan(32, payload_bits=24)
+        a = jnp.ones((32, 32), f64)
+        return jax.jit(lambda a, b: ops.ozaki_gemm(a, b, plan=plan, interpret=True)
+                       ).lower(a, a)
+    if kind == "gemv":
+        plan = ozaki2.make_plan(32, payload_bits=24)
+        return jax.jit(lambda a, x: ops.ozaki_gemv(a, x, plan=plan, interpret=True)
+                       ).lower(jnp.ones((32, 32), f64), jnp.ones((32, 4), f64))
+    if kind == "spmv_bell":
+        plan = ozaki2.make_plan(7, payload_bits=24)
+        return ozaki_spmv.spmv_bell.lower(jnp.ones((64, 7), f64),
+                                          jnp.zeros((64, 7), jnp.int32),
+                                          jnp.ones(64, f64), plan, interpret=True)
+    if kind == "stencil7":
+        plan = ozaki2.make_plan(8, payload_bits=24)
+        return ozaki_stencil.stencil7.lower(jnp.ones((8, 8, 128), f64),
+                                            jnp.ones(7, f64), plan, interpret=True)
+    x = jnp.ones(300, f64)
+    return jax.jit(compensated.compensated_dot).lower(x, x)
+
+
+@pytest.mark.parametrize("kind,expected", [
+    ("gemm", {"ozaki.split_a", "ozaki.split_b", "ozaki.finish"}),
+    ("gemv", {"ozaki.split_a", "ozaki.split_b", "ozaki.finish"}),
+    ("spmv_bell", {"ozaki.split_a", "ozaki.split_b", "spmv.gather", "ozaki.finish"}),
+    ("stencil7", {"ozaki.split_a", "ozaki.split_b", "ozaki.finish"}),
+    ("dot2", {"reduce.dot2"}),
+])
+def test_scopes_in_hlo_op_name_metadata(kind, expected):
+    """Each phase's scope is a segment of its ops' HLO ``op_name`` paths."""
+    text = _scope_lowering(kind).compiler_ir("hlo").as_hlo_module().to_string()
+    segments = {seg for path in re.findall(r'op_name="([^"]*)"', text)
+                for seg in path.split("/")}
+    assert segments & set(spans.SCOPES) == expected
+
+
+def test_unknown_span_or_scope_name_raises():
+    with pytest.raises(ValueError, match="unknown scope"):
+        spans.scope("ozaki.split")
+    with pytest.raises(ValueError, match="unknown span"):
+        spans.span("cg")
+
+
+@pytest.mark.parametrize("record_plain,syncs_per_iter", [(False, 1), (True, 2)])
+def test_cg_host_spans_in_profiler_trace(tmp_path, record_plain, syncs_per_iter):
+    """One ``repro.cg.iter`` span an iteration, and one ``repro.sync`` span a
+    host read: before the loop and in each iteration, twice with the plain
+    shadow history."""
+    from jax.profiler import ProfileData
+
+    n, iters = 16, 4
+    a = jnp.asarray(np.diag(np.arange(1.0, n + 1)))
+    b = jnp.ones(n)
+    cg.cg_solve(lambda v: a @ v, b, tol=0.0, maxiter=1)       # compile first
+    with jax.profiler.trace(str(tmp_path)):
+        res = cg.cg_solve(lambda v: a @ v, b, tol=0.0, maxiter=iters,
+                          record_plain=record_plain)
+    assert res.iters == iters
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    counts = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(spans.PREFIX):
+                    counts[ev.name] = counts.get(ev.name, 0) + 1
+    assert counts == {"repro.cg.iter": iters,
+                      "repro.sync": syncs_per_iter * (iters + 1)}

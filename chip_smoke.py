@@ -232,25 +232,9 @@ def phase_matmul(name: str, m: int, k: int, n: int, u_dev: float, rng,
 # ---------------------------------------------------------------------------
 
 def poisson_bell(n: int):
-    """7-point -Δ_h on an n^3 grid (zero Dirichlet), Blocked-ELL with bw = 7:
-    slot 0 the diagonal (6), slots 1-6 the neighbours (-1); slots past the
-    boundary point at the row itself with value 0."""
-    idx = np.arange(n ** 3).reshape(n, n, n)
-    val = np.zeros((n ** 3, 7))
-    col = np.repeat(idx.reshape(-1, 1), 7, axis=1).astype(np.int32)
-    val[:, 0] = 6.0
-    slot = 1
-    for ax in range(3):
-        for d in (-1, 1):
-            nb = np.roll(idx, -d, axis=ax)
-            inside = np.ones((n, n, n), bool)
-            edge = [slice(None)] * 3
-            edge[ax] = -1 if d == 1 else 0
-            inside[tuple(edge)] = False
-            col[inside.reshape(-1), slot] = nb[inside]
-            val[inside.reshape(-1), slot] = -1.0
-            slot += 1
-    return val, col
+    """7-point -Δ_h on an n^3 grid, Blocked-ELL with bw = 7 (banded)."""
+    from repro.hpc import spmv_formats
+    return spmv_formats.laplacian_3d_bell(n)
 
 
 def _bell_matvec(val, col, x):

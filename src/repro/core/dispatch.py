@@ -471,7 +471,8 @@ def dot(x: jax.Array, w: jax.Array, plan: Optional[ozaki2.Plan] = None,
 
 def spmv(a_val: jax.Array, a_col: jax.Array, x: jax.Array,
          plan: Optional[ozaki2.Plan] = None, out_rep: str = "f64",
-         br: Optional[int] = None, mode: Optional[str] = None) -> jax.Array:
+         br: Optional[int] = None, mode: Optional[str] = None,
+         offsets: Optional[Tuple[int, ...]] = None) -> jax.Array:
     """Emulated Blocked-ELL SpMV y = A x through the dispatch layer.
 
     a_val: (M, bw) padded per-row nonzero values, a_col: (M, bw) int32 column
@@ -480,6 +481,11 @@ def spmv(a_val: jax.Array, a_col: jax.Array, x: jax.Array,
     ``choose_route(plan, "spmv_bell", mode)``, and the two routes are
     bit-identical — the fused kernel pads M up to the row-block internally and
     unpads before returning, with all-zero padded rows contributing nothing.
+
+    ``offsets`` (``spmv_formats.band_offsets`` of the operator) lets the
+    pallas route read x by static shifts instead of a gather; the xla route
+    keeps its gather as the independent reference.  The ``spmv_band`` cache
+    tally counts a hit for each recorded call that takes the shifts.
     """
     # Deferred module import (kernels import core, not vice versa); attribute
     # access at call time keeps the route monkeypatch-able in tests.
@@ -495,9 +501,12 @@ def spmv(a_val: jax.Array, a_col: jax.Array, x: jax.Array,
         if br is None:
             br = int(get_tuning("spmv_bell", a_val.shape).get("br", 128))
         out = _spmv.spmv_bell(a_val, a_col, x, plan, out_rep=out_rep,
-                              br=br, interpret=pallas_interpret("spmv_bell"))
+                              br=br, interpret=pallas_interpret("spmv_bell"),
+                              offsets=offsets)
     else:
         out = _spmv.spmv_bell_ref(a_val, a_col, x, plan, out_rep=out_rep)
+    if rec is not None:
+        obs.record_cache("spmv_band", route == "pallas" and offsets is not None)
     return obs.op_end(rec, out)
 
 

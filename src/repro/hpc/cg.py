@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import compensated, dispatch, ozaki2
+from repro.hpc import spmv_formats
 from repro.obs import spans, telemetry as obs
 
 
@@ -102,14 +103,17 @@ def cg_solve_bell(a_val: jax.Array, a_col: jax.Array, b: jax.Array,
     The plan resolves once from the dispatch cache (not per iteration); the
     SpMV route follows ``mode`` / ``mode_scope`` / ``REPRO_DISPATCH`` like
     every multiplication behind the seam — the sparse-LA dwarf's §7.1(a)
-    recipe with the emulated kernel as a uniformly-routed drop-in.
+    recipe with the emulated kernel as a uniformly-routed drop-in.  A banded
+    operator is found once, before the loop (``spmv_formats.band_offsets``),
+    and every matvec then reads x by static shifts instead of a gather.
     """
     if plan is None:
         plan = dispatch.get_plan(a_val.shape[1], margin_bits=4)
+    offsets = spmv_formats.band_offsets(a_val, a_col)
 
     def matvec(x):
         return dispatch.spmv(a_val, a_col, x, plan=plan, out_rep=out_rep,
-                             mode=mode)
+                             mode=mode, offsets=offsets)
     return cg_solve(matvec, b, **kw)
 
 

@@ -8,7 +8,9 @@ contract the bw-length products per modulus, Garner, store.
 TPU adaptation notes:
   * the gather x[a_col] runs in XLA ahead of the kernel, as (hi, lo) int32 pairs:
     Mosaic lowers no general vector gather.  That costs 8 B per stored slot
-    on top of Algorithm 3's VMEM-resident x;
+    on top of Algorithm 3's VMEM-resident x.  A banded operator (each slot one
+    diagonal, ``spmv_formats.band_offsets``) takes static shifted slices of x
+    instead, with no gather;
   * blocks are laid out (bw, br), rows on the 128 lanes, so a block is
     lane-dense for any band width;
   * β otherwise inherits the ELL padding ratio ρ_pad exactly as Appendix D
@@ -18,6 +20,7 @@ TPU adaptation notes:
 from __future__ import annotations
 
 import functools
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -121,27 +124,47 @@ def spmv_bell_ref(a_val: jax.Array, a_col: jax.Array, x: jax.Array,
     return _spmv_ref_epilogue(tuple(digits), sa, sx, plan, out_rep)
 
 
-@functools.partial(jax.jit, static_argnames=("plan", "out_rep", "br", "interpret"))
+def _band_slices(x: jax.Array, offsets: Tuple[int, ...], rows: int) -> jax.Array:
+    """(bw, rows): row k is x[r + offsets[k]] for r < rows, 0 outside x —
+    the gather of a banded operator's x, as static slices of a padded x."""
+    lo = max(0, -min(offsets))
+    xp = jnp.pad(x, (lo, max(0, rows + max(offsets) - x.shape[0])))
+    return jnp.stack([xp[lo + o:lo + o + rows] for o in offsets])
+
+
+@functools.partial(jax.jit, static_argnames=("plan", "out_rep", "br", "interpret",
+                                             "offsets"))
 def spmv_bell(a_val: jax.Array, a_col: jax.Array, x: jax.Array,
               plan: ozaki2.Plan, out_rep: str = "f64", br: int = 128,
-              interpret: bool = True) -> jax.Array:
+              interpret: bool = True,
+              offsets: Optional[Tuple[int, ...]] = None) -> jax.Array:
+    """``offsets`` (``spmv_formats.band_offsets``) marks a banded operator:
+    slot k reads x at row + offsets[k] wherever its value is nonzero.  x is
+    then laid out by static shifts and ``a_col`` is not read; where a value
+    is 0 its A residues are 0, so the result is bit-identical to the gather."""
     M, bw = a_val.shape
     f64 = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
     br = min(br, M)
     pm = (-M) % br
+    Mp = M + pm
+    if offsets is not None and len(offsets) != bw:
+        raise ValueError(f"{len(offsets)} offsets for {bw} slots")
 
     av_hi, av_lo, col, x_hi, x_lo, sa, sx = _decompose_operands(
         a_val, a_col, x, plan)
     # The gather runs in XLA: Mosaic has no general vector gather.  Rows go
     # to the lanes, so a (bw, br) block is lane-dense for any band width.
     # Scopes around the statements in their trace order (``repro.obs.spans``).
-    with spans.scope("spmv.gather"):
-        xg_hi, xg_lo = x_hi[col], x_lo[col]
+    if offsets is None:
+        with spans.scope("spmv.gather"):
+            xg_hi, xg_lo = x_hi[col], x_lo[col]
     with spans.scope("ozaki.split_a"):
         av_hi, av_lo = [jnp.pad(o, ((0, pm), (0, 0))).T for o in (av_hi, av_lo)]
     with spans.scope("spmv.gather"):
-        xg_hi, xg_lo = [jnp.pad(o, ((0, pm), (0, 0))).T for o in (xg_hi, xg_lo)]
-    Mp = M + pm
+        if offsets is None:
+            xg_hi, xg_lo = [jnp.pad(o, ((0, pm), (0, 0))).T for o in (xg_hi, xg_lo)]
+        else:
+            xg_hi, xg_lo = [_band_slices(o, offsets, Mp) for o in (x_hi, x_lo)]
     zero = common.ZERO
     blk = pl.BlockSpec((bw, br), lambda i: (zero, i))
 

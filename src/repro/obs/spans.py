@@ -31,7 +31,7 @@ PREFIX = "repro."
 SCOPES = (
     "ozaki.split_a",   # Phase-1 scaling, hi/lo split, padding of the matrix operand
     "ozaki.split_b",   # the same for the other operand (B, x, the stencil's u)
-    "spmv.gather",     # x's hi/lo gathered to the ELL slots, laid out for the kernel
+    "spmv.gather",     # x's hi/lo gathered (banded: shifted) to the ELL slots, for the kernel
     "ozaki.finish",    # digits -> working float, and the exact unscale
     "reduce.dot2",     # the compensated sum's block tree and carry scan
 )

@@ -14,6 +14,8 @@ library admits one process at a time, and xdist workers import every test
 file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -81,12 +83,18 @@ def test_gemv_compiles(tpu):
     assert "tpu_custom_call" in txt
 
 
-def test_spmv_bell_compiles(tpu):
-    n = 64 ** 3
+@pytest.mark.parametrize("banded", [False, True])
+def test_spmv_bell_compiles(tpu, banded):
+    """The 7-point operator at 64^3: gathered, or (banded) laid out by
+    static shifts of x with no gather in the program."""
+    g = 64
+    offsets = (0, -g * g, g * g, -g, g, -1, 1) if banded else None
+    n = g ** 3
     txt = _compile_text(
-        lambda v, c, x: dispatch.spmv(v, c, x, mode="pallas"),
+        lambda v, c, x: dispatch.spmv(v, c, x, mode="pallas", offsets=offsets),
         tpu((n, 7)), tpu((n, 7), I32), tpu((n,)))
     assert "tpu_custom_call" in txt
+    assert (re.search(r"\sgather\(", txt) is None) == banded
 
 
 def test_stencil7_compiles(tpu):

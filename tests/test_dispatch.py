@@ -233,7 +233,7 @@ def _spy_spmv_routes(monkeypatch):
         return real_ref(*a, **kw)
 
     def pallas_spy(a_val, a_col, x, plan, out_rep="f64", br=128,
-                   interpret=True):
+                   interpret=True, offsets=None):
         calls.append("pallas")
         assert interpret == dispatch.pallas_interpret("spmv_bell")
         return real_ref(a_val, a_col, x, plan, out_rep=out_rep)
@@ -318,20 +318,47 @@ def test_stencil_routes_bit_identical():
         np.testing.assert_array_equal(v_xla, v_pal)
 
 
-def test_spmv_routes_bit_identical_small_plan():
+def _spmv_operator(kind):
+    """(val, col, x): a random Blocked-ELL operator, or a banded one (the 3-D
+    Poisson operator; wide and tall rectangles whose slots past the edge
+    hold 0 and point at random columns)."""
+    from repro.hpc import spmv_formats
+    if kind == "random":
+        val = RNG.standard_normal((24, 4))
+        col = RNG.integers(0, 32, (24, 4)).astype(np.int32)
+        return val, col, RNG.standard_normal(32)
+    if kind == "poisson3d":
+        val, col = spmv_formats.laplacian_3d_bell(3)
+        return val, col, RNG.standard_normal(27)
+    m, n, offsets = {"wide": (20, 30, (-3, 0, 7)),
+                     "tall": (30, 20, (0, 2, -1))}[kind]
+    col = np.arange(m)[:, None] + np.asarray(offsets)
+    inside = (col >= 0) & (col < n)
+    val = np.where(inside, RNG.standard_normal(col.shape), 0.0)
+    col = np.where(inside, col, RNG.integers(0, n, col.shape)).astype(np.int32)
+    return val, col, RNG.standard_normal(n)
+
+
+@pytest.mark.parametrize("kind", ["random", "poisson3d", "wide", "tall"])
+@pytest.mark.parametrize("out_rep", ["f64", "ds"])
+def test_spmv_routes_bit_identical_small_plan(kind, out_rep):
     """xla vs pallas through dispatch.spmv with a 24-bit-payload plan (r = 7):
     small enough for the interpreted gather graph to compile in seconds, so
     the fast lane pins SpMV cross-route parity too (a second r = 7 geometry —
     ragged M, both reps, via the ops entry point — runs in the slow lane:
     test_kernels.py; the default r = 15 plan is uncoverable on CPU, its
-    interpreter compile exceeds 10 minutes regardless of problem size)."""
-    plan = ozaki2.make_plan(4, payload_bits=24, margin_bits=4)
-    val = jnp.asarray(RNG.standard_normal((24, 4)))
-    col = jnp.asarray(RNG.integers(0, 32, (24, 4)).astype(np.int32))
-    x = jnp.asarray(RNG.standard_normal(32))
-    y_xla = np.asarray(dispatch.spmv(val, col, x, plan=plan, mode="xla"))
-    y_pal = np.asarray(dispatch.spmv(val, col, x, plan=plan, br=8,
-                                     mode="pallas"))
+    interpreter compile exceeds 10 minutes regardless of problem size).
+    Banded operators take the pallas route's static shifts of x, the others
+    its gather; both kernel outputs ("f64" writes digits, "ds" a pair)."""
+    from repro.hpc import spmv_formats
+    val, col, x = (jnp.asarray(t) for t in _spmv_operator(kind))
+    offsets = spmv_formats.band_offsets(val, col)
+    assert (offsets is None) == (kind == "random")
+    plan = ozaki2.make_plan(val.shape[1], payload_bits=24, margin_bits=4)
+    y_xla = np.asarray(dispatch.spmv(val, col, x, plan=plan, out_rep=out_rep,
+                                     mode="xla"))
+    y_pal = np.asarray(dispatch.spmv(val, col, x, plan=plan, out_rep=out_rep,
+                                     br=8, mode="pallas", offsets=offsets))
     np.testing.assert_array_equal(y_xla, y_pal)
 
 

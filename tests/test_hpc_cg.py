@@ -1,5 +1,6 @@
 """§7.1(a) integration: CG with Ozaki-II SpMV + compensated dots."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -112,3 +113,72 @@ def test_cg_iteration_counts_unchanged_by_blocked_eft():
     assert blocked.converged and scan.converged
     assert blocked.iters == scan.iters
     np.testing.assert_allclose(blocked.history, scan.history, rtol=1e-12)
+
+
+# ---------------------------------------------------------------------------
+# Banded operators: the SpMV reads x by static shifts
+# ---------------------------------------------------------------------------
+
+def _banded(m, n, offsets, rng):
+    """(m, bw) Blocked-ELL of a banded m x n matrix; slots past the matrix's
+    edge hold value 0 and point at random columns."""
+    r = np.arange(m)[:, None]
+    col = r + np.asarray(offsets)
+    inside = (col >= 0) & (col < n)
+    val = np.where(inside, rng.standard_normal((m, len(offsets))), 0.0)
+    col = np.where(inside, col, rng.integers(0, n, col.shape)).astype(np.int32)
+    return val, col
+
+
+def _off_diagonal():
+    val, col = spmv_formats.laplacian_3d_bell(3)
+    col[13, 4] += 1                      # one nonzero leaves its diagonal
+    return val, col
+
+
+def _random_sparse():
+    rng = np.random.default_rng(4)
+    return rng.standard_normal((40, 5)), rng.integers(0, 40, (40, 5)).astype(np.int32)
+
+
+@pytest.mark.parametrize("operator, want", [
+    (lambda: spmv_formats.laplacian_3d_bell(3), (0, -9, 9, -3, 3, -1, 1)),
+    (lambda: spmv_formats.laplacian_3d_bell(1), (0, 0, 0, 0, 0, 0, 0)),
+    (lambda: _banded(20, 30, (-3, 0, 7), np.random.default_rng(5)), (-3, 0, 7)),
+    (lambda: spmv_formats.to_blocked_ell(spmv_formats.laplacian_1d(12), 4), None),
+    (_random_sparse, None),
+    (_off_diagonal, None),
+], ids=["poisson3d", "all-zero-slots", "zero-slots-anywhere", "left-packed",
+        "random", "one-off-diagonal"])
+def test_band_offsets(operator, want):
+    val, col = operator()
+    assert spmv_formats.band_offsets(val, col) == want
+    assert spmv_formats.band_offsets(jnp.asarray(val), jnp.asarray(col)) == want
+
+
+def test_band_offsets_of_tracers_is_none():
+    val, col = spmv_formats.laplacian_3d_bell(2)
+    seen = []
+    jax.jit(lambda v, c: seen.append(spmv_formats.band_offsets(v, c)) or v)(val, col)
+    assert seen == [None]
+
+
+def test_cg_bell_band_shifts_bit_identical(monkeypatch):
+    """CG on the 3-D Poisson operator with the pallas SpMV: the iterates of
+    the static-shift path are those of the gather, bit for bit."""
+    from repro.core import ozaki2
+    from repro.obs import telemetry as obs
+
+    val, col = (jnp.asarray(t) for t in spmv_formats.laplacian_3d_bell(3))
+    b = jnp.asarray(np.random.default_rng(6).standard_normal(27))
+    plan = ozaki2.make_plan(7, payload_bits=24, margin_bits=4)
+    kw = dict(plan=plan, mode="pallas", tol=0.0, maxiter=6)
+    obs.reset()
+    with obs.telemetry_scope("counters"):
+        shifted = cg_solve_bell(val, col, b, **kw)
+        monkeypatch.setattr(spmv_formats, "band_offsets", lambda *a: None)
+        gathered = cg_solve_bell(val, col, b, **kw)
+    assert obs.cache_snapshot()["spmv_band"] == (7, 7)   # 1 + 6 matvecs each
+    obs.reset()
+    np.testing.assert_array_equal(np.asarray(shifted.x), np.asarray(gathered.x))
+    assert shifted.history == gathered.history

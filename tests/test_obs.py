@@ -257,6 +257,23 @@ def test_plan_and_tune_cache_counters():
     assert caches["tune"] == (1, 1)
 
 
+def test_spmv_band_cache_counter():
+    """``spmv_band`` counts a hit where the pallas SpMV reads x by static
+    shifts (a banded operator), a miss where it gathers."""
+    from repro.hpc import spmv_formats
+    rng = _rng()
+    plan_r7 = ozaki2.make_plan(7, payload_bits=24, margin_bits=4)
+    banded = [jnp.asarray(t) for t in spmv_formats.laplacian_3d_bell(2)]
+    general = [jnp.asarray(rng.standard_normal((8, 7))),
+               jnp.asarray(rng.integers(0, 8, (8, 7)).astype(np.int32))]
+    x = jnp.asarray(rng.standard_normal(8))
+    with obs.telemetry_scope("counters"):
+        for val, col in (banded, general):
+            dispatch.spmv(val, col, x, plan=plan_r7, br=8, mode="pallas",
+                          offsets=spmv_formats.band_offsets(val, col))
+    assert obs.cache_snapshot()["spmv_band"] == (1, 1)   # (hits, misses)
+
+
 # --- solver residual traces --------------------------------------------------
 
 def test_cg_residual_trace_matches_history():

@@ -52,9 +52,12 @@ def cg_solve(matvec: Callable[[jax.Array], jax.Array], b: jax.Array,
     test, a plain-dot shadow history records what uncompensated working
     precision would have reported for the same iterates.  ``record_plain=False``
     drops the shadow reduction (one extra O(n) dot + host sync per iteration)
-    for production solves that never read it."""
-    x = jnp.zeros_like(b) if x0 is None else x0
-    r = b - matvec(x)
+    for production solves that never read it.  With ``x0`` None the solve
+    starts from x = 0 and r = b, with no product of A and zero."""
+    if x0 is None:
+        x, r = jnp.zeros_like(b), b
+    else:
+        x, r = x0, b - matvec(x0)
     p = r
     rs = dot(r, r)
     bnorm = norm(b)

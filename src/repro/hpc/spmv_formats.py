@@ -1,10 +1,10 @@
 """Sparse-matrix format tooling for the Blocked-ELL SpMV kernel (paper §5.4).
 
-``to_blocked_ell`` converts a dense/COO matrix to the (values, columns) padded
-layout; ``padding_ratio`` is Appendix D's ρ_pad — the lower bound on the TME β
-for the SpMV kernel; ``band_offsets`` finds the diagonal each slot holds when
-the operator is banded, so the kernel can read x by static shifts instead of
-a gather.
+``csr_to_blocked_ell`` converts a CSR matrix, and ``to_blocked_ell`` a small
+dense one, to the (values, columns) padded layout; ``padding_ratio`` is Appendix
+D's ρ_pad — the lower bound on the TME β for the SpMV kernel;
+``band_offsets`` finds the diagonal each slot holds when the operator is
+banded, so the kernel can read x by static shifts instead of a gather.
 """
 
 from __future__ import annotations
@@ -17,18 +17,43 @@ import numpy as np
 
 
 def to_blocked_ell(dense: np.ndarray, bw: int) -> Tuple[np.ndarray, np.ndarray]:
-    """Dense (M, N) -> (values (M, bw), columns (M, bw)); raises if a row has
-    more than bw nonzeros.  Padded slots point at column 0 with value 0."""
-    M, N = dense.shape
-    val = np.zeros((M, bw), dense.dtype)
-    col = np.zeros((M, bw), np.int32)
-    for i in range(M):
-        nz = np.nonzero(dense[i])[0]
-        if len(nz) > bw:
-            raise ValueError(f"row {i} has {len(nz)} > bw={bw} nonzeros")
-        val[i, :len(nz)] = dense[i, nz]
-        col[i, :len(nz)] = nz
-    return val, col
+    """Dense (M, N) -> (values (M, bw), columns (M, bw)), for small operators:
+    the nonzeros of each row in column order, as CSR, through
+    ``csr_to_blocked_ell``."""
+    rows, cols = np.nonzero(dense)
+    rowptr = np.r_[0, np.cumsum(np.bincount(rows, minlength=dense.shape[0]))]
+    return csr_to_blocked_ell(rowptr, cols.astype(np.int32), dense[rows, cols],
+                              bw)
+
+
+def csr_to_blocked_ell(rowptr: np.ndarray, col: np.ndarray, val: np.ndarray,
+                       bw: Optional[int] = None) -> Tuple[np.ndarray, np.ndarray]:
+    """CSR (``rowptr`` (M + 1,), ``col``, ``val``) -> (values (M, bw), columns
+    (M, bw)), vectorised.  ``bw`` defaults to the longest row; raises if a
+    row has more than ``bw`` entries.  Slot k of a row holds its k-th stored
+    entry.  Padded slots have value 0 and point at the row itself (an x the
+    row of a square operator reads anyway), or, in a row whose number is past
+    the largest stored column, at that column: a column of the matrix
+    whatever its shape."""
+    rowptr = np.asarray(rowptr, np.int64)
+    col = np.asarray(col)
+    val = np.asarray(val)
+    m = rowptr.shape[0] - 1
+    lengths = np.diff(rowptr)
+    longest = int(lengths.max(initial=0))
+    if bw is None:
+        bw = longest
+    if longest > bw:
+        row = int(np.argmax(lengths))
+        raise ValueError(f"row {row} has {longest} > bw={bw} entries")
+    rows = np.repeat(np.arange(m), lengths)
+    slots = np.arange(rowptr[-1]) - rowptr[rows]
+    pad = np.minimum(np.arange(m), int(col.max(initial=0))).astype(np.int32)
+    out_val = np.zeros((m, bw), val.dtype)
+    out_col = np.repeat(pad[:, None], bw, axis=1)
+    out_val[rows, slots] = val
+    out_col[rows, slots] = col
+    return out_val, out_col
 
 
 def laplacian_1d(n: int) -> np.ndarray:

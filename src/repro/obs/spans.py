@@ -40,6 +40,7 @@ SCOPES = (
 SPANS = (
     "cg.iter",         # one CG iteration of the host loop
     "sync",            # one device-to-host read that the host loop waits on
+    "npb.outer",       # one outer step of NPB CG's inverse power iteration
 )
 
 

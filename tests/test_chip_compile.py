@@ -97,6 +97,16 @@ def test_spmv_bell_compiles(tpu, banded):
     assert (re.search(r"\sgather\(", txt) is None) == banded
 
 
+def test_spmv_bell_compiles_at_npb_class_b(tpu):
+    """NPB CG class B's operator (75000 rows, bw 404, plan r = 16): the
+    general path, x gathered to every slot, into the Mosaic kernel."""
+    m, bw = 75000, 404
+    txt = _compile_text(lambda v, c, x: dispatch.spmv(v, c, x, mode="pallas"),
+                        tpu((m, bw)), tpu((m, bw), I32), tpu((m,)))
+    assert "tpu_custom_call" in txt
+    assert re.search(r"\sgather\(", txt) is not None
+
+
 def test_stencil7_compiles(tpu):
     c = jacobi.laplacian_coeffs()
     txt = _compile_text(lambda u: dispatch.stencil7(u, c, mode="pallas"),

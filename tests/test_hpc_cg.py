@@ -178,7 +178,29 @@ def test_cg_bell_band_shifts_bit_identical(monkeypatch):
         shifted = cg_solve_bell(val, col, b, **kw)
         monkeypatch.setattr(spmv_formats, "band_offsets", lambda *a: None)
         gathered = cg_solve_bell(val, col, b, **kw)
-    assert obs.cache_snapshot()["spmv_band"] == (7, 7)   # 1 + 6 matvecs each
+    assert obs.cache_snapshot()["spmv_band"] == (6, 6)   # 6 matvecs each
     obs.reset()
     np.testing.assert_array_equal(np.asarray(shifted.x), np.asarray(gathered.x))
     assert shifted.history == gathered.history
+
+
+def test_cg_from_zero_skips_the_product_with_zero():
+    """With x0 None the solve starts from r = b, one SpMV fewer than from an
+    explicit x0 = 0, and its iterates are those of b - A 0, bit for bit."""
+    from repro.core import dispatch
+
+    val, col = (jnp.asarray(t) for t in spmv_formats.laplacian_3d_bell(4))
+    b = jnp.asarray(np.random.default_rng(8).standard_normal(64))
+    calls = []
+
+    def matvec(x):
+        calls.append(1)
+        return dispatch.spmv(val, col, x)
+    kw = dict(tol=0.0, maxiter=8, record_plain=True)
+    skipped = cg_solve(matvec, b, **kw)
+    n_skipped = len(calls)
+    explicit = cg_solve(matvec, b, x0=jnp.zeros_like(b), **kw)
+    assert (n_skipped, len(calls) - n_skipped) == (8, 9)
+    np.testing.assert_array_equal(np.asarray(skipped.x), np.asarray(explicit.x))
+    assert skipped.history == explicit.history
+    assert skipped.history_plain == explicit.history_plain

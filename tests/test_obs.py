@@ -456,3 +456,27 @@ def test_cg_host_spans_in_profiler_trace(tmp_path, record_plain, syncs_per_iter)
                     counts[ev.name] = counts.get(ev.name, 0) + 1
     assert counts == {"repro.cg.iter": iters,
                       "repro.sync": syncs_per_iter * (iters + 1)}
+
+
+def test_npb_power_step_records_one_outer_span(tmp_path):
+    """One ``repro.npb.outer`` span an NPB outer step, around its CG
+    iterations' ``repro.cg.iter`` spans."""
+    from jax.profiler import ProfileData
+
+    from repro.hpc import npb_cg, spmv_formats
+
+    val, col = (jnp.asarray(t) for t in spmv_formats.laplacian_3d_bell(3))
+    x = jnp.ones(27)
+    npb_cg.power_step(val, col, x, 10.0, cg_iters=1)          # compile first
+    with jax.profiler.trace(str(tmp_path)):
+        jax.block_until_ready(npb_cg.power_step(val, col, x, 10.0, cg_iters=3))
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans_seen = [(ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
+                  for plane in ProfileData.from_file(path).planes
+                  for line in plane.lines for ev in line.events
+                  if ev.name in ("repro.npb.outer", "repro.cg.iter")]
+    outer = [(s, e) for s, e, name in spans_seen if name == "repro.npb.outer"]
+    inner = [(s, e) for s, e, name in spans_seen if name == "repro.cg.iter"]
+    assert len(outer) == 1 and len(inner) == 3
+    (lo, hi), = outer
+    assert all(lo <= s and e <= hi for s, e in inner)

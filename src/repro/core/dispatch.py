@@ -483,9 +483,12 @@ def spmv(a_val: jax.Array, a_col: jax.Array, x: jax.Array,
     unpads before returning, with all-zero padded rows contributing nothing.
 
     ``offsets`` (``spmv_formats.band_offsets`` of the operator) lets the
-    pallas route read x by static shifts instead of a gather; the xla route
-    keeps its gather as the independent reference.  The ``spmv_band`` cache
-    tally counts a hit for each recorded call that takes the shifts.
+    pallas route read x by static shifts instead of a gather; without them
+    the pallas kernel gathers x itself from VMEM where
+    ``ozaki_spmv.x_in_vmem`` holds, and XLA gathers it otherwise.  The xla
+    route keeps its gather as the independent reference.  The ``spmv_band``
+    and ``spmv_vmem`` cache tallies count a hit for each recorded call that
+    takes the shifts or the in-kernel gather.
     """
     # Deferred module import (kernels import core, not vice versa); attribute
     # access at call time keeps the route monkeypatch-able in tests.
@@ -507,6 +510,8 @@ def spmv(a_val: jax.Array, a_col: jax.Array, x: jax.Array,
         out = _spmv.spmv_bell_ref(a_val, a_col, x, plan, out_rep=out_rep)
     if rec is not None:
         obs.record_cache("spmv_band", route == "pallas" and offsets is not None)
+        obs.record_cache("spmv_vmem", route == "pallas" and offsets is None
+                         and _spmv.x_in_vmem(x.shape[0], a_val.shape[1], br))
     return obs.op_end(rec, out)
 
 

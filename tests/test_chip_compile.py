@@ -25,6 +25,7 @@ from jax.sharding import SingleDeviceSharding
 from repro import spectral
 from repro.core import backend, compensated, dispatch
 from repro.hpc import jacobi
+from repro.kernels import ozaki_spmv
 
 F64, I32 = jnp.float64, jnp.int32
 
@@ -85,8 +86,9 @@ def test_gemv_compiles(tpu):
 
 @pytest.mark.parametrize("banded", [False, True])
 def test_spmv_bell_compiles(tpu, banded):
-    """The 7-point operator at 64^3: gathered, or (banded) laid out by
-    static shifts of x with no gather in the program."""
+    """The 7-point operator at 64^3: x gathered (in the kernel from VMEM, or
+    in XLA, as ``x_in_vmem`` decides for its length), or (banded) laid out
+    by static shifts of x with no gather in the program."""
     g = 64
     offsets = (0, -g * g, g * g, -g, g, -1, 1) if banded else None
     n = g ** 3
@@ -94,17 +96,20 @@ def test_spmv_bell_compiles(tpu, banded):
         lambda v, c, x: dispatch.spmv(v, c, x, mode="pallas", offsets=offsets),
         tpu((n, 7)), tpu((n, 7), I32), tpu((n,)))
     assert "tpu_custom_call" in txt
-    assert (re.search(r"\sgather\(", txt) is None) == banded
+    no_xla_gather = banded or ozaki_spmv.x_in_vmem(n, 7, 128)
+    assert (re.search(r"\sgather\(", txt) is None) == no_xla_gather
 
 
 def test_spmv_bell_compiles_at_npb_class_b(tpu):
     """NPB CG class B's operator (75000 rows, bw 404, plan r = 16): the
-    general path, x gathered to every slot, into the Mosaic kernel."""
+    general path, x resident in VMEM and gathered inside the Mosaic kernel,
+    which keeps its name; no XLA gather in the program."""
     m, bw = 75000, 404
     txt = _compile_text(lambda v, c, x: dispatch.spmv(v, c, x, mode="pallas"),
                         tpu((m, bw)), tpu((m, bw), I32), tpu((m,)))
+    assert re.search(r"%spmv_bell(\.\d+)? = \S+ custom-call\(", txt) is not None
     assert "tpu_custom_call" in txt
-    assert re.search(r"\sgather\(", txt) is not None
+    assert re.search(r"\sgather\(", txt) is None
 
 
 def test_stencil7_compiles(tpu):

@@ -184,6 +184,34 @@ def test_cg_bell_band_shifts_bit_identical(monkeypatch):
     assert shifted.history == gathered.history
 
 
+def test_cg_bell_vmem_gather_bit_identical(monkeypatch):
+    """CG on NPB's class-S matrix with the pallas SpMV: the iterates of the
+    in-kernel gather of x from VMEM are those of XLA's gather, bit for bit."""
+    from repro.core import ozaki2
+    from repro.hpc import npb_cg
+    from repro.kernels import ozaki_spmv
+    from repro.obs import telemetry as obs
+
+    val, col = (jnp.asarray(t) for t in
+                spmv_formats.csr_to_blocked_ell(*npb_cg.makea("S")))
+    b = jnp.ones(val.shape[0])
+    plan = ozaki2.make_plan(val.shape[1], payload_bits=24, margin_bits=4)
+    kw = dict(plan=plan, mode="pallas", tol=0.0, maxiter=3)
+    obs.reset()
+    try:
+        with obs.telemetry_scope("counters"):
+            in_kernel = cg_solve_bell(val, col, b, **kw)
+            monkeypatch.setattr(ozaki_spmv, "X_VMEM_MAX", 0)
+            ozaki_spmv.spmv_bell.clear_cache()       # the route is traced in
+            gathered = cg_solve_bell(val, col, b, **kw)
+        assert obs.cache_snapshot()["spmv_vmem"] == (3, 3)   # 3 matvecs each
+    finally:
+        ozaki_spmv.spmv_bell.clear_cache()
+        obs.reset()
+    np.testing.assert_array_equal(np.asarray(in_kernel.x), np.asarray(gathered.x))
+    assert in_kernel.history == gathered.history
+
+
 def test_cg_from_zero_skips_the_product_with_zero():
     """With x0 None the solve starts from r = b, one SpMV fewer than from an
     explicit x0 = 0, and its iterates are those of b - A 0, bit for bit."""

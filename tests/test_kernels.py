@@ -202,3 +202,31 @@ def test_spmv_routes_bit_identical_pallas_interpreter():
         y_pal = np.asarray(ops.ozaki_spmv_bell(val, col, x, plan=plan, br=8,
                                                out_rep=rep, mode="pallas"))
         np.testing.assert_array_equal(y_ref, y_pal)
+
+
+def _last_chunk_bell(m, n, bw, br):
+    """Every column in x's last, partial chunk of br entries."""
+    val, _, x = _random_bell(m, n, bw)
+    col = RNG.integers(n - n % br, n, (m, bw)).astype(np.int32)
+    return val, jnp.asarray(col), x
+
+
+@pytest.mark.parametrize("operator", [
+    lambda: _random_bell(27, 64, 8),         # ragged M: padded rows
+    lambda: _random_bell(32, 37, 8),         # n not a multiple of br
+    lambda: _random_bell(24, 40, 13),        # bw not a multiple of 8
+    lambda: _last_chunk_bell(16, 45, 5, 8),  # columns in the last, partial chunk
+], ids=["ragged-m", "ragged-n", "bw-13", "last-chunk"])
+def test_spmv_vmem_gather_bit_identical_to_xla(operator):
+    """The pallas route with x resident in VMEM and gathered in the kernel
+    matches the xla route's gather bit for bit (24-bit plan, interpreted)."""
+    from repro.kernels import ozaki_spmv
+    plan = ozaki2.make_plan(13, payload_bits=24)
+    val, col, x = operator()
+    assert ozaki_spmv.x_in_vmem(x.shape[0], val.shape[1], 8)
+    for rep in ("f64", "digits"):
+        y_ref = np.asarray(ops.ozaki_spmv_bell(val, col, x, plan=plan,
+                                               out_rep=rep, mode="xla"))
+        y_pal = np.asarray(ops.ozaki_spmv_bell(val, col, x, plan=plan, br=8,
+                                               out_rep=rep, mode="pallas"))
+        np.testing.assert_array_equal(y_ref, y_pal)

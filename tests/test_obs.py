@@ -274,6 +274,41 @@ def test_spmv_band_cache_counter():
     assert obs.cache_snapshot()["spmv_band"] == (1, 1)   # (hits, misses)
 
 
+def test_spmv_vmem_cache_counter(monkeypatch):
+    """``spmv_vmem`` counts a hit where the pallas SpMV gathers x inside the
+    kernel (a general operator, short x), a miss for a banded operator and
+    for an x longer than ``X_VMEM_MAX``."""
+    from repro.hpc import spmv_formats
+    rng = _rng()
+    plan_r7 = ozaki2.make_plan(7, payload_bits=24, margin_bits=4)
+    banded = [jnp.asarray(t) for t in spmv_formats.laplacian_3d_bell(2)]
+    general = [jnp.asarray(rng.standard_normal((8, 7))),
+               jnp.asarray(rng.integers(0, 8, (8, 7)).astype(np.int32))]
+    x = jnp.asarray(rng.standard_normal(8))
+    try:
+        with obs.telemetry_scope("counters"):
+            for val, col in (general, banded):
+                dispatch.spmv(val, col, x, plan=plan_r7, br=8, mode="pallas",
+                              offsets=spmv_formats.band_offsets(val, col))
+            monkeypatch.setattr(ozaki_spmv, "X_VMEM_MAX", 7)
+            ozaki_spmv.spmv_bell.clear_cache()       # the route is traced in
+            dispatch.spmv(*general, x, plan=plan_r7, br=8, mode="pallas")
+    finally:
+        ozaki_spmv.spmv_bell.clear_cache()
+    assert obs.cache_snapshot()["spmv_vmem"] == (1, 2)   # (hits, misses)
+
+
+@pytest.mark.parametrize("bw", [1, 7, 8, 404])
+def test_x_in_vmem_threshold_boundary(bw):
+    """The route holds up to X_VMEM_MAX entries of x for a whole number of
+    8-slot tiles; padding bw to tiles shrinks the limit in proportion."""
+    br, most = 128, ozaki_spmv.X_VMEM_MAX
+    tiles = -(-bw // 8) * 8
+    n = most * bw // tiles // br * br               # the longest x that fits
+    assert ozaki_spmv.x_in_vmem(n, bw, br)
+    assert not ozaki_spmv.x_in_vmem(n + 1, bw, br)
+
+
 # --- solver residual traces --------------------------------------------------
 
 def test_cg_residual_trace_matches_history():

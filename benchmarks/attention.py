@@ -26,7 +26,7 @@ from typing import List, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import ops
+from repro.core import dispatch
 from benchmarks.kernels import _provenance, _timed
 
 Row = Tuple[str, float, float, str, str]
@@ -47,12 +47,12 @@ def attention_section() -> List[Row]:
     # (both routes run the full residue pipeline per kv block), and the smoke
     # lane runs this section in both REPRO_DISPATCH legs.
     for mode in ("xla", "pallas"):
-        us = _timed(lambda mode=mode: ops.ozaki_attention(
+        us = _timed(lambda mode=mode: dispatch.attention(
             q, k, v, mask=causal, mode=mode), reps=1)
-        route, cls = _provenance(lambda mode=mode: ops.ozaki_attention(
+        route, cls = _provenance(lambda mode=mode: dispatch.attention(
             q, k, v, mask=causal, mode=mode))
         pre[mode] = (f"kernel_attention/route_{mode}/us", us,
-                     ops.ozaki_attention(q, k, v, mask=causal, mode=mode),
+                     dispatch.attention(q, k, v, mask=causal, mode=mode),
                      route, cls)
     diff = float(jnp.max(jnp.abs(pre["pallas"][2] - pre["xla"][2])))
     rows.extend((name, us, diff, route, cls)
@@ -66,12 +66,12 @@ def attention_section() -> List[Row]:
     valid = jnp.asarray((np.arange(T) < 80).astype(np.int8))[None, :]
     dec = {}
     for mode in ("xla", "pallas"):
-        us = _timed(lambda mode=mode: ops.ozaki_attention(
+        us = _timed(lambda mode=mode: dispatch.attention(
             qd, kd, vd, mask=valid, mode=mode), reps=1)
-        route, cls = _provenance(lambda mode=mode: ops.ozaki_attention(
+        route, cls = _provenance(lambda mode=mode: dispatch.attention(
             qd, kd, vd, mask=valid, mode=mode))
         dec[mode] = (f"kernel_attention/decode_route_{mode}/us", us,
-                     ops.ozaki_attention(qd, kd, vd, mask=valid, mode=mode),
+                     dispatch.attention(qd, kd, vd, mask=valid, mode=mode),
                      route, cls)
     diff = float(jnp.max(jnp.abs(dec["pallas"][2] - dec["xla"][2])))
     rows.extend((name, us, diff, route, cls)

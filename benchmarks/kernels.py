@@ -2,14 +2,14 @@
 *structural* β accounting that the paper's §5/Appendix D analysis is about.
 
 derived column:
-  wallclock rows — CPU interpret μs (not TPU perf; the roofline story for TPU lives
-                   in EXPERIMENTS.md §Roofline from the compiled dry-run);
+  wallclock rows — CPU interpret μs (not TPU perf; the TPU roofline comes from
+                   the compiled dry-run, ``repro.launch.roofline``);
   beta rows      — HBM bytes of the emulated kernel / bytes of the native-FP64
                    kernel, computed from the actual operand/result shapes.  The
                    paper's claim is β = 1 for f64/ds output and (8+r)/16-ish for
                    digits mode; this prints the exact numbers.
   route rows     — xla vs pallas through the dispatch entry points
-                   (``ops.ozaki_spmv_bell`` / ``ops.ozaki_stencil7`` with
+                   (``dispatch.spmv`` / ``dispatch.stencil7`` with
                    ``mode=``); derived = max |pallas - xla|, expected exactly 0
                    (the routes are bit-identical).
 
@@ -29,8 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import ozaki2
-from repro.kernels import ops
+from repro.core import dispatch, ozaki2
+from repro.kernels import ozaki_gemm, ozaki_gemv
 from repro.obs import telemetry as obs
 
 Row = Tuple[str, float, float]
@@ -67,8 +67,9 @@ def all_kernels() -> List[Row]:
     b = jnp.asarray(rng.standard_normal((k, n)))
     plan = ozaki2.make_plan(k)
     for rep in ("f64", "digits", "ds"):
-        us = _timed(lambda rep=rep: ops.ozaki_gemm(a, b, plan=plan, out_rep=rep,
-                                                   bm=64, bn=64, bk=64))
+        us = _timed(lambda rep=rep: ozaki_gemm.gemm(
+            a, b, plan, out_rep=rep, bm=64, bn=64, bk=64,
+            interpret=dispatch.pallas_interpret("gemm")))
         out_bytes = {"f64": 8, "ds": 8, "digits": plan.r}[rep] * m * n
         beta = _beta((m * k + k * n) * 8, m * n * 8,
                      (m * k + k * n) * 8, out_bytes)
@@ -81,8 +82,9 @@ def all_kernels() -> List[Row]:
         X = jnp.asarray(rng.standard_normal((N, B)))
         planv = ozaki2.make_plan(N)
         for rep in ("f64", "digits"):
-            us = _timed(lambda rep=rep, X=X: ops.ozaki_gemv(
-                A, X, plan=planv, out_rep=rep, bm=128, bk=128))
+            us = _timed(lambda rep=rep, X=X: ozaki_gemv.gemv(
+                A, X, planv, out_rep=rep, bm=128, bk=128,
+                interpret=dispatch.pallas_interpret("gemv")))
             out_bytes = {"f64": 8, "digits": planv.r}[rep] * M * B
             beta = _beta((M * N + N * B) * 8, M * B * 8,
                          (M * N + N * B) * 8, out_bytes)
@@ -94,8 +96,8 @@ def all_kernels() -> List[Row]:
     u = jnp.asarray(rng.standard_normal((32, 32, 32)))
     c = jnp.asarray(np.array([6.0, -1, -1, -1, -1, -1, -1]))
     for rep in ("f64", "digits", "ds"):
-        usx = _timed(lambda rep=rep: ops.ozaki_stencil7(u, c, out_rep=rep,
-                                                        bx=4, mode="pallas"))
+        usx = _timed(lambda rep=rep: dispatch.stencil7(u, c, out_rep=rep,
+                                                       bx=4, mode="pallas"))
         plan_s = ozaki2.make_plan(8, margin_bits=4)
         npts = 32 ** 3
         out_bytes = {"f64": 8, "ds": 8, "digits": plan_s.r}[rep] * npts
@@ -115,8 +117,8 @@ def all_kernels() -> List[Row]:
         # default plan, which would hang the smoke lane.  The fused-kernel
         # machinery is covered by the bounded-plan route rows below (and on
         # TPU these same entry points measure the Mosaic kernel via auto).
-        us = _timed(lambda rep=rep: ops.ozaki_spmv_bell(val, col, x, out_rep=rep,
-                                                        br=256, mode="xla"))
+        us = _timed(lambda rep=rep: dispatch.spmv(val, col, x, out_rep=rep,
+                                                  br=256, mode="xla"))
         plan_v = ozaki2.make_plan(bw, margin_bits=4)
         out_bytes = {"f64": 8, "digits": plan_v.r}[rep] * Ms
         # native bytes: values + colidx + x-gather (cached ~1x) + y
@@ -130,11 +132,11 @@ def all_kernels() -> List[Row]:
     # stencil: default plan, both routes are cheap on CPU.
     stencil_out = {}
     for mode in ("xla", "pallas"):
-        us = _timed(lambda mode=mode: ops.ozaki_stencil7(u, c, bx=4, mode=mode))
+        us = _timed(lambda mode=mode: dispatch.stencil7(u, c, bx=4, mode=mode))
         route, cls = _provenance(
-            lambda mode=mode: ops.ozaki_stencil7(u, c, bx=4, mode=mode))
+            lambda mode=mode: dispatch.stencil7(u, c, bx=4, mode=mode))
         stencil_out[mode] = (f"kernel_stencil/route_{mode}/us", us,
-                             ops.ozaki_stencil7(u, c, bx=4, mode=mode),
+                             dispatch.stencil7(u, c, bx=4, mode=mode),
                              route, cls)
     diff = float(jnp.max(jnp.abs(stencil_out["pallas"][2]
                                  - stencil_out["xla"][2])))
@@ -149,13 +151,13 @@ def all_kernels() -> List[Row]:
     x_r = jnp.asarray(rng.standard_normal(Nr))
     spmv_out = {}
     for mode in ("xla", "pallas"):
-        us = _timed(lambda mode=mode: ops.ozaki_spmv_bell(
+        us = _timed(lambda mode=mode: dispatch.spmv(
             val_r, col_r, x_r, plan=plan_r7, br=128, mode=mode))
-        route, cls = _provenance(lambda mode=mode: ops.ozaki_spmv_bell(
+        route, cls = _provenance(lambda mode=mode: dispatch.spmv(
             val_r, col_r, x_r, plan=plan_r7, br=128, mode=mode))
         spmv_out[mode] = (f"kernel_spmv/route_{mode}/us", us,
-                          ops.ozaki_spmv_bell(val_r, col_r, x_r, plan=plan_r7,
-                                              br=128, mode=mode),
+                          dispatch.spmv(val_r, col_r, x_r, plan=plan_r7,
+                                        br=128, mode=mode),
                           route, cls)
     diff = float(jnp.max(jnp.abs(spmv_out["pallas"][2] - spmv_out["xla"][2])))
     rows.extend((name, us, diff, route, cls)

@@ -3,6 +3,8 @@
 Every branch that differs between a TPU and the other backends keys on
 ``name()``: the dispatch layer's auto routes and Pallas flavour (Mosaic or
 the interpreter), and the float64 arithmetic that has to change on a TPU.
+``working_float`` is the one place the program picks the float that the
+emulation computes in.
 
 XLA:TPU emulates float64 as a pair of float32 values.  Measured on a TPU v5e
 (phase 0 of ``chip_smoke.py``):
@@ -22,6 +24,7 @@ XLA:TPU emulates float64 as a pair of float32 values.  Measured on a TPU v5e
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 
 
 def name() -> str:
@@ -32,3 +35,9 @@ def name() -> str:
 def float64_is_f32_pair() -> bool:
     """Whether float64 on this backend is XLA's float32-pair emulation."""
     return name() == "tpu"
+
+
+def working_float():
+    """The float the emulation scales, reconstructs and returns in: float64
+    when x64 is enabled, else float32."""
+    return jnp.float64 if jax.config.jax_enable_x64 else jnp.float32

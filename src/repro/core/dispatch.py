@@ -4,9 +4,10 @@ The paper's §8 recommendation is that Ozaki-style emulation live *behind* the
 precision-policy interface of the standard libraries, with the register-fused
 kernels as the default execution path.  This module is that seam: every
 emulated multiplication in the repo (``Policy.dot``, the HPC solvers, the
-serving engine, the kernel wrappers, the spectral transforms) resolves its
-configuration and its execution path here instead of hand-rolling both at each
-call-site.
+serving engine, the spectral transforms) resolves its configuration and its
+execution path here instead of hand-rolling both at each call-site.  The
+kernel modules under ``repro.kernels`` each own their whole pallas route and
+import nothing from this layer.
 
 Three concerns, one layer:
 
@@ -73,6 +74,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import backend, ozaki2
+from repro.kernels import (ozaki_attention, ozaki_gemm, ozaki_gemv, ozaki_spmv,
+                           ozaki_stencil)
 from repro.obs import telemetry as obs
 
 MODES = ("auto", "xla", "pallas")
@@ -402,10 +405,6 @@ def pallas_interpret(kind: str = "gemm") -> bool:
     return backend.name() != "tpu"
 
 
-def _working_float():
-    return jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
-
-
 # RHS widths at or below this route to the fused batched-GEMV kernel (paper
 # Alg. 1's small-B regime) instead of padding the N axis up to a full GEMM lane.
 GEMV_MAX_B = 16
@@ -417,8 +416,6 @@ def _matmul_kind(n: int) -> str:
 
 
 def _pallas_matmul(a: jax.Array, b: jax.Array, plan: ozaki2.Plan) -> jax.Array:
-    from repro.kernels import ops  # deferred: kernels import core, not vice versa
-
     m, k = a.shape
     n = b.shape[1]
     if _matmul_kind(n) == "gemv":
@@ -427,12 +424,12 @@ def _pallas_matmul(a: jax.Array, b: jax.Array, plan: ozaki2.Plan) -> jax.Array:
         bm, _, bk = choose_blocks(m, k, n)
         ap = _pad_axis(_pad_axis(a, 0, bm), 1, bk)
         bp = _pad_axis(b, 0, bk)
-        out = ops.ozaki_gemv(ap, bp, plan=plan, bm=bm, bk=bk,
-                             interpret=pallas_interpret("gemv"))
+        out = ozaki_gemv.gemv(ap, bp, plan, bm=bm, bk=bk,
+                              interpret=pallas_interpret("gemv"))
         return out[:m]
     ap, bp, (bm, bn, bk) = pad_operands(a, b)
-    out = ops.ozaki_gemm(ap, bp, plan=plan, bm=bm, bn=bn, bk=bk,
-                         interpret=pallas_interpret("gemm"))
+    out = ozaki_gemm.gemm(ap, bp, plan, bm=bm, bn=bn, bk=bk,
+                          interpret=pallas_interpret("gemm"))
     return out[:m, :n]
 
 
@@ -443,8 +440,9 @@ def matmul(a: jax.Array, b: jax.Array, plan: Optional[ozaki2.Plan] = None,
 
     a: (m, k), b: (k, n); returns working-float (m, n) regardless of route —
     callers needing the kernel-native digits/ds output representations use
-    ``repro.kernels.ops`` directly.  The plan comes from the process cache
-    unless given explicitly; the execution path follows ``choose_route``.
+    ``ozaki_gemm.gemm`` / ``ozaki_gemv.gemv`` directly.  The plan comes from
+    the process cache unless given explicitly; the execution path follows
+    ``choose_route``.
     """
     if plan is None:
         plan = get_plan(a.shape[-1], payload_bits, substrate)
@@ -455,7 +453,8 @@ def matmul(a: jax.Array, b: jax.Array, plan: Optional[ozaki2.Plan] = None,
     if route == "pallas":
         out = _pallas_matmul(a, b, plan)
     else:
-        out = ozaki2.emulated_matmul(a, b, plan, out_dtype=_working_float())
+        out = ozaki2.emulated_matmul(a, b, plan,
+                                     out_dtype=backend.working_float())
     return obs.op_end(rec, out)
 
 
@@ -490,10 +489,6 @@ def spmv(a_val: jax.Array, a_col: jax.Array, x: jax.Array,
     and ``spmv_vmem`` cache tallies count a hit for each recorded call that
     takes the shifts or the in-kernel gather.
     """
-    # Deferred module import (kernels import core, not vice versa); attribute
-    # access at call time keeps the route monkeypatch-able in tests.
-    from repro.kernels import ozaki_spmv as _spmv
-
     if plan is None:
         plan = get_plan(a_val.shape[1], margin_bits=4)
     route = choose_route(plan, "spmv_bell", mode, shape=a_val.shape)
@@ -503,15 +498,15 @@ def spmv(a_val: jax.Array, a_col: jax.Array, x: jax.Array,
     if route == "pallas":
         if br is None:
             br = int(get_tuning("spmv_bell", a_val.shape).get("br", 128))
-        out = _spmv.spmv_bell(a_val, a_col, x, plan, out_rep=out_rep,
-                              br=br, interpret=pallas_interpret("spmv_bell"),
-                              offsets=offsets)
+        out = ozaki_spmv.spmv_bell(
+            a_val, a_col, x, plan, out_rep=out_rep, br=br,
+            interpret=pallas_interpret("spmv_bell"), offsets=offsets)
     else:
-        out = _spmv.spmv_bell_ref(a_val, a_col, x, plan, out_rep=out_rep)
+        out = ozaki_spmv.spmv_bell_ref(a_val, a_col, x, plan, out_rep=out_rep)
     if rec is not None:
         obs.record_cache("spmv_band", route == "pallas" and offsets is not None)
         obs.record_cache("spmv_vmem", route == "pallas" and offsets is None
-                         and _spmv.x_in_vmem(x.shape[0], a_val.shape[1], br))
+                         and ozaki_spmv.x_in_vmem(x.shape[0], a_val.shape[1], br))
     return obs.op_end(rec, out)
 
 
@@ -526,8 +521,6 @@ def stencil7(u: jax.Array, c: jax.Array, plan: Optional[ozaki2.Plan] = None,
     kernel (x-axis blocked, padded and unpadded internally) vs the
     bit-identical jnp reference ``ozaki_stencil.stencil7_ref``.
     """
-    from repro.kernels import ozaki_stencil as _stencil
-
     if plan is None:
         plan = get_plan(8, margin_bits=4)
     route = choose_route(plan, "stencil7", mode, shape=u.shape)
@@ -535,10 +528,10 @@ def stencil7(u: jax.Array, c: jax.Array, plan: Optional[ozaki2.Plan] = None,
     if route == "pallas":
         if bx is None:
             bx = int(get_tuning("stencil7", u.shape).get("bx", 1))
-        out = _stencil.stencil7(u, c, plan, out_rep=out_rep, bx=bx,
-                                interpret=pallas_interpret("stencil7"))
+        out = ozaki_stencil.stencil7(u, c, plan, out_rep=out_rep, bx=bx,
+                                     interpret=pallas_interpret("stencil7"))
     else:
-        out = _stencil.stencil7_ref(u, c, plan, out_rep=out_rep)
+        out = ozaki_stencil.stencil7_ref(u, c, plan, out_rep=out_rep)
     return obs.op_end(rec, out)
 
 
@@ -567,8 +560,6 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     op with a ``prefill`` (S > 1) or ``decode`` (S = 1) label so the two
     serving shape classes stay distinguishable in the measured-vs-TME table.
     """
-    from repro.kernels import ozaki_attention as _attn
-
     lead = q.shape[:-2]
     S, D = q.shape[-2:]
     T = k.shape[-2]
@@ -585,7 +576,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     route = choose_route(plan_qk, "attention", mode, shape=(B, S, D, T))
     rec = obs.op_start("attention", (B, S, D, T), route, plan_qk, q, k, v,
                        label="decode" if S == 1 else "prefill")
-    wf = _working_float()
+    wf = backend.working_float()
     if mask is None:
         mask = jnp.ones((S, T), jnp.int8)
     if mask.ndim == 2:
@@ -598,13 +589,14 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if route == "pallas":
         def one(args):
             qi, ki, vi, mi = args
-            return _attn.attention_pallas_gemms(
+            return ozaki_attention.attention_pallas_gemms(
                 qi, ki, vi, mi, plan_qk, plan_pv, _pallas_matmul,
                 softcap=softcap, bkv=bkv, out_dtype=wf)
     else:
         def one(args):
             qi, ki, vi, mi = args
-            return _attn.attention_ref(qi, ki, vi, mi, plan_qk, plan_pv,
-                                       softcap=softcap, bkv=bkv, out_dtype=wf)
+            return ozaki_attention.attention_ref(
+                qi, ki, vi, mi, plan_qk, plan_pv, softcap=softcap, bkv=bkv,
+                out_dtype=wf)
     out = jax.lax.map(one, (qf, kf, vf, mask))
     return obs.op_end(rec, out.reshape(lead + (S, D)))

@@ -29,14 +29,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.core import dispatch, ozaki1, ozaki2
+from repro.core import backend, dispatch, ozaki1, ozaki2
 
 POLICIES = ("bf16", "fp32", "fp64", "ozaki2_int8", "ozaki2_fp8", "ozaki1_int8")
-
-
-def _working_f64():
-    """float64 when x64 is live, else float32 (payload auto-clips to 24 bits)."""
-    return jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
 
 
 def _flatten_dot(fn):
@@ -79,7 +74,7 @@ ozaki2_dot.defvjp(_ozaki2_dot_fwd, _ozaki2_dot_bwd)
 
 @jax.custom_vjp
 def ozaki1_dot(a: jax.Array, b: jax.Array) -> jax.Array:
-    return ozaki1.emulated_matmul(a, b, out_dtype=_working_f64())
+    return ozaki1.emulated_matmul(a, b, out_dtype=backend.working_float())
 
 
 def _ozaki1_dot_fwd(a, b):
@@ -88,8 +83,8 @@ def _ozaki1_dot_fwd(a, b):
 
 def _ozaki1_dot_bwd(res, g):
     a, b = res
-    da = ozaki1.emulated_matmul(g, b.T, out_dtype=_working_f64())
-    db = ozaki1.emulated_matmul(a.T, g, out_dtype=_working_f64())
+    da = ozaki1.emulated_matmul(g, b.T, out_dtype=backend.working_float())
+    db = ozaki1.emulated_matmul(a.T, g, out_dtype=backend.working_float())
     return da.astype(a.dtype), db.astype(b.dtype)
 
 
@@ -130,17 +125,17 @@ class Policy:
                 (((x.ndim - 1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32).astype(x.dtype)
         if self.name == "fp64":
-            f64 = _working_f64()
+            f64 = backend.working_float()
             return jnp.dot(x.astype(f64), w.astype(f64)).astype(x.dtype)
         if self.name in ("ozaki2_int8", "ozaki2_fp8"):
             substrate = self.name.split("_")[1]
             plan = dispatch.get_plan(x.shape[-1], self.payload_bits,
                                      substrate=substrate)
-            f64 = _working_f64()
+            f64 = backend.working_float()
             out = _flatten_dot(ozaki2_dot)(x.astype(f64), w.astype(f64), plan)
             return out.astype(x.dtype)
         if self.name == "ozaki1_int8":
-            f64 = _working_f64()
+            f64 = backend.working_float()
             out = _flatten_dot(ozaki1_dot)(x.astype(f64), w.astype(f64))
             return out.astype(x.dtype)
         raise AssertionError(self.name)

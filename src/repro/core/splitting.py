@@ -4,7 +4,8 @@ Paper mapping (Matsuoka 2026 §2.3 Phase 1, Appendix C):
   * ``scale_to_int`` implements Ã = ⌊D A⌉ with power-of-two diagonal D chosen per row
     (or per column for the right operand) so the largest entry uses the full payload
     width p.  Power-of-two scaling is exact in FP64, so D^{-1} Ĉ E^{-1} is error-free.
-  * ``split_hi_lo`` is the hardware adaptation documented in DESIGN.md §3: TPUs have no
+    ``global_scale_to_int`` is the same with one shift for the whole array.
+  * ``split_hi_lo`` is the hardware adaptation: TPUs have no
     FP64 VMEM type and no fast int64, so the 53-bit scaled integer is carried as an
     exact pair of int32 halves, x = hi * 2^26 + lo.  8 bytes/element — identical HBM
     traffic to native FP64, which is what keeps the TME bandwidth multiplier β = 1.
@@ -96,6 +97,20 @@ def scale_to_int(x: jax.Array, payload_bits: int, axis: int) -> Tuple[jax.Array,
     scaled = jnp.where(too_big, scaled * 0.5, scaled)
     xi = jnp.round(scaled)
     return xi, jnp.squeeze(shift, axis=ax)
+
+
+def global_scale_to_int(x: jax.Array, payload_bits: int) -> Tuple[jax.Array, jax.Array]:
+    """``scale_to_int`` with one power-of-two shift for the whole array (a
+    scalar int32), for operands that a kernel reads by more than one row:
+    the stencil's grid and coefficients, the SpMV's x."""
+    absmax = jnp.max(jnp.abs(x))
+    e = jnp.floor(jnp.log2(jnp.where(absmax > 0, absmax, 1.0)))
+    shift = (payload_bits - 1) - e.astype(jnp.int32)
+    scaled = ldexp(x, shift)
+    too_big = jnp.max(jnp.abs(scaled)) >= 2.0 ** payload_bits
+    shift = shift - too_big.astype(jnp.int32)
+    scaled = jnp.where(too_big, scaled * 0.5, scaled)
+    return jnp.round(scaled), shift
 
 
 def split_hi_lo(xi: jax.Array) -> Tuple[jax.Array, jax.Array]:

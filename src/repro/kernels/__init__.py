@@ -1,3 +1,6 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""The fused Ozaki-II Pallas kernels, one module per kind, each owning its
+whole pallas route (prologue, the jitted ``pallas_call``, epilogue) behind
+``repro.core.dispatch``.  ``common`` holds what the kernels share, ``ref``
+the float64 oracles.  Nothing here imports the dispatch layer or the
+precision policies above it.
+"""

@@ -12,6 +12,10 @@ TPU mapping of the paper's register-fusion pattern:
 Block shapes default to MXU-friendly multiples (second-minor 8/32, minor 128 lanes);
 the VMEM working set is r·bm·bn·4 B of accumulator + (bm+bn)·bk·8 B of tiles —
 r=16, bm=bn=128, bk=256: ~1.0 MiB + 0.5 MiB, comfortably inside a v5e core's VMEM.
+
+``gemm`` is the whole pallas route of ``repro.core.dispatch.matmul``: the
+shared prologue (``common.matmul_prologue``), ``gemm_hilo`` and the shared
+epilogue (``common.matmul_epilogue``).
 """
 
 from __future__ import annotations
@@ -24,7 +28,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import ozaki2
+from repro.core import ozaki2, splitting
 from repro.kernels import common
 
 
@@ -37,20 +41,20 @@ def _gemm_kernel(a_hi_ref, a_lo_ref, b_hi_ref, b_lo_ref, out_ref, acc_ref, *,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # Residue decomposition of the freshly-loaded tiles — stays in VMEM/VREGs.
-    a_res = common.residues_int32(a_hi_ref[...], a_lo_ref[...], plan.moduli)
-    b_res = common.residues_int32(b_hi_ref[...], b_lo_ref[...], plan.moduli)
+    a_res = splitting.residues_int32(a_hi_ref[...], a_lo_ref[...], plan.moduli)
+    b_res = splitting.residues_int32(b_hi_ref[...], b_lo_ref[...], plan.moduli)
 
     for i, m in enumerate(plan.moduli):
         part = jax.lax.dot_general(
             a_res[i].astype(jnp.int8), b_res[i].astype(jnp.int8),
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
-        acc_ref[i] = common.balanced_mod(acc_ref[i] + part, m)
+        acc_ref[i] = splitting.balanced_mod(acc_ref[i] + part, m)
 
     @pl.when(kidx == np.int32(k_steps - 1))
     def _epilogue():
-        digits = common.garner_digits([acc_ref[i] for i in range(plan.r)], plan)
+        digits = ozaki2.garner_digits([acc_ref[i] for i in range(plan.r)], plan)
         if out_rep == "ds":
-            hi, lo = common.digits_to_ds(digits, plan)
+            hi, lo = ozaki2.digits_to_ds(digits, plan)
             out_ref[0] = hi
             out_ref[1] = lo
         else:  # digits
@@ -102,3 +106,17 @@ def gemm_hilo(a_hi: jax.Array, a_lo: jax.Array, b_hi: jax.Array, b_lo: jax.Array
         scratch_shapes=[pltpu.VMEM((plan.r, bm, bn), jnp.int32)],
         interpret=interpret,
     )(a_hi, a_lo, b_hi, b_lo)
+
+
+def gemm(a: jax.Array, b: jax.Array, plan: ozaki2.Plan, out_rep: str = "f64",
+         bm: int = 128, bn: int = 128, bk: int = 256,
+         interpret: bool = True) -> jax.Array:
+    """FP64-accurate C = A @ B through the fused Pallas kernel."""
+    M, K = a.shape
+    _, N = b.shape
+    bm, bn, bk = min(bm, M), min(bn, N), min(bk, K)
+    operands, shifts = common.matmul_prologue(a, b, plan, (bm, bk), (bk, bn))
+    rep = common.kernel_rep(out_rep)
+    raw = gemm_hilo(*operands, plan, out_rep=rep, bm=bm, bn=bn, bk=bk,
+                    interpret=interpret)
+    return common.matmul_epilogue(raw, plan, rep, shifts, (M, N))

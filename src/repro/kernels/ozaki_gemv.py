@@ -10,7 +10,10 @@ residues and accumulators VMEM-resident, Garner before store.  Register-pressure
 note from §5.2: r accumulator planes of (bm, B) int32 — at r=16, bm=128, B=8 that
 is 64 KiB of VMEM scratch, far below the spill threshold; the paper's caveat that
 B ≳ 8 forces spilling applies to CUDA register files, not to VMEM-scale scratch
-(an honest TPU-vs-GPU difference recorded in DESIGN.md §3).
+(a real difference between the TPU and a GPU).
+
+``gemv`` is the narrow-RHS pallas route of ``repro.core.dispatch.matmul``:
+the GEMM's prologue and epilogue (``common``) around ``gemv_hilo``.
 """
 
 from __future__ import annotations
@@ -23,7 +26,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import ozaki2
+from repro.core import ozaki2, splitting
 from repro.kernels import common
 
 
@@ -35,20 +38,20 @@ def _gemv_kernel(a_hi_ref, a_lo_ref, x_hi_ref, x_lo_ref, out_ref, acc_ref, *,
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a_res = common.residues_int32(a_hi_ref[...], a_lo_ref[...], plan.moduli)
-    x_res = common.residues_int32(x_hi_ref[...], x_lo_ref[...], plan.moduli)
+    a_res = splitting.residues_int32(a_hi_ref[...], a_lo_ref[...], plan.moduli)
+    x_res = splitting.residues_int32(x_hi_ref[...], x_lo_ref[...], plan.moduli)
 
     for i, m in enumerate(plan.moduli):
         part = jax.lax.dot_general(
             a_res[i].astype(jnp.int8), x_res[i].astype(jnp.int8),
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
-        acc_ref[i] = common.balanced_mod(acc_ref[i] + part, m)
+        acc_ref[i] = splitting.balanced_mod(acc_ref[i] + part, m)
 
     @pl.when(kidx == np.int32(k_steps - 1))
     def _epilogue():
-        digits = common.garner_digits([acc_ref[i] for i in range(plan.r)], plan)
+        digits = ozaki2.garner_digits([acc_ref[i] for i in range(plan.r)], plan)
         if out_rep == "ds":
-            hi, lo = common.digits_to_ds(digits, plan)
+            hi, lo = ozaki2.digits_to_ds(digits, plan)
             out_ref[0] = hi
             out_ref[1] = lo
         else:
@@ -92,3 +95,16 @@ def gemv_hilo(a_hi: jax.Array, a_lo: jax.Array, x_hi: jax.Array, x_lo: jax.Array
         scratch_shapes=[pltpu.VMEM((plan.r, bm, B), jnp.int32)],
         interpret=interpret,
     )(a_hi, a_lo, x_hi, x_lo)
+
+
+def gemv(a: jax.Array, x: jax.Array, plan: ozaki2.Plan, out_rep: str = "f64",
+         bm: int = 128, bk: int = 256, interpret: bool = True) -> jax.Array:
+    """Batched GEMV Y = A @ X (paper Alg. 1): A (M,N) fp64, X (N,B) with small B."""
+    M, N = a.shape
+    _, B = x.shape
+    bm, bk = min(bm, M), min(bk, N)
+    operands, shifts = common.matmul_prologue(a, x, plan, (bm, bk), (bk, B))
+    rep = common.kernel_rep(out_rep)
+    raw = gemv_hilo(*operands, plan, out_rep=rep, bm=bm, bk=bk,
+                    interpret=interpret)
+    return common.matmul_epilogue(raw, plan, rep, shifts, (M, B))

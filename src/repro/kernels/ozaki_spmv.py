@@ -34,9 +34,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import ozaki2, splitting
+from repro.core import backend, ozaki2, splitting
 from repro.kernels import common
-from repro.kernels.ozaki_stencil import _global_scale_to_int
 from repro.obs import spans
 
 _SUBLANES = 8
@@ -135,18 +134,18 @@ def _spmv_kernel(*refs, plan: ozaki2.Plan, out_rep: str, unroll: int):
                   unroll=unroll)
     else:
         av_hi_ref, av_lo_ref, xg_hi_ref, xg_lo_ref, out_ref = refs
-    a_res = common.residues_int32(av_hi_ref[...], av_lo_ref[...], plan.moduli)
-    x_res = common.residues_int32(xg_hi_ref[...], xg_lo_ref[...], plan.moduli)
+    a_res = splitting.residues_int32(av_hi_ref[...], av_lo_ref[...], plan.moduli)
+    x_res = splitting.residues_int32(xg_hi_ref[...], xg_lo_ref[...], plan.moduli)
 
     accs = []
     for i, m in enumerate(plan.moduli):
         prod = a_res[i] * x_res[i]           # (bw, br) int32, |.| <= 128*128
-        accs.append(common.balanced_mod(
+        accs.append(splitting.balanced_mod(
             jnp.sum(prod, axis=0, keepdims=True, dtype=jnp.int32), m))
 
-    digits = common.garner_digits(accs, plan)   # (1, br) each
+    digits = ozaki2.garner_digits(accs, plan)   # (1, br) each
     if out_rep == "ds":
-        hi, lo = common.digits_to_ds(digits, plan)
+        hi, lo = ozaki2.digits_to_ds(digits, plan)
         out_ref[0] = hi
         out_ref[1] = lo
     else:
@@ -158,13 +157,13 @@ def _decompose_operands(a_val: jax.Array, a_col: jax.Array, x: jax.Array,
     """Shared prologue of the fused kernel and the jnp reference: Phase-1
     scaling, hi/lo split, column cast.  One implementation keeps the two
     paths' bit-identity structural rather than a testing promise."""
-    f64 = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
+    f64 = backend.working_float()
     # Scopes around the statements in their trace order (``repro.obs.spans``).
     with spans.scope("ozaki.split_a"):
         av, sa = splitting.scale_to_int(a_val.astype(f64), plan.payload_bits,
                                         axis=-1)
     with spans.scope("ozaki.split_b"):
-        xi, sx = _global_scale_to_int(x.astype(f64), plan.payload_bits)
+        xi, sx = splitting.global_scale_to_int(x.astype(f64), plan.payload_bits)
     with spans.scope("ozaki.split_a"):
         av_hi, av_lo = splitting.split_hi_lo(av)
     with spans.scope("ozaki.split_b"):
@@ -179,23 +178,23 @@ def _spmv_ref_digits(a_val: jax.Array, a_col: jax.Array, x: jax.Array,
     av_hi, av_lo, cols, x_hi, x_lo, sa, sx = _decompose_operands(
         a_val, a_col, x, plan)
 
-    a_res = common.residues_int32(av_hi, av_lo, plan.moduli)
-    x_res = common.residues_int32(x_hi[cols], x_lo[cols], plan.moduli)
-    accs = [common.balanced_mod(
+    a_res = splitting.residues_int32(av_hi, av_lo, plan.moduli)
+    x_res = splitting.residues_int32(x_hi[cols], x_lo[cols], plan.moduli)
+    accs = [splitting.balanced_mod(
                 jnp.sum(a_res[i] * x_res[i], axis=-1, dtype=jnp.int32), m)
             for i, m in enumerate(plan.moduli)]
-    return common.garner_digits(accs, plan), sa, sx
+    return ozaki2.garner_digits(accs, plan), sa, sx
 
 
 @functools.partial(jax.jit, static_argnames=("plan", "out_rep"))
 def _spmv_ref_epilogue(digits, sa: jax.Array, sx: jax.Array,
                        plan: ozaki2.Plan, out_rep: str) -> jax.Array:
     """Reference back half: digit reconstruction + exact unscale."""
-    f64 = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
+    f64 = backend.working_float()
     if out_rep in ("f64", "digits"):
-        y = common.digits_to_f64(digits, plan, out_dtype=f64)
+        y = ozaki2.digits_to_f64(digits, plan, out_dtype=f64)
     elif out_rep == "ds":
-        hi, lo = common.digits_to_ds(digits, plan)
+        hi, lo = ozaki2.digits_to_ds(digits, plan)
         y = hi.astype(f64) + lo.astype(f64)
     else:
         raise ValueError(f"out_rep must be one of {common.OUT_REPS}")
@@ -244,7 +243,7 @@ def spmv_bell(a_val: jax.Array, a_col: jax.Array, x: jax.Array,
     Without ``offsets`` the kernel gathers x itself where ``x_in_vmem``
     holds, and XLA gathers it otherwise; both read the same halves."""
     M, bw = a_val.shape
-    f64 = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
+    f64 = backend.working_float()
     in_vmem = offsets is None and x_in_vmem(x.shape[0], bw, br)
     br = min(br, M)
     pm = (-M) % br

@@ -26,7 +26,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import ozaki2, splitting
+from repro.core import backend, ozaki2, splitting
 from repro.kernels import common
 from repro.obs import spans
 
@@ -34,17 +34,6 @@ from repro.obs import spans
 # of a 256 x 256 plane take ~20 MiB, past Mosaic's 16 MiB default (a v5e core
 # has 128 MiB of VMEM).
 VMEM_LIMIT_BYTES = 64 * 1024 * 1024
-
-
-def _global_scale_to_int(x: jax.Array, payload_bits: int):
-    absmax = jnp.max(jnp.abs(x))
-    e = jnp.floor(jnp.log2(jnp.where(absmax > 0, absmax, 1.0)))
-    shift = (payload_bits - 1) - e.astype(jnp.int32)
-    scaled = splitting.ldexp(x, shift)
-    too_big = jnp.max(jnp.abs(scaled)) >= 2.0 ** payload_bits
-    shift = shift - too_big.astype(jnp.int32)
-    scaled = jnp.where(too_big, scaled * 0.5, scaled)
-    return jnp.round(scaled), shift
 
 
 def _roll_mask(arr: jax.Array, ax: int, d: int) -> jax.Array:
@@ -77,9 +66,9 @@ def _stencil_kernel(c_res_ref, u_hi_p, u_lo_p, u_hi_c, u_lo_c, u_hi_n, u_lo_n,
     # each point once per modulus, then shift residue planes.
     accs = []
     for i, m in enumerate(plan.moduli):
-        (cur,) = common.residues_int32(u_hi_c[...], u_lo_c[...], (m,))
-        (prev,) = common.residues_int32(u_hi_p[...], u_lo_p[...], (m,))
-        (nxt,) = common.residues_int32(u_hi_n[...], u_lo_n[...], (m,))
+        (cur,) = splitting.residues_int32(u_hi_c[...], u_lo_c[...], (m,))
+        (prev,) = splitting.residues_int32(u_hi_p[...], u_lo_p[...], (m,))
+        (nxt,) = splitting.residues_int32(u_hi_n[...], u_lo_n[...], (m,))
         prev = jnp.where(first, jnp.zeros_like(prev), prev)
         nxt = jnp.where(last, jnp.zeros_like(nxt), nxt)
         if bx == 1:
@@ -94,11 +83,11 @@ def _stencil_kernel(c_res_ref, u_hi_p, u_lo_p, u_hi_c, u_lo_c, u_hi_n, u_lo_n,
         acc = nbs[0] * c_res_ref[i, 0]
         for t in range(1, 7):
             acc = acc + nbs[t] * c_res_ref[i, t]
-        accs.append(common.balanced_mod(acc, m))
+        accs.append(splitting.balanced_mod(acc, m))
 
-    digits = common.garner_digits(accs, plan)
+    digits = ozaki2.garner_digits(accs, plan)
     if out_rep == "ds":
-        hi, lo = common.digits_to_ds(digits, plan)
+        hi, lo = ozaki2.digits_to_ds(digits, plan)
         out_ref[0] = hi
         out_ref[1] = lo
     else:
@@ -109,14 +98,14 @@ def _stencil_kernel(c_res_ref, u_hi_p, u_lo_p, u_hi_c, u_lo_c, u_hi_n, u_lo_n,
 def stencil7(u: jax.Array, c: jax.Array, plan: ozaki2.Plan,
              out_rep: str = "f64", bx: int = 1, interpret: bool = True) -> jax.Array:
     X, Y, Z = u.shape
-    f64 = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
+    f64 = backend.working_float()
     bx = min(bx, X)
     px = (-X) % bx
     # Scopes around the statements in their trace order (``repro.obs.spans``).
     with spans.scope("ozaki.split_b"):
-        ui, su = _global_scale_to_int(u.astype(f64), plan.payload_bits)
+        ui, su = splitting.global_scale_to_int(u.astype(f64), plan.payload_bits)
     with spans.scope("ozaki.split_a"):
-        ci, sc = _global_scale_to_int(c.astype(f64), plan.payload_bits)
+        ci, sc = splitting.global_scale_to_int(c.astype(f64), plan.payload_bits)
     with spans.scope("ozaki.split_b"):
         u_hi, u_lo = splitting.split_hi_lo(ui)
         if px:
@@ -125,7 +114,7 @@ def stencil7(u: jax.Array, c: jax.Array, plan: ozaki2.Plan,
             u_lo = jnp.pad(u_lo, ((0, px), (0, 0), (0, 0)))
     with spans.scope("ozaki.split_a"):
         c_hi, c_lo = splitting.split_hi_lo(ci)
-        c_res = jnp.stack(common.residues_int32(c_hi, c_lo, plan.moduli))
+        c_res = jnp.stack(splitting.residues_int32(c_hi, c_lo, plan.moduli))
 
     Xp = X + px
     x_steps = Xp // bx
@@ -181,13 +170,13 @@ def stencil7_ref(u: jax.Array, c: jax.Array, plan: ozaki2.Plan,
     regardless of x-blocking.  This is the ``xla``
     route of ``repro.core.dispatch.stencil7``.
     """
-    f64 = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
-    ui, su = _global_scale_to_int(u.astype(f64), plan.payload_bits)
-    ci, sc = _global_scale_to_int(c.astype(f64), plan.payload_bits)
+    f64 = backend.working_float()
+    ui, su = splitting.global_scale_to_int(u.astype(f64), plan.payload_bits)
+    ci, sc = splitting.global_scale_to_int(c.astype(f64), plan.payload_bits)
     u_hi, u_lo = splitting.split_hi_lo(ui)
     c_hi, c_lo = splitting.split_hi_lo(ci)
-    c_res = common.residues_int32(c_hi, c_lo, plan.moduli)
-    u_res = common.residues_int32(u_hi, u_lo, plan.moduli)
+    c_res = splitting.residues_int32(c_hi, c_lo, plan.moduli)
+    u_res = splitting.residues_int32(u_hi, u_lo, plan.moduli)
 
     accs = []
     for i, m in enumerate(plan.moduli):
@@ -200,15 +189,15 @@ def stencil7_ref(u: jax.Array, c: jax.Array, plan: ozaki2.Plan,
         acc = nbs[0] * c_res[i][0]
         for t in range(1, 7):
             acc = acc + nbs[t] * c_res[i][t]
-        accs.append(common.balanced_mod(acc, m))
+        accs.append(splitting.balanced_mod(acc, m))
 
     # Barriers around the Garner step, as in ``ozaki2.garner_reconstruct``.
     accs = jax.lax.optimization_barrier(accs)
-    digits = jax.lax.optimization_barrier(common.garner_digits(accs, plan))
+    digits = jax.lax.optimization_barrier(ozaki2.garner_digits(accs, plan))
     if out_rep in ("f64", "digits"):
-        v = common.digits_to_f64(digits, plan, out_dtype=f64)
+        v = ozaki2.digits_to_f64(digits, plan, out_dtype=f64)
     elif out_rep == "ds":
-        hi, lo = common.digits_to_ds(digits, plan)
+        hi, lo = ozaki2.digits_to_ds(digits, plan)
         v = hi.astype(f64) + lo.astype(f64)
     else:
         raise ValueError(f"out_rep must be one of {common.OUT_REPS}")

@@ -27,6 +27,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import backend
 from repro.spectral import dft
 
 
@@ -50,8 +51,8 @@ def dft_stacked(x: jax.Array, inverse: bool = False,
     four-step with recursive factor transforms above it.
     """
     x = jnp.asarray(x)
-    yr, yi = dft_stacked_parts(jnp.real(x).astype(dft.working_float()),
-                               jnp.imag(x).astype(dft.working_float()),
+    yr, yi = dft_stacked_parts(jnp.real(x).astype(backend.working_float()),
+                               jnp.imag(x).astype(backend.working_float()),
                                inverse=inverse, mode=mode)
     return jax.lax.complex(yr, yi)
 

@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import dispatch
+from repro.core import backend, dispatch
 
 # Transforms at or below this length run as a single dense DFT GEMM; longer
 # lengths go through the Bailey four-step factorisation (repro.spectral.bailey).
@@ -39,10 +39,6 @@ DENSE_MAX = 64
 # Hard cap on the dense fallback (taken only when n has no usable factorisation,
 # i.e. prime n): an (2n, 2n) operator above this is a memory bug, not a path.
 DENSE_HARD_MAX = 4096
-
-
-def working_float():
-    return jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
 
 
 def working_complex():
@@ -92,7 +88,7 @@ def realified_dft(n: int, inverse: bool = False) -> jax.Array:
             f"dense DFT fallback refused for n={n} > {DENSE_HARD_MAX} "
             "(prime length with no four-step factorisation; pad to a "
             "composite length instead)")
-    dtype_name = jnp.dtype(working_float()).name
+    dtype_name = jnp.dtype(backend.working_float()).name
     if n > CACHE_MAX:
         return _build_realified(int(n), bool(inverse), dtype_name)
     return _realified_dft(int(n), bool(inverse), dtype_name)
@@ -121,7 +117,7 @@ def _twiddle(n: int, n1: int, n2: int, inverse: bool,
 def twiddle(n: int, n1: int, n2: int,
             inverse: bool = False) -> Tuple[jax.Array, jax.Array]:
     """Real and imaginary parts of the (n1, n2) four-step twiddle table."""
-    dtype_name = jnp.dtype(working_float()).name
+    dtype_name = jnp.dtype(backend.working_float()).name
     if n > TWIDDLE_CACHE_MAX:
         return _build_twiddle(int(n), int(n1), int(n2), bool(inverse),
                               dtype_name)

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import backend
 from repro.spectral import bailey, dft
 
 
@@ -31,7 +32,7 @@ def _apply_parts(xr: jax.Array, xi: jax.Array, axis: int, inverse: bool,
                  ) -> Tuple[jax.Array, jax.Array]:
     """DFT along ``axis`` (over ``norm``) of the real and imaginary parts:
     move the axis to the front, flatten the rest as batch."""
-    wf = dft.working_float()
+    wf = backend.working_float()
     parts = []
     for part in (xr, xi):
         part = jnp.moveaxis(jnp.asarray(part).astype(wf), axis, 0)

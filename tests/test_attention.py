@@ -137,16 +137,14 @@ def _spy_attention_routes(monkeypatch):
 
 
 def test_mode_scope_flips_attention_route(monkeypatch):
-    from repro.kernels import ops
-
     calls = _spy_attention_routes(monkeypatch)
     q, k, v = _qkv(8, 8, 8)
     with dispatch.mode_scope("xla"):
-        ops.ozaki_attention(q, k, v)
+        dispatch.attention(q, k, v)
     with dispatch.mode_scope("pallas"):
-        ops.ozaki_attention(q, k, v)
+        dispatch.attention(q, k, v)
     monkeypatch.setenv(dispatch.ENV_VAR, "pallas")
-    ops.ozaki_attention(q, k, v)
+    dispatch.attention(q, k, v)
     assert calls == ["xla", "pallas", "pallas"]
 
 

@@ -244,26 +244,24 @@ def _spy_spmv_routes(monkeypatch):
 
 
 def test_mode_scope_flips_spmv_route(monkeypatch):
-    """mode_scope / REPRO_DISPATCH select the route of ozaki_spmv_bell the
+    """mode_scope / REPRO_DISPATCH select the route of dispatch.spmv the
     same way they do for GEMM — no caller passes interpret= anymore."""
-    from repro.kernels import ops
-
     calls = _spy_spmv_routes(monkeypatch)
     val = jnp.asarray(RNG.standard_normal((16, 4)))
     col = jnp.asarray(RNG.integers(0, 24, (16, 4)).astype(np.int32))
     x = jnp.asarray(RNG.standard_normal(24))
 
     with dispatch.mode_scope("xla"):
-        ops.ozaki_spmv_bell(val, col, x)
+        dispatch.spmv(val, col, x)
     with dispatch.mode_scope("pallas"):
-        ops.ozaki_spmv_bell(val, col, x)
+        dispatch.spmv(val, col, x)
     monkeypatch.setenv(dispatch.ENV_VAR, "pallas")
-    ops.ozaki_spmv_bell(val, col, x)
+    dispatch.spmv(val, col, x)
     assert calls == ["xla", "pallas", "pallas"]
 
 
 def test_mode_scope_flips_stencil_route(monkeypatch):
-    from repro.kernels import ops, ozaki_stencil
+    from repro.kernels import ozaki_stencil
 
     calls = []
     real_ref = ozaki_stencil.stencil7_ref
@@ -283,11 +281,11 @@ def test_mode_scope_flips_stencil_route(monkeypatch):
     u = jnp.asarray(RNG.standard_normal((4, 4, 4)))
     c = jnp.asarray(np.array([6.0, -1, -1, -1, -1, -1, -1]))
     with dispatch.mode_scope("xla"):
-        ops.ozaki_stencil7(u, c)
+        dispatch.stencil7(u, c)
     with dispatch.mode_scope("pallas"):
-        ops.ozaki_stencil7(u, c)
+        dispatch.stencil7(u, c)
     monkeypatch.setenv(dispatch.ENV_VAR, "xla")
-    ops.ozaki_stencil7(u, c)
+    dispatch.stencil7(u, c)
     assert calls == ["xla", "pallas", "xla"]
 
 

@@ -11,8 +11,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import ozaki2
-from repro.kernels import ops, ref
+from repro.core import dispatch, ozaki2
+from repro.kernels import ozaki_gemm, ozaki_gemv, ref
 
 U64 = 2.0 ** -53
 RNG = np.random.default_rng(123)
@@ -39,14 +39,16 @@ def test_gemm_accuracy_sweep(mkn, blocks, out_rep):
     bm, bn, bk = blocks
     a = jnp.asarray(RNG.standard_normal((m, k)))
     b = jnp.asarray(RNG.standard_normal((k, n)))
-    c = ops.ozaki_gemm(a, b, out_rep=out_rep, bm=bm, bn=bn, bk=bk)
+    c = ozaki_gemm.gemm(a, b, dispatch.get_plan(k), out_rep=out_rep,
+                        bm=bm, bn=bn, bk=bk)
     assert _gemm_err(c, a, b) <= 16 * U64
 
 
 def test_gemm_ds_mode_precision():
     a = jnp.asarray(RNG.standard_normal((32, 64)))
     b = jnp.asarray(RNG.standard_normal((64, 32)))
-    c = ops.ozaki_gemm(a, b, out_rep="ds", bm=16, bn=16, bk=32)
+    c = ozaki_gemm.gemm(a, b, dispatch.get_plan(64), out_rep="ds",
+                        bm=16, bn=16, bk=32)
     err = _gemm_err(c, a, b)
     assert err <= 2.0 ** -44  # double-single carries ~45-48 bits
     assert err > 2.0 ** -60   # ...but is not full f64 (sanity on the mode split)
@@ -56,7 +58,7 @@ def test_gemm_kernel_bitexact_vs_xla_ozaki2():
     a = jnp.asarray(RNG.standard_normal((24, 48)))
     b = jnp.asarray(RNG.standard_normal((48, 16)))
     plan = ozaki2.make_plan(48)
-    c_kernel = ops.ozaki_gemm(a, b, plan=plan, out_rep="f64", bm=8, bn=8, bk=16)
+    c_kernel = ozaki_gemm.gemm(a, b, plan=plan, out_rep="f64", bm=8, bn=8, bk=16)
     c_xla = ozaki2.emulated_matmul(a, b, plan)
     np.testing.assert_array_equal(np.asarray(c_kernel), np.asarray(c_xla))
 
@@ -65,7 +67,7 @@ def test_gemm_f32_inputs():
     a = jnp.asarray(RNG.standard_normal((16, 32)), jnp.float32)
     b = jnp.asarray(RNG.standard_normal((32, 16)), jnp.float32)
     plan = ozaki2.make_plan(32, payload_bits=24)
-    c = ops.ozaki_gemm(a, b, plan=plan, bm=16, bn=16, bk=32)
+    c = ozaki_gemm.gemm(a, b, plan=plan, bm=16, bn=16, bk=32)
     want = np.asarray(a, np.float64) @ np.asarray(b, np.float64)
     denom = np.abs(np.asarray(a, np.float64)) @ np.abs(np.asarray(b, np.float64))
     assert np.max(np.abs(np.asarray(c) - want) / denom) <= 2.0 ** -22
@@ -81,7 +83,8 @@ def test_gemv_accuracy_sweep(mnb, out_rep):
     m, n, bsz = mnb
     a = jnp.asarray(RNG.standard_normal((m, n)))
     x = jnp.asarray(RNG.standard_normal((n, bsz)))
-    y = ops.ozaki_gemv(a, x, out_rep=out_rep, bm=16, bk=32)
+    y = ozaki_gemv.gemv(a, x, dispatch.get_plan(n), out_rep=out_rep,
+                        bm=16, bk=32)
     denom = np.abs(np.asarray(a)) @ np.abs(np.asarray(x)) + 1e-300
     err = np.max(np.abs(np.asarray(y) - np.asarray(ref.gemv_f64(a, x))) / denom)
     assert err <= 16 * U64
@@ -91,8 +94,8 @@ def test_gemv_matches_gemm_kernel():
     a = jnp.asarray(RNG.standard_normal((32, 64)))
     x = jnp.asarray(RNG.standard_normal((64, 8)))
     plan = ozaki2.make_plan(64)
-    y1 = ops.ozaki_gemv(a, x, plan=plan, bm=16, bk=32)
-    y2 = ops.ozaki_gemm(a, x, plan=plan, bm=16, bn=8, bk=32)
+    y1 = ozaki_gemv.gemv(a, x, plan=plan, bm=16, bk=32)
+    y2 = ozaki_gemm.gemm(a, x, plan=plan, bm=16, bn=8, bk=32)
     np.testing.assert_array_equal(np.asarray(y1), np.asarray(y2))
 
 
@@ -109,7 +112,7 @@ def test_gemv_matches_gemm_kernel():
 def test_stencil_accuracy_sweep(shape, bx, out_rep):
     u = jnp.asarray(RNG.standard_normal(shape))
     c = jnp.asarray(np.array([6.0, -1.0, -1.0, -1.0, -1.0, -1.0, -1.0]))
-    v = ops.ozaki_stencil7(u, c, out_rep=out_rep, bx=bx)
+    v = dispatch.stencil7(u, c, out_rep=out_rep, bx=bx)
     want = np.asarray(ref.stencil7_f64(u, c))
     scale = 7 * np.max(np.abs(np.asarray(u))) * np.max(np.abs(np.asarray(c)))
     assert np.max(np.abs(np.asarray(v) - want)) <= 8 * U64 * scale
@@ -120,7 +123,7 @@ def test_stencil_boundary_zero_halo():
     """Points on the global boundary must see a zero halo, not wraparound."""
     u = jnp.asarray(np.ones((4, 4, 8)))
     c = jnp.asarray(np.array([0.0, 0.0, 0.0, 0.0, 0.0, 1.0, 0.0]))  # pure -z shift
-    v = np.asarray(ops.ozaki_stencil7(u, c, bx=4))
+    v = np.asarray(dispatch.stencil7(u, c, bx=4))
     assert np.all(v[:, :, 0] == 0.0)   # first plane has no -z neighbour
     assert np.all(v[:, :, 1:] == 1.0)
 
@@ -128,7 +131,7 @@ def test_stencil_boundary_zero_halo():
 def test_stencil_anisotropic_coeffs():
     u = jnp.asarray(RNG.standard_normal((8, 8, 8)))
     c = jnp.asarray(RNG.standard_normal(7))
-    v = np.asarray(ops.ozaki_stencil7(u, c, bx=4))
+    v = np.asarray(dispatch.stencil7(u, c, bx=4))
     want = np.asarray(ref.stencil7_f64(u, c))
     scale = float(7 * jnp.max(jnp.abs(u)) * jnp.max(jnp.abs(c)))
     assert np.max(np.abs(v - want)) <= 8 * U64 * scale
@@ -153,7 +156,7 @@ def test_spmv_accuracy_sweep(mnbw, out_rep):
     # leg the default-plan interpreter would pay minutes of XLA-CPU compile.
     m, n, bw = mnbw
     val, col, x = _random_bell(m, n, bw)
-    y = ops.ozaki_spmv_bell(val, col, x, out_rep=out_rep, br=16, mode="xla")
+    y = dispatch.spmv(val, col, x, out_rep=out_rep, br=16, mode="xla")
     want = np.asarray(ref.spmv_bell_f64(val, col, x))
     denom = (np.abs(np.asarray(val)).sum(-1) * np.max(np.abs(np.asarray(x)))
              + 1e-300)
@@ -172,8 +175,8 @@ def test_spmv_laplacian_1d():
         for s, (j, v) in enumerate(nz):
             col[i, s], val[i, s] = j, v
     x = RNG.standard_normal(n)
-    y = np.asarray(ops.ozaki_spmv_bell(jnp.asarray(val), jnp.asarray(col),
-                                       jnp.asarray(x), br=32, mode="xla"))
+    y = np.asarray(dispatch.spmv(jnp.asarray(val), jnp.asarray(col),
+                                 jnp.asarray(x), br=32, mode="xla"))
     np.testing.assert_allclose(y, dense @ x, rtol=0, atol=4 * U64 * 4 * np.abs(x).max())
 
 
@@ -197,10 +200,10 @@ def test_spmv_routes_bit_identical_pallas_interpreter():
     plan = ozaki2.make_plan(4, payload_bits=24)
     val, col, x = _random_bell(27, 32, 4)    # 27 % br != 0: padding path
     for rep in ("f64", "digits"):
-        y_ref = np.asarray(ops.ozaki_spmv_bell(val, col, x, plan=plan,
-                                               out_rep=rep, mode="xla"))
-        y_pal = np.asarray(ops.ozaki_spmv_bell(val, col, x, plan=plan, br=8,
-                                               out_rep=rep, mode="pallas"))
+        y_ref = np.asarray(dispatch.spmv(val, col, x, plan=plan,
+                                         out_rep=rep, mode="xla"))
+        y_pal = np.asarray(dispatch.spmv(val, col, x, plan=plan, br=8,
+                                         out_rep=rep, mode="pallas"))
         np.testing.assert_array_equal(y_ref, y_pal)
 
 
@@ -225,8 +228,35 @@ def test_spmv_vmem_gather_bit_identical_to_xla(operator):
     val, col, x = operator()
     assert ozaki_spmv.x_in_vmem(x.shape[0], val.shape[1], 8)
     for rep in ("f64", "digits"):
-        y_ref = np.asarray(ops.ozaki_spmv_bell(val, col, x, plan=plan,
-                                               out_rep=rep, mode="xla"))
-        y_pal = np.asarray(ops.ozaki_spmv_bell(val, col, x, plan=plan, br=8,
-                                               out_rep=rep, mode="pallas"))
+        y_ref = np.asarray(dispatch.spmv(val, col, x, plan=plan,
+                                         out_rep=rep, mode="xla"))
+        y_pal = np.asarray(dispatch.spmv(val, col, x, plan=plan, br=8,
+                                         out_rep=rep, mode="pallas"))
         np.testing.assert_array_equal(y_ref, y_pal)
+
+
+# ---------------------------------------------------------------------------
+# Layering
+# ---------------------------------------------------------------------------
+
+def test_kernels_import_nothing_from_dispatch_or_policy():
+    """The kernel layer sits under the dispatch seam: no module under
+    ``repro.kernels`` imports ``repro.core.dispatch`` or ``repro.core.policy``."""
+    import ast
+    import pathlib
+
+    import repro.kernels
+
+    upward = {"repro.core.dispatch", "repro.core.policy"}
+    offenders = []
+    for path in sorted(pathlib.Path(repro.kernels.__file__).parent.glob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.Import):
+                names = {alias.name for alias in node.names}
+            elif isinstance(node, ast.ImportFrom) and node.module:
+                names = {node.module} | {f"{node.module}.{alias.name}"
+                                         for alias in node.names}
+            else:
+                continue
+            offenders += [(path.name, n) for n in sorted(names & upward)]
+    assert offenders == []

@@ -15,7 +15,7 @@ import pytest
 
 from repro.core import compensated, dispatch, ozaki2
 from repro.hpc import cg, jacobi
-from repro.kernels import ops, ozaki_spmv, ozaki_stencil
+from repro.kernels import ozaki_gemm, ozaki_gemv, ozaki_spmv, ozaki_stencil
 from repro.obs import report, spans, telemetry as obs
 
 
@@ -426,11 +426,11 @@ def _scope_lowering(kind):
     if kind == "gemm":
         plan = ozaki2.make_plan(32, payload_bits=24)
         a = jnp.ones((32, 32), f64)
-        return jax.jit(lambda a, b: ops.ozaki_gemm(a, b, plan=plan, interpret=True)
+        return jax.jit(lambda a, b: ozaki_gemm.gemm(a, b, plan, interpret=True)
                        ).lower(a, a)
     if kind == "gemv":
         plan = ozaki2.make_plan(32, payload_bits=24)
-        return jax.jit(lambda a, x: ops.ozaki_gemv(a, x, plan=plan, interpret=True)
+        return jax.jit(lambda a, x: ozaki_gemv.gemv(a, x, plan, interpret=True)
                        ).lower(jnp.ones((32, 32), f64), jnp.ones((32, 4), f64))
     if kind == "spmv_bell":
         plan = ozaki2.make_plan(7, payload_bits=24)

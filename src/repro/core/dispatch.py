@@ -110,6 +110,9 @@ AUTO_ROUTE = {
 # multiples of 8, minor axis in lane multiples of 128.
 SUBLANE = 8
 LANE = 128
+# int8 blocks (the GEMM's residue planes, both kernels' digits) tile in
+# (32, 128): a tuned bm is a multiple of 32, else the whole padded m-axis.
+INT8_SUBLANE = 32
 DEFAULT_BM = 128
 DEFAULT_BN = 128
 DEFAULT_BK = 256
@@ -197,12 +200,15 @@ def clear_plan_cache() -> None:
 # ---------------------------------------------------------------------------
 
 # Seeded (measured) tuning entries.  "*" is the per-kind wildcard; specific
-# shape-classes (see ``shape_class``) override it.  GEMM/GEMV entries mirror
-# the MXU defaults (DEFAULT_BM/BN/BK); spmv_bell/stencil7 carry the kernel
+# shape-classes (see ``shape_class``) override it.  The GEMV entry mirrors
+# the MXU defaults (DEFAULT_BM/BK); spmv_bell/stencil7 carry the kernel
 # defaults so every kind resolves its blocking here rather than in
-# per-call-site constants.
+# per-call-site constants.  GEMM: the fastest of bm, bn in {128, 256, 512}
+# and bk in {256, 512} on one TPU v5e, at 8192^3 (the kernel 137.8 ms, 246.4
+# at 128/128/256) and at 2048^3 (7.03 ms, 7.68 at 128/128/256), each with
+# Garner over the whole tile.
 TUNE_TABLE: Dict[Tuple[str, str], Dict[str, Any]] = {
-    ("gemm", "*"): {"bm": 128, "bn": 128, "bk": 256},
+    ("gemm", "*"): {"bm": 512, "bn": 512, "bk": 512},
     ("gemv", "*"): {"bm": 128, "bk": 256},
     ("spmv_bell", "*"): {"br": 128},
     ("stencil7", "*"): {"bx": 1},
@@ -303,14 +309,16 @@ def choose_blocks(m: int, k: int, n: int) -> Tuple[int, int, int]:
     RHS width, default 128/128/256); smaller axes shrink to the dimension
     rounded up to the hardware granule (sublane 8 for the second-minor m-axis,
     lane 128 for the minor n/k axes) so padding stays bounded while tiles keep
-    legal Mosaic shapes.  Tuned values are clamped to the same legality rules,
-    so a bad REPRO_TUNE entry degrades performance, never correctness.
+    legal Mosaic shapes; a tuned bm is a multiple of the int8 sublane tile
+    (32), and a smaller m-axis is one whole block.  Tuned values are clamped
+    to the same legality rules, so a bad REPRO_TUNE entry degrades
+    performance, never correctness.
     """
     tune = get_tuning(_matmul_kind(n), (m, k, n))
-    tbm = int(tune.get("bm", DEFAULT_BM))
+    tbm = _round_up(int(tune.get("bm", DEFAULT_BM)), INT8_SUBLANE)
     tbn = int(tune.get("bn", DEFAULT_BN))
     tbk = int(tune.get("bk", DEFAULT_BK))
-    bm = _round_up(tbm, SUBLANE) if m >= tbm else _round_up(m, SUBLANE)
+    bm = tbm if m >= tbm else _round_up(m, SUBLANE)
     bn = _round_up(tbn, LANE) if n >= tbn else _round_up(n, LANE)
     # bk must divide the lane-padded K; falling back to one lane (128) keeps
     # the K padding at < one lane of zeros (bk=256 on k=257 would pad to 512).

@@ -1,10 +1,12 @@
 """What the fused Ozaki-II Pallas kernels share: in-kernel primitives, the
 XLA epilogue ``finish``, and the GEMM/GEMV prologue and epilogue.
 
-The β→1 discipline (paper §5.1) in TPU terms: operands enter the kernel as (hi, lo)
-int32 pairs (8 B/elem — the same HBM traffic as native FP64); residue planes are
-computed *inside* the kernel in VMEM/VREGs and never round-trip to HBM; the Garner
-reconstruction runs on the int32 accumulators before the store.
+The β→1 discipline (paper §5.1) in TPU terms: operands enter the GEMV, SpMV and
+stencil kernels as (hi, lo) int32 pairs (8 B/elem — the same HBM traffic as native
+FP64) and their residues are computed *inside* the kernel in VMEM/VREGs; the GEMM,
+which reads each operand tile many times, reads int8 residue planes built once in
+its prologue (``ozaki_gemm``).  The Garner reconstruction runs on the int32
+accumulators before the store.
 
 Output representations (the one place the TPU adaptation pays a real cost, since
 Mosaic has no float64 type):
@@ -95,6 +97,16 @@ def matmul_epilogue(raw: jax.Array, plan: ozaki2.Plan, rep: str, shifts,
     with spans.scope("ozaki.finish"):
         c = finish(raw, plan, rep, backend.working_float())[..., :m, :n]
         return splitting.apply_unscale(c, *shifts)
+
+
+def loop(n: int, body, init):
+    """``lax.fori_loop(0, n, body, init)`` with an int32 index, for use inside
+    a Mosaic kernel: under x64 fori_loop's index is int64, which Mosaic does
+    not lower."""
+    def step(carry, _):
+        i, x = carry
+        return (i + np.int32(1), body(i, x)), None
+    return jax.lax.scan(step, (np.int32(0), init), None, length=n)[0][1]
 
 
 def stack_digits_int8(digits: Sequence[jax.Array]) -> jax.Array:

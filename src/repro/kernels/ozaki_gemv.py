@@ -5,8 +5,8 @@ dimension (the paper's 16/32-wide tensor-core n-dim); the M and N axes tile.
 Operational intensity ≈ B/2 FLOPs/B, the regime where the TME model predicts the
 largest memory-bound win on FP64-starved parts (~24x on B300 at B=8).
 
-The fusion discipline is identical to ozaki_gemm: (hi, lo) int32 operands in,
-residues and accumulators VMEM-resident, Garner before store.  Register-pressure
+The fusion discipline: (hi, lo) int32 operands in, residues built per tile and
+accumulators VMEM-resident, Garner before store.  Register-pressure
 note from §5.2: r accumulator planes of (bm, B) int32 — at r=16, bm=128, B=8 that
 is 64 KiB of VMEM scratch, far below the spill threshold; the paper's caveat that
 B ≳ 8 forces spilling applies to CUDA register files, not to VMEM-scale scratch

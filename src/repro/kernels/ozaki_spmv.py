@@ -75,15 +75,6 @@ def _lane_gather(src: jax.Array, idx: jax.Array) -> jax.Array:
                       mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS)
 
 
-def _loop(n: int, body, init):
-    """``lax.fori_loop(0, n, body, init)`` with an int32 index: under x64
-    fori_loop's index is int64, which Mosaic does not lower."""
-    def step(carry, _):
-        i, x = carry
-        return (i + np.int32(1), body(i, x)), None
-    return lax.scan(step, (np.int32(0), init), None, length=n)[0][1]
-
-
 def _gather_x(col_ref, x_hi_ref, x_lo_ref, xg_hi_ref, xg_lo_ref, *,
               unroll: int):
     """xg_hi/xg_lo[k, j] = x_hi/x_lo[col[k, j]] for a (bw, br) block of
@@ -117,12 +108,12 @@ def _gather_x(col_ref, x_hi_ref, x_lo_ref, xg_hi_ref, xg_lo_ref, *,
             return acc
 
         zero = jnp.zeros(tile_shape, jnp.int32)
-        hi, lo = _loop(x_hi_ref.shape[0] // unroll, trip, (zero, zero))
+        hi, lo = common.loop(x_hi_ref.shape[0] // unroll, trip, (zero, zero))
         xg_hi_ref[rows, :] = hi
         xg_lo_ref[rows, :] = lo
         return carry
 
-    _loop(bw // _SUBLANES, tile, np.int32(0))
+    common.loop(bw // _SUBLANES, tile, np.int32(0))
 
 
 def _spmv_kernel(*refs, plan: ozaki2.Plan, out_rep: str, unroll: int):

@@ -31,6 +31,7 @@ PREFIX = "repro."
 SCOPES = (
     "ozaki.split_a",   # Phase-1 scaling, hi/lo split, padding of the matrix operand
     "ozaki.split_b",   # the same for the other operand (B, x, the stencil's u)
+    "ozaki.residues",  # the GEMM's int8 residue planes of both operands, built once a call
     "spmv.gather",     # x's hi/lo gathered (banded: shifted) to the ELL slots, for the kernel;
                        # where the kernel gathers from VMEM, a_col's transpose and x's reshape
     "ozaki.finish",    # digits -> working float, and the exact unscale

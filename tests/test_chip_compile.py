@@ -71,11 +71,18 @@ def _compile_text(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("route", ["pallas", "xla"])
-def test_gemm_compiles(tpu, route):
+@pytest.mark.parametrize("route,n", [("pallas", 1024), ("xla", 1024),
+                                     ("pallas", 8192)])
+def test_gemm_compiles(tpu, route, n):
+    """The pallas route with ``choose_blocks``'s tiles: int8 residue-plane
+    blocks legal for Mosaic and the VMEM they need granted, at the dgemm
+    cell's 8192^3 too; the kernel keeps its name."""
     txt = _compile_text(lambda a, b: dispatch.matmul(a, b, mode=route),
-                        tpu((1024, 1024)), tpu((1024, 1024)))
+                        tpu((n, n)), tpu((n, n)))
     assert ("tpu_custom_call" in txt) == (route == "pallas")
+    if route == "pallas":
+        assert re.search(r"%gemm_hilo(\.\d+)? = s8\[\d+,\d+,\d+\]\S* custom-call\(",
+                         txt) is not None
 
 
 def test_gemv_compiles(tpu):

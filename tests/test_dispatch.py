@@ -440,11 +440,11 @@ def test_reduce_kind_has_no_pallas_route():
 def test_choose_blocks_tuned_values_are_legality_clamped(tune_env):
     tune_env('{"gemm": {"*": {"bm": 100, "bn": 100, "bk": 100}}}')
     bm, bn, bk = dispatch.choose_blocks(512, 512, 512)
-    assert bm == 104          # rounded up to the sublane granule (8)
+    assert bm == 128          # rounded up to the int8 sublane granule (32)
     assert bn == 128          # rounded up to the lane granule (128)
     assert bk == 128          # lane-rounded and dividing the padded K
     # A bad tuning entry degrades performance, never correctness/legality.
-    assert bm % dispatch.SUBLANE == 0 and bn % dispatch.LANE == 0
+    assert bm % dispatch.INT8_SUBLANE == 0 and bn % dispatch.LANE == 0
 
 
 def test_tuned_blocks_keep_pallas_route_bit_identical(tune_env):

@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import dispatch, ozaki2
+from repro.core import dispatch, ozaki2, splitting
 from repro.kernels import ozaki_gemm, ozaki_gemv, ref
 
 U64 = 2.0 ** -53
@@ -54,13 +54,58 @@ def test_gemm_ds_mode_precision():
     assert err > 2.0 ** -60   # ...but is not full f64 (sanity on the mode split)
 
 
-def test_gemm_kernel_bitexact_vs_xla_ozaki2():
-    a = jnp.asarray(RNG.standard_normal((24, 48)))
-    b = jnp.asarray(RNG.standard_normal((48, 16)))
-    plan = ozaki2.make_plan(48)
-    c_kernel = ozaki_gemm.gemm(a, b, plan=plan, out_rep="f64", bm=8, bn=8, bk=16)
-    c_xla = ozaki2.emulated_matmul(a, b, plan)
+def _xla_ozaki2(a, b, plan, out_rep):
+    """The unfused XLA pipeline's answer in the kernel's output representation."""
+    if out_rep == "f64":
+        return ozaki2.emulated_matmul(a, b, plan)
+    ares, sa = ozaki2.decompose(a, plan, scale_axis=-1)
+    bres, sb = ozaki2.decompose(b, plan, scale_axis=0)
+    digits = ozaki2.garner_digits(list(ozaki2.modular_matmul(ares, bres, plan)),
+                                  plan)
+    hi, lo = ozaki2.digits_to_ds(digits, plan)
+    return splitting.apply_unscale(hi.astype(jnp.float64) + lo.astype(jnp.float64),
+                                   sa, sb)
+
+
+@pytest.mark.parametrize("mkn,blocks,interval", [
+    ((24, 48, 16), (8, 8, 16), None),      # 3 x 2 x 3 grid
+    ((100, 100, 50), (64, 16, 32), None),  # 2 x 4 x 4 grid, ragged on every axis,
+                                           # Garner in two strips of rows
+    ((100, 100, 50), (64, 16, 32), 3),     # the accumulator reduced mid-loop
+])
+@pytest.mark.parametrize("out_rep", ["f64", "ds"])
+def test_gemm_kernel_bitexact_vs_xla_ozaki2(mkn, blocks, interval, out_rep,
+                                            monkeypatch):
+    m, k, n = mkn
+    bm, bn, bk = blocks
+    a = jnp.asarray(RNG.standard_normal((m, k)))
+    b = jnp.asarray(RNG.standard_normal((k, n)))
+    plan = ozaki2.make_plan(k)
+    if interval is not None:
+        # K would have to pass ~131k for the reduction to fall mid-loop.
+        monkeypatch.setattr(ozaki_gemm, "reduce_interval", lambda bk, ms: interval)
+    ozaki_gemm.gemm_hilo.clear_cache()
+    try:
+        c_kernel = ozaki_gemm.gemm(a, b, plan=plan, out_rep=out_rep,
+                                   bm=bm, bn=bn, bk=bk)
+    finally:
+        ozaki_gemm.gemm_hilo.clear_cache()
+    c_xla = _xla_ozaki2(a, b, plan, out_rep)
     np.testing.assert_array_equal(np.asarray(c_kernel), np.asarray(c_xla))
+
+
+@pytest.mark.parametrize("bk", [16, 256, 512])
+def test_gemm_reduce_interval_cannot_overflow(bk):
+    moduli = dispatch.get_plan(8192).moduli
+    s = ozaki_gemm.reduce_interval(bk, moduli)
+    h = max(m // 2 for m in moduli)        # largest |balanced residue|
+    assert s >= 1
+    assert s * bk * h * h + h < 2 ** 31
+    # At the dgemm cell's shape the accumulator is reduced once, at the end.
+    _, _, tuned_bk = dispatch.choose_blocks(8192, 8192, 8192)
+    assert ozaki_gemm.reduce_interval(tuned_bk, moduli) >= 8192 // tuned_bk
+    with pytest.raises(ValueError, match="overflow"):
+        ozaki_gemm.reduce_interval(1 << 17, moduli)
 
 
 def test_gemm_f32_inputs():

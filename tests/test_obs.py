@@ -428,6 +428,11 @@ def _scope_lowering(kind):
         a = jnp.ones((32, 32), f64)
         return jax.jit(lambda a, b: ozaki_gemm.gemm(a, b, plan, interpret=True)
                        ).lower(a, a)
+    if kind == "matmul_pallas":
+        plan = ozaki2.make_plan(32, payload_bits=24)
+        a = jnp.ones((32, 32), f64)
+        return jax.jit(lambda a, b: dispatch.matmul(a, b, plan, mode="pallas")
+                       ).lower(a, a)
     if kind == "gemv":
         plan = ozaki2.make_plan(32, payload_bits=24)
         return jax.jit(lambda a, x: ozaki_gemv.gemv(a, x, plan, interpret=True)
@@ -446,7 +451,9 @@ def _scope_lowering(kind):
 
 
 @pytest.mark.parametrize("kind,expected", [
-    ("gemm", {"ozaki.split_a", "ozaki.split_b", "ozaki.finish"}),
+    ("gemm", {"ozaki.split_a", "ozaki.split_b", "ozaki.residues", "ozaki.finish"}),
+    ("matmul_pallas", {"ozaki.split_a", "ozaki.split_b", "ozaki.residues",
+                       "ozaki.finish"}),
     ("gemv", {"ozaki.split_a", "ozaki.split_b", "ozaki.finish"}),
     ("spmv_bell", {"ozaki.split_a", "ozaki.split_b", "spmv.gather", "ozaki.finish"}),
     ("stencil7", {"ozaki.split_a", "ozaki.split_b", "ozaki.finish"}),
